@@ -188,6 +188,7 @@ def flash_decode_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(lengths.astype(jnp.int32), *args)
     return out
 
@@ -289,5 +290,6 @@ def flash_decode_paged_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), *args)
     return out

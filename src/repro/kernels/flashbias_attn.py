@@ -218,7 +218,8 @@ def flashbias_attention_fwd(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch)
         return pl.pallas_call(kernel, grid_spec=grid_spec,
-                              out_shape=out_shape, interpret=interpret)(
+                              out_shape=out_shape, interpret=interpret,
+                              name="flashbias_attn_ragged")(
             lengths.astype(jnp.int32), *args)
 
     kernel = functools.partial(_attn_kernel, kv_len=kv_len, **static)
@@ -230,5 +231,6 @@ def flashbias_attention_fwd(
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flashbias_attn",
     )(*args)
     return out

@@ -120,5 +120,6 @@ def ssd_scan_fwd(x: jax.Array,      # (B, H, S, P)
         out_shape=jax.ShapeDtypeStruct((bsz, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, a.reshape(h).astype(jnp.float32), b, c)
     return out

@@ -38,12 +38,25 @@ from repro.serve.pages import PagePool
 from repro.serve.prefix import PrefixCache
 from repro.serve.sampling import sample_tokens, sample_tokens_guarded
 from repro.serve.scheduler import ChunkPlan, Request
+from repro.serve.trace import span
 
 __all__ = ["Backend", "TokenDecodeBackend", "PairBatchBackend"]
 
 
 def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def named(name: str, fn):
+    """``fn`` under the program name ``name``: ``jax.jit`` names the
+    compiled module ``jit_<name>``, which is how the device trace tells
+    the backend's programs apart (a lambda traces as ``jit__lambda``).
+    Only the two admission prefill programs are named ``_pf``."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 class Backend:
@@ -61,6 +74,11 @@ class Backend:
     lazy: bool = False         # pages grow mid-flight (may force preemption)
     guards: bool = True        # host-side non-finite guards (ISSUE 10)
     faults = None              # serve.faults.FaultPlan injection hook
+    # prefill work since the engine started, read by ``engine.counters()``:
+    # rows x padded length the prefill programs ran, and the real prompt
+    # tokens (residues) among them
+    n_prefill_rows_x_len: int = 0
+    n_prefill_real: int = 0
 
     def ensure_state(self) -> None:
         """Allocate device state on first use (idempotent)."""
@@ -282,27 +300,30 @@ class TokenDecodeBackend(Backend):
                 batch["frontend"] = front
             return model.prefill(p, batch, max_len=max_len, lengths=lengths)
 
-        self._prefill = jax.jit(self._with_mesh(_pf),
-                                static_argnames=("max_len",))
+        self._prefill = self._jit("_pf", _pf, static_argnames=("max_len",))
         # max_pages is a STATIC cap on the pages a paged decode step may
         # reference: the engine passes a power-of-two rounding of its
         # host-mirrored longest live length, so the paged XLA fallback
         # gathers Θ(longest request) instead of the full page-table width
         # while recompiling at most log2(pages_per_slot) times.
-        self._decode = jax.jit(self._with_mesh(model.decode),
-                               static_argnames=("max_pages",))
-        self._insert = jax.jit(self._with_mesh(model.insert_cache))
+        self._decode = self._jit("decode", model.decode,
+                                 static_argnames=("max_pages",))
+        self._insert = self._jit("insert", model.insert_cache)
         if self.paged:
-            self._insert_paged = jax.jit(self._with_mesh(model.insert_paged))
-            self._grow_tables = jax.jit(self._with_mesh(
-                model.grow_page_table))
+            self._insert_paged = self._jit("insert_paged", model.insert_paged)
+            self._grow_tables = self._jit("grow_tables",
+                                          model.grow_page_table)
         if self._prefix is not None:
             # fixed-shape CoW program: (n_slots,) src/dst page ids per
             # call, out-of-range dst ids dropped — compiles once
-            self._copy_pages = jax.jit(self._with_mesh(model.copy_pages))
+            self._copy_pages = self._jit("copy_pages", model.copy_pages)
         if self.chunk_size:
-            self._chunk = jax.jit(self._with_mesh(model.prefill_chunk),
-                                  static_argnames=("max_pages",))
+            self._chunk = self._jit("prefill_chunk", model.prefill_chunk,
+                                    static_argnames=("max_pages",))
+
+    def _jit(self, name: str, fn, **kw):
+        """``fn`` jitted as the program ``name``, traced under the mesh."""
+        return jax.jit(named(name, self._with_mesh(fn)), **kw)
 
     def _with_mesh(self, fn):
         """Bind ``use_mesh_rules(mesh, rules)`` around ``fn`` at TRACE
@@ -589,25 +610,31 @@ class TokenDecodeBackend(Backend):
             pf_len = _ceil_to(padded + front_len, self.page_size)
         else:
             pf_len = self.max_len
-        logits, wave_cache = self._prefill(
-            self.params, jnp.asarray(toks), front, jnp.asarray(lengths),
-            pf_len)
-        slot_ids = np.full((ns,), ns, np.int32)    # padding rows -> dropped
-        slot_ids[:w] = slots
-        if self.paged:
-            # lazy: reserve only the prompt's pages — decode grows the
-            # table on page-boundary crossings. whole: reserve the full
-            # footprint so decode never allocates mid-flight
-            tables = np.full((ns, self.pages_per_slot), self.n_pages,
-                             np.int32)
-            for i, (slot, r) in enumerate(zip(slots, wave)):
-                pages = self._pool.alloc(self.admission_units(r))
-                self._slot_pages[slot] = pages
-                tables[i, :len(pages)] = pages
-            self._cache = self._insert_paged(self._cache, wave_cache,
-                                             slot_ids, jnp.asarray(tables))
-        else:
-            self._cache = self._insert(self._cache, wave_cache, slot_ids)
+        real = [r.prompt_len for r in wave]
+        self.n_prefill_rows_x_len += ns * padded
+        self.n_prefill_real += sum(real)
+        with span("serve.prefill", requests=w, rows=ns, row_len=padded,
+                  real=sum(real), real_sq=sum(n * n for n in real)):
+            logits, wave_cache = self._prefill(
+                self.params, jnp.asarray(toks), front, jnp.asarray(lengths),
+                pf_len)
+            slot_ids = np.full((ns,), ns, np.int32)  # padding rows: dropped
+            slot_ids[:w] = slots
+            if self.paged:
+                # lazy: reserve only the prompt's pages — decode grows the
+                # table on page-boundary crossings. whole: reserve the full
+                # footprint so decode never allocates mid-flight
+                tables = np.full((ns, self.pages_per_slot), self.n_pages,
+                                 np.int32)
+                for i, (slot, r) in enumerate(zip(slots, wave)):
+                    pages = self._pool.alloc(self.admission_units(r))
+                    self._slot_pages[slot] = pages
+                    tables[i, :len(pages)] = pages
+                self._cache = self._insert_paged(self._cache, wave_cache,
+                                                 slot_ids,
+                                                 jnp.asarray(tables))
+            else:
+                self._cache = self._insert(self._cache, wave_cache, slot_ids)
 
         # per-slot sampling state + per-request PRNG chains; a preempted
         # request resumes from its key snapshot so its sample stream stays
@@ -737,18 +764,24 @@ class TokenDecodeBackend(Backend):
         clens = np.zeros((ns,), np.int32)
         flens = np.full((ns,), -1, np.int32)
         finalized: List[int] = []
+        real = 0
         for slot, plan in self._pending.items():
             off, chunk_toks, final = plan.next_chunk(c)
             toks[slot, :chunk_toks.size] = chunk_toks
             offs[slot] = off
             clens[slot] = chunk_toks.size
+            real += chunk_toks.size
             if final:
                 flens[slot] = plan.req.prompt_len
                 finalized.append(slot)
         cap = self._chunk_page_cap()
-        logits, self._cache = self._chunk(
-            self.params, self._cache, jnp.asarray(toks), jnp.asarray(offs),
-            jnp.asarray(clens), jnp.asarray(flens), max_pages=cap)
+        self.n_prefill_rows_x_len += ns * c
+        self.n_prefill_real += real
+        with span("serve.chunk", rows=ns, row_len=c, real=real, pages=cap):
+            logits, self._cache = self._chunk(
+                self.params, self._cache, jnp.asarray(toks),
+                jnp.asarray(offs), jnp.asarray(clens), jnp.asarray(flens),
+                max_pages=cap)
         for slot in finalized:
             plan = self._pending.pop(slot)
             if self._prefix is not None:
@@ -766,9 +799,10 @@ class TokenDecodeBackend(Backend):
         mid-chunked-prefill ride the batch frozen (device length 0) and
         are EXCLUDED from the advance mask — committing their sampling
         state here would burn a PRNG split the wave path never spends."""
-        logits, self._cache = self._decode(self.params, self._cache,
-                                           self._last_tok,
-                                           max_pages=self.page_cap(live))
+        cap = self.page_cap(live)
+        with span("serve.decode", pages=cap, live=len(live)):
+            logits, self._cache = self._decode(self.params, self._cache,
+                                               self._last_tok, max_pages=cap)
         mask = np.zeros((self.n_slots,), bool)
         for s, st in live.items():
             if s not in self._pending:
@@ -793,40 +827,43 @@ class TokenDecodeBackend(Backend):
         quarantine. Fault-plan ``nan`` injections overwrite the chosen
         slots' logits on device first, so drills flow through the same
         guard as real poison."""
-        if self.faults is not None:
-            bad = self.faults.nan_slots()
-            if bad:
-                rows = (jnp.arange(self.n_slots) if -1 in bad
-                        else jnp.asarray(sorted(bad)))
-                logits2d = jnp.asarray(logits2d).at[rows].set(jnp.nan)
-        commit = mask
-        if self.guards:
-            # fused sampler variant: the guard's row-max reduction rides
-            # the same dispatch as sampling, and the peak vector comes
-            # back in the same host transfer as the tokens — the guarded
-            # path costs no extra device round-trip over the unguarded
-            # one (gated at <= 5% overhead by check_bench).
-            toks, new_keys, peak_dev = sample_tokens_guarded(
-                logits2d, self._temps, self._topks, self._keys, self._vocab)
-            toks_h, peak = np.asarray(toks), np.asarray(peak_dev)
-            if mask.any():
-                trip = ~np.isfinite(peak) & mask
-                if trip.any():
-                    commit = mask & ~trip
-                    for s in np.nonzero(trip)[0]:
-                        self._guard_bad[int(s)] = (
-                            f"non-finite logits (row max {peak[s]!r}) at "
-                            f"slot {int(s)} — emission withheld")
-        else:
-            toks, new_keys = sample_tokens(logits2d, self._temps,
-                                           self._topks, self._keys,
-                                           self._vocab)
-            toks_h = np.asarray(toks)
-        m = jnp.asarray(commit)
-        self._keys = jnp.where(m[:, None], new_keys, self._keys)
-        self._last_tok = jnp.where(m[:, None], toks[:, None],
-                                   self._last_tok)
-        return toks_h
+        with span("serve.sample"):
+            if self.faults is not None:
+                bad = self.faults.nan_slots()
+                if bad:
+                    rows = (jnp.arange(self.n_slots) if -1 in bad
+                            else jnp.asarray(sorted(bad)))
+                    logits2d = jnp.asarray(logits2d).at[rows].set(jnp.nan)
+            commit = mask
+            if self.guards:
+                # fused sampler variant: the guard's row-max reduction rides
+                # the same dispatch as sampling, and the peak vector comes
+                # back in the same host transfer as the tokens — the guarded
+                # path costs no extra device round-trip over the unguarded
+                # one (gated at <= 5% overhead by check_bench).
+                toks, new_keys, peak_dev = sample_tokens_guarded(
+                    logits2d, self._temps, self._topks, self._keys, self._vocab)
+                with span("serve.fetch"):
+                    toks_h, peak = np.asarray(toks), np.asarray(peak_dev)
+                if mask.any():
+                    trip = ~np.isfinite(peak) & mask
+                    if trip.any():
+                        commit = mask & ~trip
+                        for s in np.nonzero(trip)[0]:
+                            self._guard_bad[int(s)] = (
+                                f"non-finite logits (row max {peak[s]!r}) at "
+                                f"slot {int(s)} — emission withheld")
+            else:
+                toks, new_keys = sample_tokens(logits2d, self._temps,
+                                               self._topks, self._keys,
+                                               self._vocab)
+                with span("serve.fetch"):
+                    toks_h = np.asarray(toks)
+            m = jnp.asarray(commit)
+            self._keys = jnp.where(m[:, None], new_keys, self._keys)
+            self._last_tok = jnp.where(m[:, None], toks[:, None],
+                                       self._last_tok)
+            return toks_h
 
     # -- retire / preempt ------------------------------------------------
 
@@ -951,9 +988,10 @@ class PairBatchBackend(Backend):
             return model.prefill(p, {"feats": feats}, max_len=max_len,
                                  lengths=lengths, factors=factors)
 
-        self._prefill = jax.jit(_pf, static_argnames=("max_len",))
-        self._step = jax.jit(model.decode)
-        self._insert = jax.jit(model.insert_cache)
+        self._prefill = jax.jit(named("_pf", _pf),
+                                static_argnames=("max_len",))
+        self._step = jax.jit(named("refine", model.decode))
+        self._insert = jax.jit(named("insert_factors", model.insert_cache))
 
     def ensure_state(self) -> None:
         if self._cache is None:
@@ -980,47 +1018,59 @@ class PairBatchBackend(Backend):
         f = wave[0].tokens.shape[1]
         feats = np.zeros((ns, self.max_len, f), np.float32)
         lengths = np.zeros((ns,), np.int32)
+        real = [r.tokens.shape[0] for r in wave]
         for i, r in enumerate(wave):
-            feats[i, :r.tokens.shape[0]] = r.tokens
-            lengths[i] = r.tokens.shape[0]
-        _, wave_cache = self._prefill(self.params, jnp.asarray(feats),
-                                      jnp.asarray(lengths), self.factors,
-                                      self.max_len)
-        if self.guards:
-            # admission-time factor guard (ISSUE 10): the trunk ran once
-            # and its cached per-layer bias state is frozen for every
-            # refinement step — a NaN/Inf here (bad features, unstable
-            # factorization) poisons ALL of the request's steps, so catch
-            # it now, per wave row, before the engine registers the slot
-            flags = [jnp.isfinite(leaf).all(axis=tuple(range(1, leaf.ndim)))
-                     for leaf in jax.tree_util.tree_leaves(wave_cache)
-                     if jnp.issubdtype(leaf.dtype, jnp.floating)
-                     and leaf.ndim >= 1 and leaf.shape[0] == ns]
-            if flags:
-                ok = np.asarray(functools.reduce(jnp.logical_and, flags))
-                for i in range(w):
-                    if not ok[i]:
-                        self._guard_bad[slots[i]] = (
-                            f"non-finite factor cache at admission of "
-                            f"slot {slots[i]} (trunk produced NaN/Inf "
-                            f"from the complex features)")
-        slot_ids = np.full((ns,), ns, np.int32)    # padding rows -> dropped
-        slot_ids[:w] = slots
-        self._cache = self._insert(self._cache, wave_cache,
-                                   jnp.asarray(slot_ids))
+            feats[i, :real[i]] = r.tokens
+            lengths[i] = real[i]
+        self.n_prefill_rows_x_len += ns * self.max_len
+        self.n_prefill_real += sum(real)
+        with span("serve.prefill", requests=w, rows=ns, row_len=self.max_len,
+                  real=sum(real), real_sq=sum(n * n for n in real)):
+            _, wave_cache = self._prefill(self.params, jnp.asarray(feats),
+                                          jnp.asarray(lengths), self.factors,
+                                          self.max_len)
+            if self.guards:
+                self._guard_factors(wave_cache, slots)
+            slot_ids = np.full((ns,), ns, np.int32)  # padding rows: dropped
+            slot_ids[:w] = slots
+            self._cache = self._insert(self._cache, wave_cache,
+                                       jnp.asarray(slot_ids))
         return None, np.zeros((ns,), bool)
+
+    def _guard_factors(self, wave_cache, slots: List[int]) -> None:
+        """Admission-time factor guard: the trunk ran once
+        and its cached per-layer bias state is frozen for every
+        refinement step — a NaN/Inf here (bad features, unstable
+        factorization) poisons ALL of the request's steps, so catch it
+        now, per wave row, before the engine registers the slot."""
+        ns = self.n_slots
+        flags = [jnp.isfinite(leaf).all(axis=tuple(range(1, leaf.ndim)))
+                 for leaf in jax.tree_util.tree_leaves(wave_cache)
+                 if jnp.issubdtype(leaf.dtype, jnp.floating)
+                 and leaf.ndim >= 1 and leaf.shape[0] == ns]
+        if not flags:
+            return
+        with span("serve.fetch"):
+            ok = np.asarray(functools.reduce(jnp.logical_and, flags))
+        for i, slot in enumerate(slots):
+            if not ok[i]:
+                self._guard_bad[slot] = (
+                    f"non-finite factor cache at admission of slot {slot} "
+                    f"(trunk produced NaN/Inf from the complex features)")
 
     def step(self, live):
         """One refinement iteration over every live slot (single jitted
         call; retired slots are frozen by their zero length)."""
-        self._cache = self._step(self.params, self._cache)
+        with span("serve.decode", live=len(live)):
+            self._cache = self._step(self.params, self._cache)
         mask = np.zeros((self.n_slots,), bool)
         mask[list(live)] = True
         return None, mask
 
     def fetch_result(self, slot: int, st) -> np.ndarray:
         n = st.req.tokens.shape[0]
-        return np.asarray(self._cache["s"][slot, :n], np.float32)
+        with span("serve.fetch"):
+            return np.asarray(self._cache["s"][slot, :n], np.float32)
 
     def stream_result(self, slot: int, st) -> np.ndarray:
         """Per-iteration single rep for streaming sinks: the pair backend

@@ -69,6 +69,7 @@ from repro.serve.lifecycle import (
     PoolExhausted, RequestNotLive, RequestRecord)
 from repro.serve.sampling import SamplingParams
 from repro.serve.scheduler import FIFOScheduler, Request
+from repro.serve.trace import span, tracing
 
 __all__ = ["ServeEngine"]
 
@@ -233,6 +234,7 @@ class ServeEngine:
         self.n_preemptions = 0
         self.n_quarantines = 0          # guard trips contained
         self.n_faults_contained = 0     # step/alloc faults contained
+        self.n_waves = 0                # admission waves taken
         self.scheduler = FIFOScheduler(policy=scheduler_policy)
         self._next_rid = 0
         self._results: Dict[int, object] = {}   # rid -> [ids] | result array
@@ -405,6 +407,21 @@ class ServeEngine:
     def occupancy(self) -> int:
         return len(self._live)
 
+    def counters(self) -> Dict[str, int]:
+        """Cumulative counts since the engine started (a restored engine
+        continues its snapshot's): engine ``steps``, admission ``waves``,
+        ``prefill_rows_x_len`` (rows x padded length the prefill programs
+        ran) and ``prefill_real`` (the real prompt tokens, or residues,
+        among them), ``preemptions``, ``quarantines`` and
+        ``faults_contained``. ``1 - prefill_real / prefill_rows_x_len``
+        is the share of prefill rows that were padding."""
+        return {"steps": self.step_idx, "waves": self.n_waves,
+                "prefill_rows_x_len": self.backend.n_prefill_rows_x_len,
+                "prefill_real": self.backend.n_prefill_real,
+                "preemptions": self.n_preemptions,
+                "quarantines": self.n_quarantines,
+                "faults_contained": self.n_faults_contained}
+
     def page_stats(self) -> dict:
         """Pool accounting snapshot (empty for unpaged backends)."""
         stats = self.backend.stats()
@@ -429,25 +446,26 @@ class ServeEngine:
         chunk program, so a long arrival's admission is amortized one
         chunk per step instead of stalling the whole batch behind a
         monolithic prompt prefill."""
-        self._ensure_state()
-        if self.faults is not None:
-            self.faults.tick(self.step_idx)
-        finished = self._expire_deadlines()
-        delayed = (self.faults is not None
-                   and self.faults.admission_delayed())
-        if self._free and len(self.scheduler) and not delayed:
-            finished += self.admit()
-        if self.backend.prefill_pending():
-            emissions, mask = self.backend.prefill_step()
-            finished += self._commit_guarded(emissions, mask)
-        if self._live:
-            try:
-                finished += self.decode()
-            except InjectedFault:
-                # a failed jitted step dispatched nothing and advanced no
-                # state — containment is retrying next step
-                self.n_faults_contained += 1
-        self.step_idx += 1
+        with span("serve.step", step=self.step_idx, live=len(self._live)):
+            self._ensure_state()
+            if self.faults is not None:
+                self.faults.tick(self.step_idx)
+            finished = self._expire_deadlines()
+            delayed = (self.faults is not None
+                       and self.faults.admission_delayed())
+            if self._free and len(self.scheduler) and not delayed:
+                finished += self.admit()
+            if self.backend.prefill_pending():
+                emissions, mask = self.backend.prefill_step()
+                finished += self._commit_guarded(emissions, mask)
+            if self._live:
+                try:
+                    finished += self.decode()
+                except InjectedFault:
+                    # a failed jitted step dispatched nothing and advanced
+                    # no state — containment is retrying next step
+                    self.n_faults_contained += 1
+            self.step_idx += 1
         return finished
 
     def run(self) -> None:
@@ -544,14 +562,18 @@ class ServeEngine:
         logits, the pair backend emits nothing (its budget counts
         refinement steps)."""
         self._ensure_state()
-        wave = self._take_wave()
-        if not wave:
-            return []
-        slots = [self._free.pop(0) for _ in wave]
-        emissions, mask = self.backend.admit(wave, slots)
-        for slot, r in zip(slots, wave):
-            self._live[slot] = _Slot(r, length=r.prompt_len)
-            self._meta[r.rid].status = RUNNING
+        with span("serve.admit") as sp:
+            wave = self._take_wave()
+            if not wave:
+                return []
+            if tracing():
+                sp.set_metadata(rids=";".join(str(r.rid) for r in wave))
+            self.n_waves += 1
+            slots = [self._free.pop(0) for _ in wave]
+            emissions, mask = self.backend.admit(wave, slots)
+            for slot, r in zip(slots, wave):
+                self._live[slot] = _Slot(r, length=r.prompt_len)
+                self._meta[r.rid].status = RUNNING
         return self._commit_guarded(emissions, mask)
 
     def decode(self) -> List[int]:
@@ -587,7 +609,8 @@ class ServeEngine:
                 growing = [s for s in growing if s != victim]
             if growing:
                 try:
-                    self.backend.grow_slots(growing)
+                    with span("serve.grow", slots=len(growing)):
+                        self.backend.grow_slots(growing)
                 except PoolExhausted:
                     # growth is atomic (no partial table state): preempt
                     # the growing slots — snapshots resume bit-identically
@@ -672,11 +695,13 @@ class ServeEngine:
         enters at the head of its priority class.
         """
         st = self._live.pop(slot)
-        bisect.insort(self._free, slot)
-        resumed = self.backend.snapshot(slot, st, self._results[st.req.rid])
-        self.scheduler.add_front(resumed)
-        self._meta[st.req.rid].status = QUEUED
-        self.n_preemptions += 1
+        with span("serve.preempt", rid=st.req.rid):
+            bisect.insort(self._free, slot)
+            resumed = self.backend.snapshot(slot, st,
+                                            self._results[st.req.rid])
+            self.scheduler.add_front(resumed)
+            self._meta[st.req.rid].status = QUEUED
+            self.n_preemptions += 1
         return st.req.rid
 
     # ------------------------------------------------------------------
@@ -712,14 +737,19 @@ class ServeEngine:
         that tripped the non-finite guard this call has its emission
         withheld (the backend already withheld its PRNG/token commit) and
         is quarantined; everything else commits normally."""
-        finished: List[int] = []
-        if self.guards:
-            for slot, detail in sorted(
-                    self.backend.take_guard_faults().items()):
-                if slot in self._live:
-                    mask[slot] = False
-                    finished += self._quarantine(slot, detail)
-        return finished + self._commit(emissions, mask)
+        with span("serve.commit") as sp:
+            advanced = self._advanced
+            finished: List[int] = []
+            if self.guards:
+                for slot, detail in sorted(
+                        self.backend.take_guard_faults().items()):
+                    if slot in self._live:
+                        mask[slot] = False
+                        finished += self._quarantine(slot, detail)
+            finished += self._commit(emissions, mask)
+            sp.set_metadata(emitted=self._advanced - advanced,
+                            finished=len(finished))
+        return finished
 
     # ------------------------------------------------------------------
     # Crash-safe checkpoint / restore (ISSUE 10)
@@ -794,10 +824,7 @@ class ServeEngine:
             "config": self._config_sig(),
             "step_idx": self.step_idx,
             "next_rid": self._next_rid,
-            "counters": {"preemptions": self.n_preemptions,
-                         "quarantines": self.n_quarantines,
-                         "faults_contained": self.n_faults_contained,
-                         "advanced": self._advanced},
+            "counters": dict(self.counters(), advanced=self._advanced),
             "meta": {str(rid): {"status": m.status, "error": m.error,
                                 "retries": m.retries,
                                 "max_retries": m.max_retries,
@@ -840,6 +867,11 @@ class ServeEngine:
         self.n_quarantines = counters["quarantines"]
         self.n_faults_contained = counters["faults_contained"]
         self._advanced = counters["advanced"]
+        # absent from snapshots written before these counters existed
+        self.n_waves = counters.get("waves", 0)
+        self.backend.n_prefill_rows_x_len = counters.get(
+            "prefill_rows_x_len", 0)
+        self.backend.n_prefill_real = counters.get("prefill_real", 0)
         self._meta = {int(rid): _ReqMeta(status=m["status"],
                                          error=m["error"],
                                          retries=m["retries"],

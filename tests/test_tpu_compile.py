@@ -11,6 +11,7 @@ worker that runs this file keeps it until it exits. Every test here compiles
 in its own process; none starts a child that would need the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +56,14 @@ def _arg(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args) -> str:
+def _compile(fn, *args, kernel: str) -> str:
+    """Compile ``fn`` and check that its Pallas kernel keeps the
+    ``pallas_call`` name ``kernel``: the custom call is named after it, and
+    the device trace tells kernels apart by it."""
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo, "no Pallas kernel in the program"
+    names = re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*tpu_custom_call", hlo)
+    assert names and set(names) == {kernel}, names
     return hlo
 
 
@@ -67,7 +73,7 @@ def test_alibi_causal_prefill_gpt2(one_chip):
     slopes = _arg((64,), jnp.float32, one_chip)
     _compile(lambda q, k, v, s: ops.flash_attention(
         q, k, v, slopes=s, mask_kind="causal", impl="pallas",
-        layout="bhsd"), q, q, q, slopes)
+        layout="bhsd"), q, q, q, slopes, kernel="flashbias_attn")
 
 
 def test_alibi_contiguous_decode_gpt2(one_chip):
@@ -78,7 +84,7 @@ def test_alibi_contiguous_decode_gpt2(one_chip):
     slopes = _arg((64,), jnp.float32, one_chip)
     _compile(lambda q, k, v, n, s: ops.flash_decode(
         q, k, v, n, slopes=s, impl="pallas", kv_layout="bhsd"),
-        q, kv, kv, lengths, slopes)
+        q, kv, kv, lengths, slopes, kernel="flash_decode")
 
 
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [
@@ -97,7 +103,8 @@ def test_paged_decode_shared_phi_slab(one_chip, heads, kv_heads, head_dim):
     _compile(lambda q, k, v, n, t, pq, pk: ops.flash_decode(
         q, k, v, n, phi_q=pq, phi_k=pk, impl="pallas", page_table=t,
         kv_layout="bhsd", max_pages=p_slot),
-        q, pool, pool, lengths, table, phi_q, slab)
+        q, pool, pool, lengths, table, phi_q, slab,
+        kernel="flash_decode_paged")
 
 
 def test_pairformer_phi_ragged(one_chip):
@@ -107,7 +114,7 @@ def test_pairformer_phi_ragged(one_chip):
     lengths = _arg((2,), jnp.int32, one_chip)
     _compile(lambda q, k, v, pq, pk, n: ops.flash_attention(
         q, k, v, pq, pk, impl="pallas", lengths=n),
-        qkv, qkv, qkv, phi, phi, lengths)
+        qkv, qkv, qkv, phi, phi, lengths, kernel="flashbias_attn_ragged")
 
 
 def test_ssd_scan(one_chip):
@@ -117,7 +124,7 @@ def test_ssd_scan(one_chip):
     a = _arg((24,), jnp.float32, one_chip)
     bc = _arg((1, 1, 512, 128), jnp.float32, one_chip)
     _compile(lambda x, dt, a, b, c: ssd_scan_fwd(x, dt, a, b, c, chunk=256),
-             x, dt, a, bc, bc)
+             x, dt, a, bc, bc, kernel="ssd_scan")
 
 
 def test_sharded_paged_decode(topo):
@@ -139,6 +146,7 @@ def test_sharded_paged_decode(topo):
         hlo = _compile(lambda q, k, v, n, t, pq, pk: ops.flash_decode(
             q, k, v, n, phi_q=pq, phi_k=pk, impl="pallas", page_table=t,
             kv_layout="bhsd", max_pages=p_slot),
-            q, pool, pool, lengths, table, phi_q, slab)
+            q, pool, pool, lengths, table, phi_q, slab,
+            kernel="flash_decode_paged")
     pool_shard = (h // 2) * n_pages * ps * 128
     assert collective_sizes(hlo).get("all-gather", 0) < pool_shard
