@@ -22,6 +22,10 @@ One new token per request attends to a cache of up to S keys. TPU adaptation:
 ``flash_decode_paged_fwd`` is the PAGED variant: the KV cache (and the
 per-page ``phi_k`` factor slab) lives in a shared page pool and each
 request's pages are resolved through a scalar-prefetched page table. The
+pool may be the whole layer stack ``(L, KVH, n_pages, ps, *)``: a third
+scalar-prefetch operand names the layer, and the index maps read its pages
+straight out of the stack, so the model's layer scan never materialises one
+layer's pool. The
 kernel BODY is shared with the contiguous path — grid axis j is the
 *logical* block index (page_size == block_k), so position math and the
 length-based block skipping are unchanged; only the block index maps
@@ -193,23 +197,26 @@ def flash_decode_fwd(
     return out
 
 
-def _paged_decode_kernel(lengths_ref, page_table_ref, *rest, **kw):
-    # page resolution happens entirely in the block index maps; the body is
-    # the contiguous kernel verbatim (j stays the LOGICAL block index)
-    del page_table_ref
+def _paged_decode_kernel(lengths_ref, page_table_ref, layer_ref, *rest,
+                         **kw):
+    # page and layer resolution happen entirely in the block index maps;
+    # the body is the contiguous kernel verbatim (j stays the LOGICAL block
+    # index)
+    del page_table_ref, layer_ref
     _decode_kernel(lengths_ref, *rest, **kw)
 
 
 def flash_decode_paged_fwd(
     q: jax.Array,                         # (B, KVH, G, D)
-    k_pages: jax.Array,                   # (KVH, n_pages, ps, D)
-    v_pages: jax.Array,                   # (KVH, n_pages, ps, Dv)
+    k_pages: jax.Array,                   # ([L,] KVH, n_pages, ps, D)
+    v_pages: jax.Array,                   # ([L,] KVH, n_pages, ps, Dv)
     lengths: jax.Array,                   # (B,) int32
     page_table: jax.Array,                # (B, P) int32 page ids
     phi_q: Optional[jax.Array] = None,    # (B, KVH, G, R)
     phi_pages: Optional[jax.Array] = None,  # (KVH|1, n_pages, ps, R)
     slopes: Optional[jax.Array] = None,   # (KVH, G)
     *,
+    layer: Optional[jax.Array] = None,    # int32 scalar or (1,)
     scale: float,
     interpret: bool = False,
 ) -> jax.Array:
@@ -221,41 +228,53 @@ def flash_decode_paged_fwd(
     skips). Every page id is clamped into the pool, so a stale table can
     never fault — at worst it reads a page the length mask then discards.
 
+    With ``layer`` the pools are the stacked ``(L, KVH, n_pages, ps, *)``
+    cache and the kernel reads layer ``layer`` in place; without it they
+    are one layer's ``(KVH, n_pages, ps, *)`` pool (read as a stack of one,
+    a reshape that moves nothing).
+
     ``phi_pages`` with a leading kv-head axis of 1 is the layer/kv-head-
     shared factor slab: its index map pins the head coordinate to 0, so the
     kv-head broadcast costs nothing (same block, every head).
     """
+    if layer is None:
+        k_pages = k_pages.reshape(1, *k_pages.shape)
+        v_pages = v_pages.reshape(1, *v_pages.shape)
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     b, kvh, g, d = q.shape
-    n_pages, ps = k_pages.shape[1], k_pages.shape[2]
+    n_layers, _, n_pages, ps = k_pages.shape[:4]
     p_max = page_table.shape[1]
     dv = v_pages.shape[-1]
     bias_mode = ("phi" if phi_q is not None
                  else ("alibi" if slopes is not None else "none"))
 
-    def _page_map(h_of):
-        def m(b_, h_, j, lens_ref, pt_ref):
-            # clamp j to the last in-length block so skipped/unmapped blocks
-            # alias the previous DMA; clamp the id so stale tables stay
-            # in-pool
-            last = jnp.maximum(lens_ref[b_] - 1, 0) // ps
-            page = pt_ref[b_, jnp.minimum(j, last)]
-            return (h_of(h_), jnp.clip(page, 0, n_pages - 1), 0, 0)
-        return m
+    def _page(b_, j, lens_ref, pt_ref):
+        # clamp j to the last in-length block so skipped/unmapped blocks
+        # alias the previous DMA; clamp the id so stale tables stay in-pool
+        last = jnp.maximum(lens_ref[b_] - 1, 0) // ps
+        return jnp.clip(pt_ref[b_, jnp.minimum(j, last)], 0, n_pages - 1)
 
-    page_map = _page_map(lambda h_: h_)
+    def pool_map(b_, h_, j, lens_ref, pt_ref, layer_ref):
+        layer_ = jnp.clip(layer_ref[0], 0, n_layers - 1)
+        return (layer_, h_, _page(b_, j, lens_ref, pt_ref), 0, 0)
 
     grid = (b, kvh, p_max)
     in_specs = [
         pl.BlockSpec((1, 1, g, d), lambda b_, h_, j, *_: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, 1, ps, d), page_map),
-        pl.BlockSpec((1, 1, ps, dv), page_map),
+        pl.BlockSpec((pl.squeezed, 1, 1, ps, d), pool_map),
+        pl.BlockSpec((pl.squeezed, 1, 1, ps, dv), pool_map),
     ]
     args = [q, k_pages, v_pages]
     if bias_mode == "phi":
         r = phi_q.shape[-1]
-        # kv-head-shared slab (leading axis 1): broadcast via the index map
-        phi_map = (_page_map(lambda h_: 0) if phi_pages.shape[0] == 1
-                   else page_map)
+        shared = phi_pages.shape[0] == 1
+
+        def phi_map(b_, h_, j, lens_ref, pt_ref, layer_ref):
+            # kv-head-shared slab (leading axis 1): every head reads head
+            # 0's block, so the broadcast happens in the index map
+            return (0 if shared else h_, _page(b_, j, lens_ref, pt_ref), 0, 0)
+
         in_specs += [
             pl.BlockSpec((1, 1, g, r), lambda b_, h_, j, *_: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, ps, r), phi_map),
@@ -274,7 +293,7 @@ def flash_decode_paged_fwd(
     kernel = functools.partial(_paged_decode_kernel, scale=scale, block_k=ps,
                                bias_mode=bias_mode)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, dv),
@@ -291,5 +310,5 @@ def flash_decode_paged_fwd(
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         interpret=interpret,
         name="flash_decode_paged",
-    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), *args)
+    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), layer, *args)
     return out

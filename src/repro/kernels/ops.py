@@ -21,7 +21,9 @@ factor broadcast of anything pool-sized:
   block (128 is always safe) for the zero-copy guarantee; other shapes fall
   back to a documented pad (correctness, not speed).
 - paged KV: pools ``(KVH, n_pages, ps, hd_pad)`` per layer, ``hd_pad`` lane-
-  padded at ``init_paged_cache``; the per-page ``phi_k`` factor slab stays
+  padded at ``init_paged_cache``; the model's decode step passes the whole
+  ``(L, KVH, n_pages, ps, hd_pad)`` stack with ``layer=`` and the kernel
+  reads that layer in place; the per-page ``phi_k`` factor slab stays
   layer- AND kv-head-shared at ``(n_pages, ps, r_pad)`` — the kv-head
   broadcast happens in the kernel's block index maps, never as a
   ``broadcast_to`` on the pool.
@@ -466,6 +468,7 @@ def flash_decode(
     page_table: Optional[jax.Array] = None,  # (B, P_slot) int32 -> paged mode
     kv_layout: str = "bshd",
     max_pages: Optional[int] = None,
+    layer: Optional[jax.Array] = None,   # int32 scalar: paged layer stack
 ) -> jax.Array:
     """Single-token decode against a KV cache. Returns (B, 1, H, Dv).
 
@@ -489,13 +492,22 @@ def flash_decode(
     XLA fallback gathers each request's logical view first, capped at
     ``ceil(max(lengths)/page_size)`` pages (see ``max_pages``) instead of
     the full table width.
+
+    ``layer`` (paged only): the pools are the model's whole layer stack,
+    ``(L, *pool)``, and the call attends over layer ``layer``. The kernel-
+    layout Pallas path reads that layer in place (a scalar-prefetch operand
+    of the kernel), the XLA paths index it inside their page gather, and
+    the canonical layout slices it out (the legacy cost). ``None``: the
+    pools are one layer's.
     """
     assert kv_layout in ("bshd", "bhsd"), kv_layout
     if page_table is not None:
         return _flash_decode_paged(q, k_cache, v_cache, lengths, page_table,
                                    phi_q, phi_k, slopes, scale=scale,
                                    impl=impl, block_k=block_k,
-                                   kv_layout=kv_layout, max_pages=max_pages)
+                                   kv_layout=kv_layout, max_pages=max_pages,
+                                   layer=layer)
+    assert layer is None, "layer= indexes a paged pool stack"
     b, _, h, d = q.shape
     if kv_layout == "bhsd":
         kvh, s_len = k_cache.shape[1], k_cache.shape[2]
@@ -660,11 +672,14 @@ def _grouped_slopes(slopes, kvh: int, g: int):
 
 def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
                         phi_q, phi_k, slopes, *, scale, impl, block_k,
-                        kv_layout="bshd", max_pages=None):
+                        kv_layout="bshd", max_pages=None, layer=None):
     """Paged dispatch for ``flash_decode`` (see its docstring for layouts)."""
     b, _, h, d = q.shape
+    if layer is not None and kv_layout == "bshd":
+        # canonical pools adapt per call anyway: take the layer's pool
+        k_pages, v_pages, layer = k_pages[layer], v_pages[layer], None
     if kv_layout == "bhsd":
-        kvh, n_pages, ps = k_pages.shape[:3]
+        kvh, n_pages, ps = k_pages.shape[-4:-1]
     else:
         n_pages, ps, kvh = k_pages.shape[:3]
     dv = v_pages.shape[-1]
@@ -680,9 +695,11 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
         # pool-sized stays in the gather's native (KVH, B, S, E) axis
         # order end to end; only token-sized tensors (q, the output)
         # transpose, so XLA never copies the view.
-        def view(pool):                           # -> (KVH, B, S_view, E)
-            return pool[:, pt].reshape(kvh, b, p_cap * ps, pool.shape[-1])
-        gk, gv = view(k_pages[..., :d]), view(v_pages[..., :dv])
+        def view(pool, e):                        # -> (KVH, B, S_view, e)
+            if layer is not None:
+                pool = pool[layer]
+            return pool[:, pt, :, :e].reshape(kvh, b, p_cap * ps, e)
+        gk, gv = view(k_pages, d), view(v_pages, dv)
         if impl == "io_stub":
             dep = gk.astype(jnp.float32).sum() + gv.astype(jnp.float32).sum()
             if phi_k is not None:
@@ -756,8 +773,8 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
         return _pad_axis(_pad_axis(x, 2, g_p), 3, last_p)
 
     if kv_layout == "bhsd":
-        kt = _pad_axis(k_pages, 3, d_p)          # no-op: pools lane-padded
-        vt = _pad_axis(v_pages, 3, dv_p)
+        kt = _pad_axis(k_pages, -1, d_p)         # no-op: pools lane-padded
+        vt = _pad_axis(v_pages, -1, dv_p)
     else:
         def to_pool(x, last_p):
             # (n_pages, ps, KVH, E) -> (KVH, n_pages, ps, E_pad)
@@ -793,13 +810,17 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
                                interpret=(impl == "pallas_interpret"))
     bh = ("batch", "kv_heads", None, None)
     pool = ("kv_heads", None, None, None)
+    # the layer stack's leading axis is whole on every device
+    stack = pool if layer is None else (None,) + pool
     out = _per_shard(
         kernel,
         dict(q=qt, k_pages=kt, v_pages=vt, lengths=lengths, page_table=pt,
              phi_q=pqt, phi_pages=pkt,
-             slopes=_grouped_slopes(slopes, kvh, g)),
-        dict(q=bh, k_pages=pool, v_pages=pool, lengths=("batch",),
-             page_table=("batch", None), phi_q=bh,
+             slopes=_grouped_slopes(slopes, kvh, g),
+             layer=None if layer is None
+             else jnp.asarray(layer, jnp.int32).reshape(1)),
+        dict(q=bh, k_pages=stack, v_pages=stack, lengths=("batch",),
+             page_table=("batch", None), phi_q=bh, layer=(None,),
              # the layer/kv-head-shared slab (leading 1) is whole everywhere
              phi_pages=(pool if pkt is not None and pkt.shape[0] == kvh
                         else (None,) * 4),

@@ -210,7 +210,7 @@ def _attention(lp: dict, x: jax.Array, cfg: ArchConfig, *,
 
 def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
                       cfg: ArchConfig, *, active=None, page_table=None,
-                      phi_pages=None, max_pages=None):
+                      phi_pages=None, max_pages=None, layer=None):
     """One-token attention against a (ring / full / paged) cache.
 
     ``active`` (B,) bool freezes retired slot rows: their KV writes are
@@ -228,12 +228,15 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
     KVH, hd)``) is kept as the layout_vs_legacy A/B + parity reference and
     pays ops' per-call adaptation.
 
-    Paged mode (``page_table`` given): the new token is written through the
-    slot's page table. ``phi_pages`` is the per-page ALiBi key factor slab
-    (layer- and kv-head-shared; lane-padded ``(n_pages, ps, r_pad)`` under
-    the kernel layout); when present the bias is computed from the CACHED
-    factors (phi mode — factors ride with k, FlashBias Sec. 4.3) instead
-    of re-materializing positions. ``max_pages`` statically caps the pages
+    Paged mode (``page_table`` given): the pools are the whole ``(L, ...)``
+    stack and ``layer`` names this layer. The new token's rows land in
+    that layer in place, through the slot's page table, and the kernel
+    reads the layer straight out of the stack, so no per-layer pool is
+    sliced out or stacked back. ``phi_pages`` is the per-page ALiBi key
+    factor slab (layer- and kv-head-shared; lane-padded ``(n_pages, ps,
+    r_pad)`` under the kernel layout); when present the bias is computed
+    from the CACHED factors (phi mode — factors ride with k, FlashBias
+    Sec. 4.3) instead of re-materializing positions. ``max_pages`` statically caps the pages
     any request can reference (the serve engine derives it from host-side
     lengths).
     """
@@ -263,23 +266,29 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
     # would count a full cache read+write per layer in cost_analysis.
     skip_scatter = cfg.attn_impl == "io_stub"
     if page_table is not None:                     # paged full cache
-        if kernel_layout:                          # (KVH, n_pages, ps, hd_p)
+        if kernel_layout:                    # (L, KVH, n_pages, ps, hd_pad)
+            n_pages, ps = k_cache.shape[2], k_cache.shape[3]
+        else:                                # (L, n_pages, ps, KVH, hd)
             n_pages, ps = k_cache.shape[1], k_cache.shape[2]
-        else:                                      # (n_pages, ps, KVH, hd)
-            n_pages, ps = k_cache.shape[0], k_cache.shape[1]
         pos = lengths - 1
         page = drop_if_frozen(page_table[bidx, pos // ps], n_pages)
         if not skip_scatter:
             if kernel_layout:
-                kr = new_row(k_new, k_cache).transpose(1, 0, 2)
-                vr = new_row(v_new, v_cache).transpose(1, 0, 2)
-                k_cache = k_cache.at[:, page, pos % ps].set(kr, mode="drop")
-                v_cache = v_cache.at[:, page, pos % ps].set(vr, mode="drop")
+                # one scatter of the B x KVH token rows, every axis but the
+                # lanes indexed: a window of lanes alone keeps the pool in
+                # the layout the kernel reads (a window over kv heads too
+                # makes XLA relay the whole stack out around the scatter)
+                kv = jnp.arange(k_cache.shape[1])[None, :]
+                at = (layer, kv, page[:, None], (pos % ps)[:, None])
+                k_cache = k_cache.at[at].set(new_row(k_new, k_cache),
+                                             mode="drop")
+                v_cache = v_cache.at[at].set(new_row(v_new, v_cache),
+                                             mode="drop")
             else:
-                k_cache = k_cache.at[page, pos % ps].set(k_new[:, 0],
-                                                         mode="drop")
-                v_cache = v_cache.at[page, pos % ps].set(v_new[:, 0],
-                                                         mode="drop")
+                k_cache = k_cache.at[layer, page, pos % ps].set(
+                    k_new[:, 0], mode="drop")
+                v_cache = v_cache.at[layer, page, pos % ps].set(
+                    v_new[:, 0], mode="drop")
         phi_q = phi_k = None
         if slopes is not None and phi_pages is not None:
             # same rank-2 q factor the ops ALiBi path materializes; the key
@@ -293,7 +302,8 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
         o = kops.flash_decode(q, k_cache, v_cache, lengths, phi_q=phi_q,
                               phi_k=phi_k, slopes=slopes, impl=cfg.attn_impl,
                               block_k=cfg.attn_chunk, page_table=page_table,
-                              kv_layout=kv_layout, max_pages=max_pages)
+                              kv_layout=kv_layout, max_pages=max_pages,
+                              layer=layer)
         # lane-padded pools return lane-padded values; the pad rows are
         # zero so slicing them off is exact (token-sized, not pool-sized)
         o = o[..., :v_new.shape[-1]]
@@ -761,7 +771,9 @@ def _layer_prefill(lp: dict, x: jax.Array, cfg: ArchConfig, lengths=None):
 
 def _layer_decode(lp: dict, cache_l: dict, x: jax.Array, lengths,
                   cfg: ArchConfig, *, active=None, page_table=None,
-                  phi_pages=None, max_pages=None):
+                  phi_pages=None, max_pages=None, layer=None):
+    """One layer of a decode step: layer ``layer`` of ``cache_l``'s page
+    pools, which are the whole layer stack (paged caches only)."""
     new_cache = dict(cache_l)
     h = rmsnorm(x, lp["ln1"])
     if cfg.family in ("dense", "moe", "hybrid"):
@@ -770,7 +782,8 @@ def _layer_decode(lp: dict, cache_l: dict, x: jax.Array, lengths,
         y, kc, vc = _attention_decode(
             lp["attn"], h, cache_l[kk], cache_l[vv], lengths, cfg,
             active=active, page_table=page_table if paged else None,
-            phi_pages=phi_pages if paged else None, max_pages=max_pages)
+            phi_pages=phi_pages if paged else None, max_pages=max_pages,
+            layer=layer)
         new_cache[kk], new_cache[vv] = kc, vc
     if cfg.family in ("ssm", "hybrid"):
         ys, hs, tx, tbc = _ssm_decode(lp["ssm"], h, cache_l["ssm_h"],
@@ -1022,6 +1035,14 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, *, max_pages=None):
     rounding of its host-side longest live length, which bounds the paged
     XLA fallback's gather at Θ(longest request) instead of the full
     page-table width.
+
+    The paged pools ride the layer scan's CARRY as the whole ``(L, ...)``
+    stack: each layer writes its token rows into the stack in place and
+    the kernel reads its layer out of the stack by index, so a step moves
+    token rows, never a layer's pool (scanning the pools as ``xs``/``ys``
+    would slice each layer's pool out and stack it back). With the cache
+    donated, the pools are updated in the caller's buffers. Per-slot
+    leaves (contiguous KV, SSM state) are scanned over as before.
     """
     active = cache["length"] > 0
     lengths = cache["length"] + active.astype(jnp.int32)
@@ -1029,9 +1050,9 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, *, max_pages=None):
 
     paged = "pages_k" in cache
     page_table = cache.get("page_table")
-    leaf_keys = (("pages_k", "pages_v") if paged else ("k", "v")) \
-        + ("ssm_h", "conv_x", "conv_bc")
-    layer_cache = {k: cache[k] for k in leaf_keys if k in cache}
+    pools = {k: cache[k] for k in ("pages_k", "pages_v") if k in cache}
+    layer_cache = {k: cache[k] for k in ("k", "v", "ssm_h", "conv_x",
+                                          "conv_bc") if k in cache}
 
     new_cache = dict(cache)
     if paged and "pages_phi" in cache:
@@ -1053,20 +1074,24 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, *, max_pages=None):
     else:
         phi_pages = None
 
-    def body(x, inp):
-        lp, cl = inp
-        x, ncl = _layer_decode(lp, cl, x, lengths, cfg, active=active,
-                               page_table=page_table, phi_pages=phi_pages,
-                               max_pages=max_pages)
-        return x, ncl
+    def body(carry, inp):
+        x, pools = carry
+        lp, layer, cl = inp
+        x, ncl = _layer_decode(lp, {**cl, **pools}, x, lengths, cfg,
+                               active=active, page_table=page_table,
+                               phi_pages=phi_pages, max_pages=max_pages,
+                               layer=layer)
+        pools = {k: ncl.pop(k) for k in pools}
+        return (x, pools), ncl
 
-    x, new_layer_cache = jax.lax.scan(body, x,
-                                      (_compute_layers(params, cfg),
-                                       layer_cache),
-                                      unroll=flags.scan_unroll(cfg.n_layers))
+    (x, pools), new_layer_cache = jax.lax.scan(
+        body, (x, pools),
+        (_compute_layers(params, cfg), jnp.arange(cfg.n_layers), layer_cache),
+        unroll=flags.scan_unroll(cfg.n_layers))
     hid = rmsnorm(x, params["final_norm"])
     logits = unembed_logits(hid, params["embed"].astype(hid.dtype))
     new_cache.update(new_layer_cache)
+    new_cache.update(pools)
     new_cache["length"] = lengths
     return logits, new_cache
 

@@ -301,24 +301,45 @@ class TokenDecodeBackend(Backend):
             return model.prefill(p, batch, max_len=max_len, lengths=lengths)
 
         self._prefill = self._jit("_pf", _pf, static_argnames=("max_len",))
+        # Every program that takes the cache and returns its successor
+        # DONATES it, so the page pools are updated in their own buffers
+        # instead of being copied whole into fresh ones. A donated cache is
+        # dead after the call: ``_cache`` is replaced by each result.
+        #
         # max_pages is a STATIC cap on the pages a paged decode step may
         # reference: the engine passes a power-of-two rounding of its
         # host-mirrored longest live length, so the paged XLA fallback
         # gathers Θ(longest request) instead of the full page-table width
         # while recompiling at most log2(pages_per_slot) times.
-        self._decode = self._jit("decode", model.decode,
-                                 static_argnames=("max_pages",))
-        self._insert = self._jit("insert", model.insert_cache)
+        decode = self._jit("decode", model.decode, donate_argnums=1,
+                           static_argnames=("max_pages",))
+
+        def run_decode(params, cache, tokens, max_pages=None):
+            # the backend's cache goes in and its successor becomes the
+            # backend's cache, so a caller that drops the result (a
+            # warm-up) still leaves a live cache behind
+            logits, self._cache = decode(params, cache, tokens,
+                                         max_pages=max_pages)
+            return logits, self._cache
+
+        run_decode.lower, run_decode.trace = decode.lower, decode.trace
+        self._decode = run_decode
+        self._insert = self._jit("insert", model.insert_cache,
+                                 donate_argnums=0)
         if self.paged:
-            self._insert_paged = self._jit("insert_paged", model.insert_paged)
+            self._insert_paged = self._jit("insert_paged", model.insert_paged,
+                                           donate_argnums=0)
             self._grow_tables = self._jit("grow_tables",
-                                          model.grow_page_table)
+                                          model.grow_page_table,
+                                          donate_argnums=0)
         if self._prefix is not None:
             # fixed-shape CoW program: (n_slots,) src/dst page ids per
             # call, out-of-range dst ids dropped — compiles once
-            self._copy_pages = self._jit("copy_pages", model.copy_pages)
+            self._copy_pages = self._jit("copy_pages", model.copy_pages,
+                                         donate_argnums=0)
         if self.chunk_size:
             self._chunk = self._jit("prefill_chunk", model.prefill_chunk,
+                                    donate_argnums=1,
                                     static_argnames=("max_pages",))
 
     def _jit(self, name: str, fn, **kw):

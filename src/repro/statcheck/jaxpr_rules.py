@@ -15,8 +15,9 @@ the legacy layout's per-layer pool transpose actually lives.
 
 Calibration notes (empirically pinned by ``tests/test_statcheck.py``):
 
-- Pool-sized means "at least one full per-layer KV slab": cache leaves
-  enter the layer scan sliced along L, so the threshold is
+- Pool-sized means "at least one full per-layer KV slab": contiguous
+  cache leaves enter the layer scan sliced along L (paged pools ride it
+  whole, which only makes a relayout of them larger), so the threshold is
   ``min(leaf.size // n_layers)`` over the K/V leaves, not the whole-leaf
   size. Token-batch operands (Θ(B·H·D)) sit orders of magnitude below it.
 - The GOOD kernel-native layout emits zero banned pool-sized eqns under

@@ -9,6 +9,8 @@ Plus regression tests for two GQA phi_k bugs: the full-attention XLA path
 used to collapse per-kv-head factors to kv head 0, and the Pallas decode
 path used to raise on them.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -210,3 +212,99 @@ class TestPagedDecodeParity:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), atol=3e-5,
                                    err_msg=f"paged {impl}/{mode}")
+
+
+class TestCarriedPoolDecodeStep:
+    """``lm.decode_step`` carries the whole ``(L, ...)`` page pools through
+    its layer scan, writes token rows into them in place and reads layer l
+    out of the stack. It must give what a scan over the layers' pools
+    gives, each layer's pools sliced out, decoded as a stack of one and
+    stacked back: the same logits and pools, bitwise under XLA and to fp32
+    tolerance in the interpreted kernel. Slot 0 writes the first row of a
+    new page, slot 1 the middle of one, and slot 2 is frozen (length 0):
+    exactly those two rows change, its pages stay as they were."""
+
+    PS, N_PAGES, P_SLOT = 4, 12, 3
+    LENGTHS = np.array([4, 6, 0], np.int32)      # next rows: 4 (page 1), 6
+    TABLE = np.array([[7, 2, 9], [0, 11, 5], [3, 8, 1]], np.int32)
+
+    @staticmethod
+    def _per_layer(params, cache, tokens, cfg):
+        """The reference: the layer scan slices each layer's pools out
+        (``xs``), decodes them as a stack of one and stacks the results back
+        (``ys``)."""
+        from repro.models import lm
+        active = cache["length"] > 0
+        lengths = cache["length"] + active.astype(jnp.int32)
+        x = lm._embed_in(params, tokens, None, cfg)
+        phi = cache["pages_phi"]
+        pos = lengths - 1
+        page = jnp.where(active, cache["page_table"][jnp.arange(pos.size),
+                                                     pos // phi.shape[1]],
+                         phi.shape[0])
+        row = jnp.zeros((pos.size, phi.shape[-1]), jnp.float32)
+        row = row.at[:, 0].set(1.0).at[:, 1].set(pos.astype(jnp.float32))
+        phi = phi.at[page, pos % phi.shape[1]].set(row, mode="drop")
+
+        def body(x, inp):
+            lp, cl = inp
+            x, ncl = lm._layer_decode(
+                lp, {k: v[None] for k, v in cl.items()}, x, lengths, cfg,
+                active=active, page_table=cache["page_table"],
+                phi_pages=phi, layer=0)
+            return x, {k: v[0] for k, v in ncl.items()}
+
+        pools = {k: cache[k] for k in ("pages_k", "pages_v")}
+        x, pools = jax.lax.scan(body, x, (lm._compute_layers(params, cfg),
+                                          pools))
+        hid = lm.rmsnorm(x, params["final_norm"])
+        logits = lm.unembed_logits(hid, params["embed"].astype(hid.dtype))
+        return logits, {**cache, **pools, "pages_phi": phi,
+                        "length": lengths}
+
+    @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+    @pytest.mark.parametrize("arch,layout", [
+        ("gpt2_alibi_15b", "kernel"),      # MHA
+        ("stablelm_12b", "kernel"),        # GQA 4 / 2
+        ("stablelm_12b", "legacy"),        # canonical pools, sliced per layer
+    ], ids=["mha", "gqa", "gqa-legacy"])
+    def test_matches_per_layer_loop(self, arch, layout, impl):
+        from repro.configs import smoke_config
+        from repro.models import get_model, lm
+        from repro.models.common import init_params
+        cfg = smoke_config(arch).replace(attn_impl=impl, cache_layout=layout)
+        model = get_model(cfg)
+        params = init_params(model.template(), jax.random.PRNGKey(3))
+        cache = lm.init_paged_cache(cfg, 3, self.N_PAGES, self.PS,
+                                    self.P_SLOT)
+        ks = jax.random.split(jax.random.PRNGKey(4), 3)
+        for key, leaf in zip(ks, ("pages_k", "pages_v", "pages_phi")):
+            cache[leaf] = jax.random.normal(key, cache[leaf].shape,
+                                            cache[leaf].dtype)
+        cache["length"] = jnp.asarray(self.LENGTHS)
+        cache["page_table"] = jnp.asarray(self.TABLE)
+        tokens = jnp.asarray([[5], [17], [9]], jnp.int32)
+
+        got_logits, got = jax.jit(functools.partial(
+            lm.decode_step, cfg=cfg))(params, cache, tokens)
+        want_logits, want = jax.jit(functools.partial(
+            self._per_layer, cfg=cfg))(params, cache, tokens)
+
+        check = (np.testing.assert_array_equal if impl == "xla" else
+                 functools.partial(np.testing.assert_allclose, rtol=1e-5,
+                                   atol=1e-5))
+        check(np.asarray(got_logits), np.asarray(want_logits))
+        for leaf in ("pages_k", "pages_v", "pages_phi", "length"):
+            check(np.asarray(got[leaf]), np.asarray(want[leaf]),
+                  err_msg=leaf)
+        # the token rows landed at (page 2, row 0) and (page 11, row 2), in
+        # every layer and kv head; nothing else moved, so the frozen slot's
+        # pages are bit-identical to the input
+        page_axis = 2 if layout == "kernel" else 1
+        for leaf in ("pages_k", "pages_v"):
+            diff = np.asarray(got[leaf]) != np.asarray(cache[leaf])
+            rows = np.moveaxis(diff, (page_axis, page_axis + 1), (0, 1))
+            changed = np.any(rows, axis=tuple(range(2, rows.ndim)))
+            assert sorted(zip(*np.nonzero(changed))) == [(2, 0), (11, 2)]
+            for page, row in ((2, 0), (11, 2)):  # every layer and kv head
+                assert np.any(rows[page, row], axis=-1).all(), leaf

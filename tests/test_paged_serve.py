@@ -238,3 +238,40 @@ def test_retired_slot_frozen_contiguous_too():
         assert int(np.asarray(eng._cache["length"])[slot]) == 0
     np.testing.assert_array_equal(np.asarray(eng._cache["k"][:, slot]),
                                   k_before)
+
+
+@pytest.mark.parametrize("reservation", ["whole", "lazy"])
+def test_cache_programs_donate_the_pools(reservation):
+    """Every backend program that takes the cache and returns its successor
+    donates it: after an admission and after a decode step the page pools
+    handed in are deleted (their buffers hold the successor), and a
+    multi-request run, with lazy page growth or without, emits exactly the
+    tokens of the same engine whose programs copy the cache instead."""
+    cfg, model, params = _model("stablelm_12b")
+    kw = {"max_len": 48, "n_slots": 2, "prefill_len": PF, "page_size": 4,
+          "page_reservation": reservation}
+    prompts = _prompts(cfg, (5, 9, 7), seed=5)
+    budgets = [9, 6, 8]
+
+    def run(donating):
+        eng = ServeEngine(model, params, **kw)
+        be = eng.backend
+        if not donating:
+            # the same programs as jitted before donation: each call
+            # writes its successor into a fresh buffer
+            be._insert_paged = jax.jit(model.insert_paged)
+            be._grow_tables = jax.jit(model.grow_page_table)
+            be._decode = jax.jit(model.decode, static_argnames="max_pages")
+        rids = [eng.submit(p, b) for p, b in zip(prompts, budgets)]
+        be.ensure_state()
+        pools = [be._cache["pages_k"], be._cache["pages_v"]]
+        eng.admit()
+        assert all(p.is_deleted() == donating for p in pools)
+        pools = [be._cache["pages_k"], be._cache["pages_v"]]
+        eng.decode()
+        assert all(p.is_deleted() == donating for p in pools)
+        eng.run()
+        return [eng.result(r) for r in rids]
+
+    for i, (got, want) in enumerate(zip(run(True), run(False))):
+        np.testing.assert_array_equal(got, want, err_msg=f"request {i}")

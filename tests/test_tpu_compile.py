@@ -19,9 +19,13 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.configs import get_config
 from repro.dist import Rules, make_mesh, use_mesh_rules
 from repro.kernels import ops
 from repro.kernels.ssd_scan import ssd_scan_fwd
+from repro.models import get_model
+from repro.models.common import abstract_params
+from repro.serve.backend import TokenDecodeBackend
 from repro.statcheck.mesh_rules import collective_sizes
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
@@ -130,6 +134,17 @@ def test_ssd_scan(one_chip):
 def test_sharded_paged_decode(topo):
     """gpt2 paged decode on a (data 2, model 2) mesh: the kernel runs per
     shard inside shard_map, and nothing pool-sized is all-gathered."""
+    _sharded_paged_decode(topo, stacked=False)
+
+
+def test_sharded_paged_decode_layer_stack(topo):
+    """The same with the pools a two-layer stack read at a traced layer
+    index, as the model's decode step passes them: the layer axis stays
+    whole on every device."""
+    _sharded_paged_decode(topo, stacked=True)
+
+
+def _sharded_paged_decode(topo, stacked):
     mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices)
     b, h, ps, n_pages, p_slot = 4, 64, 128, 12, 3
 
@@ -137,16 +152,91 @@ def test_sharded_paged_decode(topo):
         return _arg(shape, dtype, NamedSharding(mesh, P(*spec)))
 
     q = arg((b, 1, h, 32), jnp.bfloat16, "data", None, "model", None)
-    pool = arg((h, n_pages, ps, 128), jnp.bfloat16, "model")
+    if stacked:
+        pool = arg((2, h, n_pages, ps, 128), jnp.bfloat16, None, "model")
+    else:
+        pool = arg((h, n_pages, ps, 128), jnp.bfloat16, "model")
+    layer = arg((), jnp.int32) if stacked else None
     lengths = arg((b,), jnp.int32, "data")
     table = arg((b, p_slot), jnp.int32, "data")
     phi_q = arg((b, 1, h, 2), jnp.float32, "data", None, "model", None)
     slab = arg((n_pages, ps, 128), jnp.float32)
     with use_mesh_rules(mesh, Rules()):
-        hlo = _compile(lambda q, k, v, n, t, pq, pk: ops.flash_decode(
+        hlo = _compile(lambda q, k, v, n, t, pq, pk, layer: ops.flash_decode(
             q, k, v, n, phi_q=pq, phi_k=pk, impl="pallas", page_table=t,
-            kv_layout="bhsd", max_pages=p_slot),
-            q, pool, pool, lengths, table, phi_q, slab,
+            kv_layout="bhsd", max_pages=p_slot, layer=layer),
+            q, pool, pool, lengths, table, phi_q, slab, layer,
             kernel="flash_decode_paged")
     pool_shard = (h // 2) * n_pages * ps * 128
     assert collective_sizes(hlo).get("all-gather", 0) < pool_shard
+
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(", re.M)
+
+
+def _roots(hlo: str) -> dict:
+    """Opcode of each computation's ROOT instruction, by computation name."""
+    roots, comp = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) ", line)
+        if head:
+            comp = head.group(1)
+        root = re.match(r"^\s*ROOT %[\w.-]+ = .*?\S ([\w-]+)\(", line)
+        if root and comp:
+            roots[comp] = root.group(1)
+    return roots
+
+
+def test_paged_decode_step_updates_pool_in_place(one_chip):
+    """gpt2_alibi_15b's paged decode step as the serve backend jits it (32
+    padded heads in 128 lanes, 32 pages of 128; 4 of its 48 layers): the
+    donated K/V pools alias the program's outputs, and no instruction but
+    an in-place scatter of token rows produces an array shaped like the
+    whole pool or like one layer's pool: no copy, slice or restack of it."""
+    cfg = get_config("gpt2_alibi_15b").replace(
+        n_layers=4, n_heads=25, n_kv_heads=25, head_dim=64,
+        attn_impl="pallas")
+    model = get_model(cfg)
+    n_slots, n_pages, ps, per_slot = 8, 32, 128, 6
+    params = abstract_params(model.template())
+    be = TokenDecodeBackend(model, params, max_len=768, n_slots=n_slots,
+                            prefill_len=256, page_size=ps, n_pages=n_pages,
+                            pages_per_slot=per_slot,
+                            page_reservation="whole")
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        n_slots, n_pages, ps, per_slot))
+    pool = (cfg.n_layers, 32, n_pages, ps, 128)
+    assert cache["pages_k"].shape == pool
+
+    def place(x):
+        return _arg(x.shape, x.dtype, one_chip)
+
+    hlo = be._decode.lower(
+        jax.tree.map(place, params), jax.tree.map(place, cache),
+        _arg((n_slots, 1), jnp.int32, one_chip),
+        max_pages=4).compile().as_text()
+
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                        hlo)
+    aliased = {int(n) for n in re.findall(r"\((\d+), \{",
+                                          aliases.group(1) if aliases else "")}
+    for leaf in ("pages_k", "pages_v"):
+        param = re.search(
+            r"%cache__" + leaf + r"__\S* = \S+ parameter\((\d+)\)", hlo)
+        assert param and int(param.group(1)) in aliased, leaf
+
+    roots = _roots(hlo)
+    shapes = {pool, pool[1:]}
+    moved = []
+    for name, dims, opcode in _INSTR.findall(hlo):
+        shape = tuple(int(d) for d in dims.split(",") if d and d != "1")
+        if shape not in shapes or opcode in ("parameter",
+                                             "get-tuple-element", "scatter"):
+            continue
+        called = re.search(
+            r"%" + re.escape(name) + r" = [^\n]*calls=%([\w.-]+)", hlo)
+        if opcode == "fusion" and roots.get(called.group(1)) == "scatter":
+            continue
+        moved.append(f"{name} {opcode} {shape}")
+    assert not moved, moved
