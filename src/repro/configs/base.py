@@ -12,6 +12,8 @@ import dataclasses
 import importlib
 from typing import Optional, Tuple
 
+import numpy as np
+
 __all__ = ["ArchConfig", "ShapeSpec", "SHAPES", "ARCH_IDS", "get_config",
            "smoke_config", "list_archs", "cells"]
 
@@ -54,6 +56,20 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     conv_width: int = 4
+    conv_bias: bool = False       # bias on the depthwise conv over x, B, C
+
+    # --- layer kinds (interleaved hybrids) ---
+    # one mixer kind per layer in published order ("attention" | "mamba");
+    # empty: every layer is the family's. Each kind holds its own
+    # parameter stack and cache leaves (models/blocks.py).
+    layer_kinds: Tuple[str, ...] = ()
+    # muP-style multipliers (Granite): embedding output, each residual
+    # branch, and the divisor of the logits; 0 / 1 leave a model as is
+    embedding_multiplier: float = 0.0   # 0 -> sqrt(d_model)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0   # 0 -> 1 / sqrt(head_dim)
+    norm_eps: float = 1e-6
 
     # --- frontends (audio/vision stubs: precomputed embeddings) ---
     frontend: str = "none"        # "none" | "audio" | "vision"
@@ -87,6 +103,21 @@ class ArchConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale of the attention logits."""
+        if self.attention_multiplier:
+            return self.attention_multiplier
+        return 1.0 / float(np.sqrt(self.resolved_head_dim))
+
+    @property
+    def embed_scale(self) -> float:
+        return self.embedding_multiplier or float(np.sqrt(self.d_model))
+
+    def layers_of(self, kind: str) -> int:
+        """Layers of one mixer kind (interleaved models)."""
+        return sum(k == kind for k in self.layer_kinds)
 
     @property
     def kv_groups(self) -> int:
@@ -157,6 +188,13 @@ class ArchConfig:
             attn = d * (2 * d_in + 2 * self.ssm_state + self.ssm_heads) + d_in * d
         else:
             ffn = 3 * d * self.d_ff
+        if self.layer_kinds:
+            d_in = self.ssm_expand * d
+            mamba = (d * (2 * d_in + 2 * self.ssm_state + self.ssm_heads)
+                     + d_in * d)
+            n_m = self.layers_of("mamba")
+            return n + n_m * (mamba + ffn + 2 * d) + (l - n_m) * (
+                attn + ffn + 2 * d)
         if self.family == "hybrid":
             d_in = self.ssm_expand * d
             attn += d * (2 * d_in + 2 * self.ssm_state + self.ssm_heads) + d_in * d

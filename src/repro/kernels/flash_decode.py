@@ -94,8 +94,13 @@ def _decode_kernel(
         if bias_mode == "phi":
             pq = phi_q_ref[0, 0].astype(jnp.float32)      # (G, R)
             pk = phi_k_ref[0, 0].astype(jnp.float32)      # (bk, R)
+            # HIGHEST: at the default precision the MXU rounds both
+            # operands to bf16, and the ALiBi key factor (1, pos) is exact
+            # in bf16 only up to 256 — past it the bias errs by up to
+            # 2 x slope per key
             s += jax.lax.dot_general(
                 pq, pk, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
 
         g = s.shape[0]

@@ -62,9 +62,10 @@ from repro.core.attention import DEFAULT_MASK_VALUE, MaskSpec
 from repro.dist import sharding as dshard
 from repro.kernels import flash_decode as _fd
 from repro.kernels import flashbias_attn as _fa
+from repro.kernels import ssd_scan as _ssd
 
 __all__ = ["flash_attention", "flash_chunk_attention", "flash_decode",
-           "resolve_impl", "IMPLS"]
+           "ssd_scan", "resolve_impl", "IMPLS"]
 
 IMPLS = ("xla", "pallas", "pallas_interpret", "io_stub")
 
@@ -925,3 +926,43 @@ def flash_chunk_attention(
     o = jnp.einsum("bkgcs,bkse->bkgce", p, vv.astype(jnp.float32))
     return (o.transpose(0, 3, 1, 2, 4).reshape(b, c, h, dv)
             .astype(q.dtype))
+
+
+# ---------------------------------------------------------------------------
+# Mamba2 SSD chunk scan (prefill) — inference only
+# ---------------------------------------------------------------------------
+
+def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+             c: jax.Array, *, chunk: int = 256,
+             h0: Optional[jax.Array] = None, impl: str = "xla"):
+    """SSD chunk scan in the model's layout: x (B, S, H, P); dt (B, S, H)
+    (softplus'd); a (H,) < 0; b, c (B, S, N) shared by every head (n_groups
+    1); h0 (B, H, P, N) or None. Returns ``(y (B, S, H, P) f32, h_final
+    (B, H, P, N) f32)``.
+
+    ``"xla"`` runs the jnp scan (``models.ssd.ssd_scan``); the Pallas impls
+    run the fused kernel (``kernels/ssd_scan.py``, named ``ssd_scan`` in the
+    trace) on head-major views, S padded to a whole chunk with dt = 0 so
+    the padding leaves the state unchanged, per shard under a mesh (batch
+    and heads). The views are prefill-sized transposes, made once per layer
+    of an admission. A ragged batch passes dt = 0 past each row's length,
+    which ends its scan there."""
+    impl = resolve_impl(impl)
+    if impl not in ("pallas", "pallas_interpret"):
+        from repro.models import ssd as ssd_xla
+        return ssd_xla.ssd_scan(x, dt, a, b, c, chunk=chunk, h0=h0)
+    bsz, s, h, p = x.shape
+    q = min(chunk, _ceil_to(s, 8))
+    s_p = _ceil_to(s, q)
+    xt = _pad_axis(x, 1, s_p).transpose(0, 2, 1, 3)         # (B, H, S, P)
+    dtt = _pad_axis(dt, 1, s_p).transpose(0, 2, 1)[..., None]
+    bt = _pad_axis(b, 1, s_p)[:, None]                       # (B, 1, S, N)
+    ct = _pad_axis(c, 1, s_p)[:, None]
+    kernel = functools.partial(_ssd.ssd_scan_fwd, chunk=q,
+                               interpret=(impl == "pallas_interpret"))
+    bh, shared = ("batch", "kv_heads", None, None), ("batch", None, None, None)
+    y, h_fin = _per_shard(
+        kernel, dict(x=xt, dt=dtt, a=a, b=bt, c=ct, h0=h0),
+        dict(x=bh, dt=bh, a=("kv_heads",), b=shared, c=shared, h0=bh),
+        bh, batch=bsz, kv_heads=h)
+    return y.transpose(0, 2, 1, 3)[:, :s], h_fin

@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.models import lm, pairformer, pde, swin
+from repro.models import blocks, lm, pairformer, pde, swin
 
 __all__ = ["Model", "get_model"]
 
@@ -134,6 +134,36 @@ def _lm_model(cfg: ArchConfig) -> Model:
     )
 
 
+def _blocks_model(cfg: ArchConfig) -> Model:
+    """An LM whose layers mix kinds (``cfg.layer_kinds``): models/blocks.py
+    for the model, lm.py's cache programs for the serve backend."""
+    return Model(
+        cfg=cfg,
+        template=lambda: blocks.template(cfg),
+        loss=lambda p, batch: blocks.loss_fn(p, batch, cfg),
+        prefill=lambda p, batch, max_len=None, lengths=None: blocks.prefill(
+            p, batch, cfg, max_len=max_len, lengths=lengths),
+        prefill_chunk=lambda p, cache, tokens, offsets, chunk_lens,
+            final_lens, max_pages=None: blocks.prefill_chunk(
+                p, cache, tokens, cfg, offsets=offsets,
+                chunk_lens=chunk_lens, final_lens=final_lens,
+                max_pages=max_pages),
+        decode=lambda p, cache, tokens, max_pages=None: blocks.decode_step(
+            p, cache, tokens, cfg, max_pages=max_pages),
+        init_cache=lambda b, max_len, length=0: blocks.init_cache(
+            cfg, b, max_len, length=length),
+        insert_cache=lm.insert_cache_at_slots,
+        init_paged_cache=lambda b, n_pages, page_size, pages_per_slot=None:
+            blocks.init_paged_cache(cfg, b, n_pages, page_size,
+                                    pages_per_slot),
+        insert_paged=lambda dst, src, slots, tables:
+            lm.insert_paged_cache_at_slots(dst, src, slots, tables,
+                                           layout=cfg.cache_layout),
+        grow_page_table=lm.grow_page_tables_at_slots,
+        copy_pages=lambda dst, src_ids, dst_ids: lm.copy_paged_pages(
+            dst, src_ids, dst_ids, layout=cfg.cache_layout))
+
+
 def _swin_model(cfg: ArchConfig) -> Model:
     def input_specs(shape: ShapeSpec, **_):
         b = shape.global_batch
@@ -183,6 +213,8 @@ def _pairformer_model(cfg: ArchConfig) -> Model:
 
 
 def get_model(cfg: ArchConfig) -> Model:
+    if cfg.layer_kinds:
+        return _blocks_model(cfg)
     if cfg.family in ("dense", "moe", "ssm", "hybrid"):
         return _lm_model(cfg)
     if cfg.family == "swin":

@@ -99,6 +99,9 @@ def _ssm_template(cfg: ArchConfig) -> dict:
         "in_dt": PDef((d, hs), ("fsdp", "heads"), ("normal", sd)),
         "conv_w": PDef((w, hs, p), (None, "heads", None), ("normal", 0.2)),
         "conv_bc_w": PDef((w, 2 * n), (None, None), ("normal", 0.2)),
+        **({"conv_b": PDef((hs, p), ("heads", None), ("zeros",)),
+            "conv_bc_b": PDef((2 * n,), (None,), ("zeros",))}
+           if cfg.conv_bias else {}),
         "a_log": PDef((hs,), ("heads",), ("zeros",)),
         "dt_bias": PDef((hs,), ("heads",), ("zeros",)),
         "d_skip": PDef((hs,), ("heads",), ("ones",)),
@@ -181,8 +184,8 @@ def _attention(lp: dict, x: jax.Array, cfg: ArchConfig, *,
         v = constrain(v, "batch", "kv_heads", "seq", None)
         o = kops.flash_attention(
             q, k, v, phi_q, phi_k, slopes, mask_kind=mask_kind,
-            window=cfg.window, impl=cfg.attn_impl, block_q=128, block_k=128,
-            layout="bhsd")
+            window=cfg.window, scale=cfg.attn_scale, impl=cfg.attn_impl,
+            block_q=128, block_k=128, layout="bhsd")
         y = jnp.einsum("bhse,hed->bsd", o, lp["wo"].astype(dt))
         return constrain(y, "batch", "seq", None), k, v
 
@@ -201,7 +204,8 @@ def _attention(lp: dict, x: jax.Array, cfg: ArchConfig, *,
     else:
         o = kops.flash_attention(
             q, k, v, phi_q, phi_k, slopes, mask_kind=mask_kind,
-            window=cfg.window, impl=cfg.attn_impl, block_q=128, block_k=128)
+            window=cfg.window, scale=cfg.attn_scale, impl=cfg.attn_impl,
+            block_q=128, block_k=128)
     y = jnp.einsum("bshe,hed->bsd", o, lp["wo"].astype(dt))
     if head_major:                       # cache emission only, off hot path
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
@@ -300,7 +304,8 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
             phi_q = pq * slopes.reshape(1, 1, hp, 1)
             phi_k, slopes = phi_pages, None
         o = kops.flash_decode(q, k_cache, v_cache, lengths, phi_q=phi_q,
-                              phi_k=phi_k, slopes=slopes, impl=cfg.attn_impl,
+                              phi_k=phi_k, slopes=slopes,
+                              scale=cfg.attn_scale, impl=cfg.attn_impl,
                               block_k=cfg.attn_chunk, page_table=page_table,
                               kv_layout=kv_layout, max_pages=max_pages,
                               layer=layer)
@@ -335,8 +340,8 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
                 k_cache = k_cache.at[bidx, pos].set(k_new[:, 0], mode="drop")
                 v_cache = v_cache.at[bidx, pos].set(v_new[:, 0], mode="drop")
         o = kops.flash_decode(q, k_cache, v_cache, lengths, slopes=slopes,
-                              impl=cfg.attn_impl, block_k=cfg.attn_chunk,
-                              kv_layout=kv_layout)
+                              scale=cfg.attn_scale, impl=cfg.attn_impl,
+                              block_k=cfg.attn_chunk, kv_layout=kv_layout)
         o = o[..., :v_new.shape[-1]]     # lane-padded caches return padded
     y = jnp.einsum("bshe,hed->bsd", o, lp["wo"].astype(dt))
     return y, k_cache, v_cache
@@ -459,8 +464,8 @@ def _attention_chunk(lp: dict, x: jax.Array, k_cache, v_cache,
                 v_new.reshape(b * c, *v_new.shape[2:]), mode="drop")
         o = kops.flash_chunk_attention(
             q, k_cache, v_cache, offsets, chunk_lens, slopes,
-            impl=cfg.attn_impl, kv_layout=kv_layout, page_table=page_table,
-            max_pages=max_pages)
+            scale=cfg.attn_scale, impl=cfg.attn_impl, kv_layout=kv_layout,
+            page_table=page_table, max_pages=max_pages)
         o = o[..., :v_new.shape[-1]]
     elif cfg.window and cfg.window == k_cache.shape[2 if kernel_layout
                                                     else 1]:  # ring (SWA)
@@ -532,7 +537,7 @@ def _attention_chunk(lp: dict, x: jax.Array, k_cache, v_cache,
             v_cache = v_cache.at[bidx[:, None], pos_w].set(v_new, mode="drop")
         o = kops.flash_chunk_attention(
             q, k_cache, v_cache, offsets, chunk_lens, slopes,
-            impl=cfg.attn_impl, kv_layout=kv_layout)
+            scale=cfg.attn_scale, impl=cfg.attn_impl, kv_layout=kv_layout)
         o = o[..., :v_new.shape[-1]]
     y = jnp.einsum("bshe,hed->bsd", o, lp["wo"].astype(dt))
     return y, k_cache, v_cache
@@ -612,8 +617,9 @@ def _ssm_proj(sp: dict, x: jax.Array):
     return xs, z, bmat, cmat, dt
 
 
-def _causal_conv(seq, w, tail=None, lengths=None):
-    """Depthwise causal conv. seq: (B,S,...) w: (W, ...); tail: (B,W-1,...).
+def _causal_conv(seq, w, tail=None, lengths=None, bias=None):
+    """Depthwise causal conv. seq: (B,S,...) w: (W, ...); tail: (B,W-1,...);
+    ``bias`` (...) is added to every output position.
 
     With ``lengths`` (B,) the returned tail holds the last W-1 inputs at or
     before position ``lengths[b]-1`` (ragged right-padded prefill); position
@@ -625,6 +631,8 @@ def _causal_conv(seq, w, tail=None, lengths=None):
         tail = jnp.zeros((seq.shape[0], width - 1) + seq.shape[2:], seq.dtype)
     full = jnp.concatenate([tail, seq], axis=1)
     out = sum(full[:, i:i + seq.shape[1]] * w[i] for i in range(width))
+    if bias is not None:
+        out = out + bias
     if width == 1:
         new_tail = tail
     elif lengths is None:
@@ -634,6 +642,23 @@ def _causal_conv(seq, w, tail=None, lengths=None):
         idx = idx.reshape(idx.shape + (1,) * (full.ndim - 2))
         new_tail = jnp.take_along_axis(full, idx, axis=1)
     return out, new_tail
+
+
+def _conv_biases(sp: dict, dt):
+    """(x bias, B/C bias) of the causal conv, or (None, None)."""
+    if "conv_b" not in sp:
+        return None, None
+    return sp["conv_b"].astype(dt), sp["conv_bc_b"].astype(dt)
+
+
+def _gated_norm(y, z, scale, cfg: ArchConfig):
+    """Mamba2's gated RMSNorm ``norm(y * silu(z))`` in f32. y, z: (..., H,
+    P). Its group is d_inner / n_groups; B and C are shared by every head
+    (n_groups 1), so it normalises all H x P channels at once."""
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    lead = y.shape[:-2]
+    return rmsnorm(y.reshape(*lead, -1), scale.reshape(-1),
+                   cfg.norm_eps).reshape(y.shape)
 
 
 def _ssm_forward(sp: dict, x: jax.Array, cfg: ArchConfig, *, h0=None,
@@ -647,12 +672,13 @@ def _ssm_forward(sp: dict, x: jax.Array, cfg: ArchConfig, *, h0=None,
     """
     xs, z, bmat, cmat, dt = _ssm_proj(sp, x)
     dt_ = x.dtype
+    bx, bbc = _conv_biases(sp, dt_)
     xs, tail_x = _causal_conv(xs, sp["conv_w"].astype(dt_), conv_tail_x,
-                              lengths=lengths)
+                              lengths=lengths, bias=bx)
     xs = jax.nn.silu(xs)
     bc = jnp.concatenate([bmat, cmat], axis=-1)
     bc, tail_bc = _causal_conv(bc, sp["conv_bc_w"].astype(dt_), conv_tail_bc,
-                               lengths=lengths)
+                               lengths=lengths, bias=bbc)
     bc = jax.nn.silu(bc)
     n = cfg.ssm_state
     bmat, cmat = bc[..., :n], bc[..., n:]
@@ -662,14 +688,13 @@ def _ssm_forward(sp: dict, x: jax.Array, cfg: ArchConfig, *, h0=None,
         keep = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
         dt = jnp.where(keep[:, :, None], dt, 0.0)
     a = -jnp.exp(sp["a_log"].astype(jnp.float32))
-    y, h_fin = ssd.ssd_scan(xs.astype(jnp.float32), dt, a,
-                            bmat.astype(jnp.float32),
-                            cmat.astype(jnp.float32),
-                            chunk=cfg.ssd_chunk, h0=h0)
+    y, h_fin = kops.ssd_scan(xs.astype(jnp.float32), dt, a,
+                             bmat.astype(jnp.float32),
+                             cmat.astype(jnp.float32),
+                             chunk=cfg.ssd_chunk, h0=h0, impl=cfg.attn_impl)
     y = y + sp["d_skip"].astype(jnp.float32)[None, None, :, None] \
         * xs.astype(jnp.float32)
-    y = (y * jax.nn.silu(z.astype(jnp.float32)))
-    y = rmsnorm(y, sp["gate_norm"]).astype(dt_)
+    y = _gated_norm(y, z, sp["gate_norm"], cfg).astype(dt_)
     out = jnp.einsum("bshp,hpd->bsd", y, sp["out"].astype(dt_))
     return constrain(out, "batch", "seq", None), h_fin, tail_x, tail_bc
 
@@ -678,10 +703,12 @@ def _ssm_decode(sp: dict, x: jax.Array, h, tail_x, tail_bc, cfg: ArchConfig):
     """One-token SSD update; x (B,1,D). Returns (y, h, tail_x, tail_bc)."""
     xs, z, bmat, cmat, dt = _ssm_proj(sp, x)
     dt_ = x.dtype
-    xs, tail_x = _causal_conv(xs, sp["conv_w"].astype(dt_), tail_x)
+    bx, bbc = _conv_biases(sp, dt_)
+    xs, tail_x = _causal_conv(xs, sp["conv_w"].astype(dt_), tail_x, bias=bx)
     xs = jax.nn.silu(xs)
     bc = jnp.concatenate([bmat, cmat], axis=-1)
-    bc, tail_bc = _causal_conv(bc, sp["conv_bc_w"].astype(dt_), tail_bc)
+    bc, tail_bc = _causal_conv(bc, sp["conv_bc_w"].astype(dt_), tail_bc,
+                               bias=bbc)
     bc = jax.nn.silu(bc)
     n = cfg.ssm_state
     bmat, cmat = bc[..., :n], bc[..., n:]
@@ -693,8 +720,7 @@ def _ssm_decode(sp: dict, x: jax.Array, h, tail_x, tail_bc, cfg: ArchConfig):
                                 cmat[:, 0].astype(jnp.float32))
     y1 = y1 + sp["d_skip"].astype(jnp.float32)[None, :, None] \
         * xs[:, 0].astype(jnp.float32)
-    y1 = y1 * jax.nn.silu(z[:, 0].astype(jnp.float32))
-    y1 = rmsnorm(y1, sp["gate_norm"]).astype(dt_)
+    y1 = _gated_norm(y1, z[:, 0], sp["gate_norm"], cfg).astype(dt_)
     out = jnp.einsum("bhp,hpd->bd", y1, sp["out"].astype(dt_))[:, None]
     return out, h, tail_x, tail_bc
 
@@ -985,6 +1011,15 @@ def prefill(params, batch, cfg: ArchConfig, *, max_len: Optional[int] = None,
     logits = unembed_logits(last, params["embed"].astype(hid.dtype))
 
     lens = (jnp.full((b,), total, jnp.int32) if lengths is None else lengths)
+    return logits, _prefill_cache(caches, cfg, lens, total, max_len)
+
+
+def _prefill_cache(caches: dict, cfg: ArchConfig, lens, total: int,
+                   max_len: int) -> dict:
+    """The cache a prefill hands on, from its per-layer entries: K/V padded
+    to ``max_len`` (or rotated into a ring of ``window``) in the cfg's cache
+    layout, SSM state and conv tails as they are."""
+    b = lens.shape[0]
     cache = {"length": lens}
     if "k" in caches:
         sc = cfg.window if (cfg.window and cfg.window < max_len) else max_len
@@ -1018,7 +1053,7 @@ def prefill(params, batch, cfg: ArchConfig, *, max_len: Optional[int] = None,
     for key in ("ssm_h", "conv_x", "conv_bc"):
         if key in caches:
             cache[key] = caches[key]
-    return logits, cache
+    return cache
 
 
 def decode_step(params, cache, tokens, cfg: ArchConfig, *, max_pages=None):

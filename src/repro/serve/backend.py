@@ -71,6 +71,7 @@ class Backend:
     """
 
     paged: bool = False        # admission gated on page accounting
+    wave_rows: Optional[int] = None   # most requests one admission wave takes
     lazy: bool = False         # pages grow mid-flight (may force preemption)
     guards: bool = True        # host-side non-finite guards (ISSUE 10)
     faults = None              # serve.faults.FaultPlan injection hook
@@ -79,6 +80,11 @@ class Backend:
     # tokens (residues) among them
     n_prefill_rows_x_len: int = 0
     n_prefill_real: int = 0
+    # device state per kind, read by ``engine.counters()`` once allocated:
+    # recurrent-state bytes one slot holds (SSM state and conv tails) and
+    # the layers whose K/V live in the page pool
+    state_bytes_per_slot: int = 0
+    paged_layers: int = 0
 
     def ensure_state(self) -> None:
         """Allocate device state on first use (idempotent)."""
@@ -224,13 +230,20 @@ class TokenDecodeBackend(Backend):
                  page_reservation: str = "lazy",
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: bool = False,
-                 mesh=None, rules: Optional[Rules] = None):
+                 mesh=None, rules: Optional[Rules] = None,
+                 prefill_rows: Optional[int] = None):
         if page_reservation not in ("lazy", "whole"):
             raise ValueError(f"page_reservation must be 'lazy' or "
                              f"'whole', got {page_reservation!r}")
         self.model, self.params = model, params
         self.max_len, self.n_slots = max_len, n_slots
         self.prefill_len = prefill_len
+        if prefill_rows is not None and not 1 <= prefill_rows <= n_slots:
+            raise ValueError(f"prefill_rows must be in 1..{n_slots}, got "
+                             f"{prefill_rows}")
+        # rows of the wave prefill program: every wave pads to them, and
+        # the engine takes at most this many requests a wave
+        self.wave_rows = prefill_rows or n_slots
         self.mesh = mesh
         self.rules = (rules or Rules()) if mesh is not None else rules
         self._guard_bad: Dict[int, str] = {}
@@ -293,6 +306,13 @@ class TokenDecodeBackend(Backend):
             self._tok_matched = 0            # prefix tokens served from cache
             self._tok_matchable = 0          # full-block tokens seen at admit
         self._cache = None                        # allocated on first step
+        one = jax.eval_shape(lambda: model.init_cache(1, 1))
+        self.state_bytes_per_slot = sum(
+            int(np.prod(one[k].shape)) * one[k].dtype.itemsize
+            for k in ("ssm_h", "conv_x", "conv_bc") if k in one)
+        if self.paged:
+            self.paged_layers = int(jax.eval_shape(
+                lambda: model.init_paged_cache(1, 1, 1))["pages_k"].shape[0])
 
         def _pf(p, toks, front, lengths, max_len):
             batch = {"tokens": toks}
@@ -598,19 +618,20 @@ class TokenDecodeBackend(Backend):
         through ``prefill_step``, and nothing emits until a final chunk."""
         if self.chunk_size:
             return self._plan_chunked(wave, slots)
-        ns, w = self.n_slots, len(wave)
+        ns, w, rows = self.n_slots, len(wave), self.wave_rows
+        assert w <= rows, (w, rows)
 
-        # right-pad prompts; pad the wave batch to n_slots so exactly one
-        # prefill program serves every wave size (padding rows are dropped
-        # at insert via an out-of-range slot id). A resumed prompt may
+        # right-pad prompts; pad the wave batch to ``wave_rows`` so exactly
+        # one prefill program serves every wave size (padding rows are
+        # dropped at insert via an out-of-range slot id). A resumed prompt may
         # exceed a pinned prefill_len — that wave pads to the resumed
         # length, and _take_wave made it a SOLO wave so no co-admitted
         # request sees the changed padding
         padded = max(r.tokens.size for r in wave)
         if self.prefill_len is not None:
             padded = max(self.prefill_len, padded)
-        toks = np.zeros((ns, padded), np.int32)
-        lengths = np.ones((ns,), np.int32)
+        toks = np.zeros((rows, padded), np.int32)
+        lengths = np.ones((rows,), np.int32)
         for i, r in enumerate(wave):
             toks[i, :r.tokens.size] = r.tokens
             lengths[i] = r.prompt_len
@@ -619,7 +640,7 @@ class TokenDecodeBackend(Backend):
         if any(has_front):
             assert all(has_front), \
                 "wave mixes frontend/frontend-less requests"
-            front = np.zeros((ns,) + self._front_dim, np.float32)
+            front = np.zeros((rows,) + self._front_dim, np.float32)
             for i, r in enumerate(wave):
                 front[i] = r.frontend
             front = jnp.asarray(front)
@@ -632,20 +653,20 @@ class TokenDecodeBackend(Backend):
         else:
             pf_len = self.max_len
         real = [r.prompt_len for r in wave]
-        self.n_prefill_rows_x_len += ns * padded
+        self.n_prefill_rows_x_len += rows * padded
         self.n_prefill_real += sum(real)
-        with span("serve.prefill", requests=w, rows=ns, row_len=padded,
+        with span("serve.prefill", requests=w, rows=rows, row_len=padded,
                   real=sum(real), real_sq=sum(n * n for n in real)):
             logits, wave_cache = self._prefill(
                 self.params, jnp.asarray(toks), front, jnp.asarray(lengths),
                 pf_len)
-            slot_ids = np.full((ns,), ns, np.int32)  # padding rows: dropped
+            slot_ids = np.full((rows,), ns, np.int32)  # padding rows: dropped
             slot_ids[:w] = slots
             if self.paged:
                 # lazy: reserve only the prompt's pages — decode grows the
                 # table on page-boundary crossings. whole: reserve the full
                 # footprint so decode never allocates mid-flight
-                tables = np.full((ns, self.pages_per_slot), self.n_pages,
+                tables = np.full((rows, self.pages_per_slot), self.n_pages,
                                  np.int32)
                 for i, (slot, r) in enumerate(zip(slots, wave)):
                     pages = self._pool.alloc(self.admission_units(r))

@@ -177,6 +177,11 @@ class ServeEngine:
             behavior: poison flows into results undetected.
         faults: a ``serve.faults.FaultPlan`` for deterministic fault
             injection (chaos drills). None (default) injects nothing.
+        prefill_rows: rows of the token backend's whole-prompt prefill
+            program (default ``n_slots``): an admission wave takes at most
+            this many requests and pads to exactly this many rows. Fewer
+            rows than slots keep a wave of one or two long prompts from
+            paying for ``n_slots`` rows of padding.
         stall_limit: ``run()`` raises ``EngineStalled`` after this many
             consecutive steps with queued/live work but zero progress
             (no admission, no chunk landed, no token committed, no
@@ -197,7 +202,8 @@ class ServeEngine:
                  mesh=None, rules=None,
                  guards: bool = True,
                  faults: Optional[FaultPlan] = None,
-                 stall_limit: int = 64):
+                 stall_limit: int = 64,
+                 prefill_rows: Optional[int] = None):
         if model.prefill is None or model.decode is None:
             raise ValueError("model is not serve-capable (needs "
                              "prefill/decode programs)")
@@ -220,7 +226,7 @@ class ServeEngine:
                 n_pages=n_pages, pages_per_slot=pages_per_slot,
                 page_reservation=page_reservation,
                 prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
-                mesh=mesh, rules=rules)
+                mesh=mesh, rules=rules, prefill_rows=prefill_rows)
         if self.backend.paged:
             self.page_size = self.backend.page_size
             self.n_pages = self.backend.n_pages
@@ -414,13 +420,19 @@ class ServeEngine:
         ran) and ``prefill_real`` (the real prompt tokens, or residues,
         among them), ``preemptions``, ``quarantines`` and
         ``faults_contained``. ``1 - prefill_real / prefill_rows_x_len``
-        is the share of prefill rows that were padding."""
+        is the share of prefill rows that were padding. Two sizes of the
+        device state ride along once it is allocated:
+        ``state_bytes_per_slot`` (the recurrent SSM state and conv tails
+        one slot holds) and ``paged_layers`` (the layers whose K/V live
+        in the page pool)."""
         return {"steps": self.step_idx, "waves": self.n_waves,
                 "prefill_rows_x_len": self.backend.n_prefill_rows_x_len,
                 "prefill_real": self.backend.n_prefill_real,
                 "preemptions": self.n_preemptions,
                 "quarantines": self.n_quarantines,
-                "faults_contained": self.n_faults_contained}
+                "faults_contained": self.n_faults_contained,
+                "state_bytes_per_slot": self.backend.state_bytes_per_slot,
+                "paged_layers": self.backend.paged_layers}
 
     def page_stats(self) -> dict:
         """Pool accounting snapshot (empty for unpaged backends)."""
@@ -537,7 +549,8 @@ class ServeEngine:
         pins (it feeds MoE expert capacity)."""
         wave: List[Request] = []
         reserved = 0
-        while len(wave) < len(self._free):
+        room = min(len(self._free), self.backend.wave_rows or self.n_slots)
+        while len(wave) < room:
             r = self.scheduler.peek()
             if r is None:
                 break

@@ -131,6 +131,20 @@ def test_ssd_scan(one_chip):
              x, dt, a, bc, bc, kernel="ssd_scan")
 
 
+def test_ssd_scan_granite_prefill(one_chip):
+    # granite-4.0-h-micro: 64 heads of 64, state 128, chunk 256, a 2048-
+    # token prompt with an initial state and the final state out, B and C
+    # shared by every head through the index map
+    x = _arg((1, 2048, 64, 64), jnp.float32, one_chip)
+    dt = _arg((1, 2048, 64), jnp.float32, one_chip)
+    a = _arg((64,), jnp.float32, one_chip)
+    bc = _arg((1, 2048, 128), jnp.float32, one_chip)
+    h0 = _arg((1, 64, 64, 128), jnp.float32, one_chip)
+    _compile(lambda x, dt, a, b, c, h0: ops.ssd_scan(
+        x, dt, a, b, c, chunk=256, h0=h0, impl="pallas"),
+        x, dt, a, bc, bc, h0, kernel="ssd_scan")
+
+
 def test_sharded_paged_decode(topo):
     """gpt2 paged decode on a (data 2, model 2) mesh: the kernel runs per
     shard inside shard_map, and nothing pool-sized is all-gathered."""
@@ -217,6 +231,9 @@ def test_paged_decode_step_updates_pool_in_place(one_chip):
         _arg((n_slots, 1), jnp.int32, one_chip),
         max_pages=4).compile().as_text()
 
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
+                         hlo)
+    assert kernels and set(kernels) == {"flash_decode_paged"}, kernels
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
                         hlo)
     aliased = {int(n) for n in re.findall(r"\((\d+), \{",
@@ -239,4 +256,52 @@ def test_paged_decode_step_updates_pool_in_place(one_chip):
         if opcode == "fusion" and roots.get(called.group(1)) == "scatter":
             continue
         moved.append(f"{name} {opcode} {shape}")
+    assert not moved, moved
+
+
+def test_hybrid_decode_step_updates_state_in_place(one_chip):
+    """granite-4.0-h-micro's paged decode step as the serve backend jits
+    it, one period of its layer pattern (9 Mamba2 layers and one attention
+    layer, published widths, 32 slots): the donated KV pools and the SSM
+    state and conv stacks alias the program's outputs, nothing but an
+    in-place update produces an array shaped like a whole stack, and its
+    only Pallas kernel keeps the name ``paged_decode_roofline.hybrid``
+    selects it by."""
+    cfg = get_config("granite_4_0_h_micro")
+    cfg = cfg.replace(n_layers=10, layer_kinds=cfg.layer_kinds[:10],
+                      attn_impl="pallas")
+    model = get_model(cfg)
+    n_slots, n_pages, ps, per_slot = 32, 64, 128, 2
+    params = abstract_params(model.template())
+    be = TokenDecodeBackend(model, params, max_len=256, n_slots=n_slots,
+                            prefill_len=128, page_size=ps, n_pages=n_pages,
+                            pages_per_slot=per_slot,
+                            page_reservation="whole", prefill_rows=1)
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        n_slots, n_pages, ps, per_slot))
+    assert cache["ssm_h"].shape == (9, n_slots, 64, 64, 128)
+    assert cache["pages_k"].shape == (1, 8, n_pages, ps, 128)
+
+    hlo = be._decode.lower(      # weights served in bf16
+        jax.tree.map(lambda x: _arg(x.shape, jnp.bfloat16, one_chip),
+                     params),
+        jax.tree.map(lambda x: _arg(x.shape, x.dtype, one_chip), cache),
+        _arg((n_slots, 1), jnp.int32, one_chip),
+        max_pages=per_slot).compile().as_text()
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
+                         hlo)
+    assert kernels and set(kernels) == {"flash_decode_paged"}, kernels
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                        hlo)
+    aliased = {int(n) for n in re.findall(r"\((\d+), \{",
+                                          aliases.group(1) if aliases else "")}
+    for leaf in ("pages_k", "pages_v", "ssm_h", "conv_x", "conv_bc"):
+        param = re.search(
+            r"%cache__" + leaf + r"__\S* = \S+ parameter\((\d+)\)", hlo)
+        assert param and int(param.group(1)) in aliased, leaf
+    stacks = {tuple(d for d in v.shape if d != 1)
+              for k, v in cache.items() if k in ("ssm_h", "pages_k")}
+    moved = [f"{name} {opcode}" for name, dims, opcode in _INSTR.findall(hlo)
+             if tuple(int(d) for d in dims.split(",") if d and d != "1")
+             in stacks and opcode in ("copy", "concatenate", "transpose")]
     assert not moved, moved
