@@ -5,19 +5,34 @@ One new token per request attends to a cache of up to S keys. TPU adaptation:
 - The G q-heads sharing one kv head form the *rows* of the logits tile
   (``(G, block_k)``), so GQA turns the tiny N=1 decode matmul into an MXU-
   shaped one — the TPU analogue of GPU flash-decoding's split-K blocks.
+- A grid step covers a block of ``Hb`` kv heads, not one: it reads a
+  ``(Hb, block_k, D)`` block of K and of V and runs ``q·kᵀ`` and ``p·v``
+  as head-batched contractions (leading batch axis). A step costs a fixed
+  ~0.3-0.5 µs on the chip whatever it moves, so the grid is
+  ``(B, KVH / Hb, S / block_k)`` and a decode call takes ``B x S /
+  block_k`` steps when ``Hb == KVH``, not ``KVH`` times as many.
+- ``Hb`` is the largest divisor of ``KVH`` whose blocks, double-buffered,
+  plus the per-head scratch and logits fit ``_VMEM_BUDGET`` (half the 16
+  MiB default scoped VMEM limit of a v5e); it is read off the call's shapes
+  and dtypes (``_head_block``). A shape whose heads do not all fit takes a
+  smaller divisor through the same code.
 - The cache sequence axis is the innermost grid axis; online-softmax state
-  (m, l, acc) rides in VMEM scratch (TPU grids are sequential, so the
-  accumulate-across-j pattern is exact, no cross-block reduction pass).
+  rides in VMEM scratch per head — m and l ``(Hb, G, 1)``, the accumulator
+  ``(Hb, G, Dv)`` (TPU grids are sequential, so the accumulate-across-j
+  pattern is exact, no cross-block reduction pass).
 - Per-request lengths arrive via scalar prefetch (SMEM); blocks past the
   length are skipped entirely (``pl.when``) — compute *and* the copy of the
   skipped KV block are elided on real hardware by block-index aliasing.
-- FlashBias factors: ``phi_q`` is (G, R) per kv head, ``phi_k`` rides with the
-  cache at (block_k, R) — rank-R bias costs R/D extra MXU depth, never NM IO.
+- FlashBias factors: ``phi_q`` is (G, R) per kv head; ``phi_k`` rides with
+  the cache at (block_k, R) — rank-R bias costs R/D extra MXU depth, never
+  NM IO. A head-shared key factor (the paged slab) is one ``(Hb·G, R) x
+  (R, block_k)`` product for all the block's query rows. The factor
+  product runs at ``HIGHEST``: at the default precision the MXU rounds its
+  operands to bf16, which holds the ALiBi key factor (1, pos) exactly only
+  up to 256.
 - ``slopes`` mode generates the rank-2 ALiBi bias in-kernel (App. C JIT
-  trick): zero bias IO at all. The slopes ride flattened in SMEM
-  (``(KVH * G,)``, whole array) and the body assembles its kv head's (G, 1)
-  column from G scalar reads — a ``(1, G)`` VMEM block of a ``(KVH, G)``
-  array is not a legal TPU tile.
+  trick): zero bias IO. The slopes arrive as a ``(KVH, G, 1)`` column, one
+  ``(Hb, G, 1)`` block a step (last two dims whole, so a legal tile).
 
 ``flash_decode_paged_fwd`` is the PAGED variant: the KV cache (and the
 per-page ``phi_k`` factor slab) lives in a shared page pool and each
@@ -25,14 +40,14 @@ request's pages are resolved through a scalar-prefetched page table. The
 pool may be the whole layer stack ``(L, KVH, n_pages, ps, *)``: a third
 scalar-prefetch operand names the layer, and the index maps read its pages
 straight out of the stack, so the model's layer scan never materialises one
-layer's pool. The
-kernel BODY is shared with the contiguous path — grid axis j is the
-*logical* block index (page_size == block_k), so position math and the
-length-based block skipping are unchanged; only the block index maps
-differ (they read ``page_table[b, j]`` to find the physical page). Blocks
-past the request length clamp to the last mapped page, so skipped and
-unmapped pages alias the previous block's index and their copies are
-elided on hardware exactly like the contiguous path's skipped blocks.
+layer's pool. The kernel BODY is shared with the contiguous path — grid
+axis j is the *logical* block index (page_size == block_k), so position
+math and the length-based block skipping are unchanged; only the block
+index maps differ (they read ``page_table[b, j]`` to find the physical
+page, the same page for every head of the block). Blocks past the request
+length clamp to the last mapped page, so skipped and unmapped pages alias
+the previous block's index and their copies are elided on hardware
+exactly like the contiguous path's skipped blocks.
 
 Cache layout contract (ISSUE 5): both kernels read KV in the layouts
 declared below — ``(B, KVH, S, *)`` contiguous, ``(KVH, n_pages, ps, *)``
@@ -40,9 +55,9 @@ paged — and since ISSUE 5 the model caches are *stored* in exactly these
 layouts (lane-padded at allocation), so ``ops.py`` passes them zero-copy:
 nobody owns a per-step transpose anymore. The paged ``phi_pages`` factor
 slab may carry a leading kv-head axis of 1 (``(1, n_pages, ps, R)``): the
-kv-head broadcast then happens in its block index map (every kv head reads
-the same physical page block), which is what lets the slab stay a single
-layer- and head-shared copy in HBM.
+kv-head broadcast then happens in its block index map (every head block
+reads the same physical page block), which is what lets the slab stay a
+single layer- and head-shared copy in HBM.
 """
 from __future__ import annotations
 
@@ -58,6 +73,32 @@ from repro.core.attention import DEFAULT_MASK_VALUE
 
 __all__ = ["flash_decode_fwd", "flash_decode_paged_fwd"]
 
+# bytes one grid step's head-scaled blocks may take: half of a v5e's 16 MiB
+# default scoped VMEM limit, the rest left to Mosaic's own temporaries
+_VMEM_BUDGET = 8 << 20
+_LANES = 128
+
+
+def _head_block(q, k, v, phi_q, phi_k, *, block_k: int,
+                phi_per_head: bool) -> int:
+    """Kv heads per grid step: the largest divisor of KVH whose VMEM
+    footprint — double-buffered q, K, V, factor and output blocks, the
+    per-head softmax scratch and the f32 logits — fits ``_VMEM_BUDGET``."""
+    kvh, g, d = q.shape[1:]
+    dv = v.shape[-1]
+    io = (block_k * (d * k.dtype.itemsize + dv * v.dtype.itemsize)
+          + g * (d + dv) * q.dtype.itemsize)
+    if phi_q is not None:
+        r = phi_q.shape[-1]
+        io += g * r * phi_q.dtype.itemsize
+        if phi_per_head:
+            io += block_k * r * phi_k.dtype.itemsize
+    scratch = g * (dv + 2 * _LANES) * 4     # acc; m and l pad to the lanes
+    logits = 3 * g * block_k * 4            # s, its mask, p
+    per_head = 2 * io + scratch + logits
+    return max(hb for hb in range(1, kvh + 1)
+               if kvh % hb == 0 and (hb == 1 or hb * per_head <= _VMEM_BUDGET))
+
 
 def _decode_kernel(
     lengths_ref,                      # scalar prefetch: (B,) int32 in SMEM
@@ -70,7 +111,6 @@ def _decode_kernel(
     bias_mode: str,
 ):
     b = pl.program_id(0)
-    h = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -85,44 +125,45 @@ def _decode_kernel(
 
     @pl.when(k_start < length)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)               # (bk, D)
+        k = k_ref[...]                                    # (Hb, bk, D)
+        q = q_ref[...].astype(k.dtype)                    # (Hb, G, D)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (G, bk)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # (Hb, G, bk)
+        hb, g, _ = s.shape
 
         if bias_mode == "phi":
-            pq = phi_q_ref[0, 0].astype(jnp.float32)      # (G, R)
-            pk = phi_k_ref[0, 0].astype(jnp.float32)      # (bk, R)
-            # HIGHEST: at the default precision the MXU rounds both
-            # operands to bf16, and the ALiBi key factor (1, pos) is exact
-            # in bf16 only up to 256 — past it the bias errs by up to
-            # 2 x slope per key
-            s += jax.lax.dot_general(
-                pq, pk, (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
+            pq = phi_q_ref[...].astype(jnp.float32)       # (Hb, G, R)
+            pk = phi_k_ref[...].astype(jnp.float32)       # ([Hb,] bk, R)
+            highest = jax.lax.Precision.HIGHEST
+            if pk.ndim == 2:
+                # head-shared key factors: one product over every query
+                # row of the block
+                s += jax.lax.dot_general(
+                    pq.reshape(hb * g, pq.shape[-1]), pk,
+                    (((1,), (1,)), ((), ())), precision=highest,
+                    preferred_element_type=jnp.float32,
+                ).reshape(hb, g, block_k)
+            else:
+                s += jax.lax.dot_general(
+                    pq, pk, (((2,), (2,)), ((0,), (0,))), precision=highest,
+                    preferred_element_type=jnp.float32)
 
-        g = s.shape[0]
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (g, block_k), 1)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         if bias_mode == "alibi":
-            row = jax.lax.broadcasted_iota(jnp.int32, (g, 1), 0)
-            slope = jnp.zeros((g, 1), jnp.float32)
-            for gi in range(g):                           # G scalar reads
-                slope = jnp.where(row == gi, slopes_ref[h * g + gi], slope)
             rel = (k_pos - (length - 1)).astype(jnp.float32)
-            s += slope * rel
+            s += slopes_ref[...] * rel                    # (Hb, G, 1) column
 
         s = jnp.where(k_pos < length, s, DEFAULT_MASK_VALUE)
 
         m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)               # (bk, Dv)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=2, keepdims=True)
+        v = v_ref[...]                                    # (Hb, bk, Dv)
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * corr + pv
         m_scr[...] = m_new
@@ -131,7 +172,18 @@ def _decode_kernel(
     def _finalize():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _scratch(hb: int, g: int, dv: int):
+    return [pltpu.VMEM((hb, g, 1), jnp.float32),
+            pltpu.VMEM((hb, g, 1), jnp.float32),
+            pltpu.VMEM((hb, g, dv), jnp.float32)]
+
+
+def _bias_mode(phi_q, slopes) -> str:
+    return ("phi" if phi_q is not None
+            else ("alibi" if slopes is not None else "none"))
 
 
 def flash_decode_fwd(
@@ -141,7 +193,7 @@ def flash_decode_fwd(
     lengths: jax.Array,                   # (B,) int32
     phi_q: Optional[jax.Array] = None,    # (B, KVH, G, R)
     phi_k: Optional[jax.Array] = None,    # (B, KVH, S, R)
-    slopes: Optional[jax.Array] = None,   # (KVH, G)
+    slopes: Optional[jax.Array] = None,   # (KVH, G, 1)
     *,
     scale: float,
     block_k: int = 512,
@@ -152,29 +204,36 @@ def flash_decode_fwd(
     s_len = k_cache.shape[2]
     dv = v_cache.shape[-1]
     assert s_len % block_k == 0, (s_len, block_k)
-    bias_mode = ("phi" if phi_q is not None
-                 else ("alibi" if slopes is not None else "none"))
+    bias_mode = _bias_mode(phi_q, slopes)
+    hb = _head_block(q, k_cache, v_cache, phi_q, phi_k, block_k=block_k,
+                     phi_per_head=True)
 
-    grid = (b, kvh, s_len // block_k)
+    def head_map(b_, h_, j, *_):
+        return (b_, h_, 0, 0)
+
+    def seq_map(b_, h_, j, *_):
+        return (b_, h_, j, 0)
+
+    sq = pl.squeezed
+    grid = (b, kvh // hb, s_len // block_k)
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b_, h_, j, *_: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j, *_: (b_, h_, j, 0)),
-        pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, j, *_: (b_, h_, j, 0)),
+        pl.BlockSpec((sq, hb, g, d), head_map),
+        pl.BlockSpec((sq, hb, block_k, d), seq_map),
+        pl.BlockSpec((sq, hb, block_k, dv), seq_map),
     ]
     args = [q, k_cache, v_cache]
     if bias_mode == "phi":
         r = phi_q.shape[-1]
-        in_specs += [
-            pl.BlockSpec((1, 1, g, r), lambda b_, h_, j, *_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, r), lambda b_, h_, j, *_: (b_, h_, j, 0)),
-        ]
+        in_specs += [pl.BlockSpec((sq, hb, g, r), head_map),
+                     pl.BlockSpec((sq, hb, block_k, r), seq_map)]
         args += [phi_q, phi_k]
     else:
         in_specs += [None, None]
         args += [None, None]
     if bias_mode == "alibi":
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(slopes.reshape(kvh * g).astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((hb, g, 1), lambda b_, h_, j, *_:
+                                     (h_, 0, 0)))
+        args.append(slopes.astype(jnp.float32))
     else:
         in_specs.append(None)
         args.append(None)
@@ -185,12 +244,8 @@ def flash_decode_fwd(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, dv), lambda b_, h_, j, *_: (b_, h_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((sq, hb, g, dv), head_map),
+        scratch_shapes=_scratch(hb, g, dv),
     )
     out = pl.pallas_call(
         kernel,
@@ -219,7 +274,7 @@ def flash_decode_paged_fwd(
     page_table: jax.Array,                # (B, P) int32 page ids
     phi_q: Optional[jax.Array] = None,    # (B, KVH, G, R)
     phi_pages: Optional[jax.Array] = None,  # (KVH|1, n_pages, ps, R)
-    slopes: Optional[jax.Array] = None,   # (KVH, G)
+    slopes: Optional[jax.Array] = None,   # (KVH, G, 1)
     *,
     layer: Optional[jax.Array] = None,    # int32 scalar or (1,)
     scale: float,
@@ -240,7 +295,7 @@ def flash_decode_paged_fwd(
 
     ``phi_pages`` with a leading kv-head axis of 1 is the layer/kv-head-
     shared factor slab: its index map pins the head coordinate to 0, so the
-    kv-head broadcast costs nothing (same block, every head).
+    kv-head broadcast costs nothing (same block, every head block).
     """
     if layer is None:
         k_pages = k_pages.reshape(1, *k_pages.shape)
@@ -251,8 +306,10 @@ def flash_decode_paged_fwd(
     n_layers, _, n_pages, ps = k_pages.shape[:4]
     p_max = page_table.shape[1]
     dv = v_pages.shape[-1]
-    bias_mode = ("phi" if phi_q is not None
-                 else ("alibi" if slopes is not None else "none"))
+    bias_mode = _bias_mode(phi_q, slopes)
+    shared = phi_pages is not None and phi_pages.shape[0] == 1
+    hb = _head_block(q, k_pages, v_pages, phi_q, phi_pages, block_k=ps,
+                     phi_per_head=not shared)
 
     def _page(b_, j, lens_ref, pt_ref):
         # clamp j to the last in-length block so skipped/unmapped blocks
@@ -264,33 +321,37 @@ def flash_decode_paged_fwd(
         layer_ = jnp.clip(layer_ref[0], 0, n_layers - 1)
         return (layer_, h_, _page(b_, j, lens_ref, pt_ref), 0, 0)
 
-    grid = (b, kvh, p_max)
+    def head_map(b_, h_, j, *_):
+        return (b_, h_, 0, 0)
+
+    sq = pl.squeezed
+    grid = (b, kvh // hb, p_max)
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b_, h_, j, *_: (b_, h_, 0, 0)),
-        pl.BlockSpec((pl.squeezed, 1, 1, ps, d), pool_map),
-        pl.BlockSpec((pl.squeezed, 1, 1, ps, dv), pool_map),
+        pl.BlockSpec((sq, hb, g, d), head_map),
+        pl.BlockSpec((sq, hb, sq, ps, d), pool_map),
+        pl.BlockSpec((sq, hb, sq, ps, dv), pool_map),
     ]
     args = [q, k_pages, v_pages]
     if bias_mode == "phi":
         r = phi_q.shape[-1]
-        shared = phi_pages.shape[0] == 1
 
         def phi_map(b_, h_, j, lens_ref, pt_ref, layer_ref):
-            # kv-head-shared slab (leading axis 1): every head reads head
-            # 0's block, so the broadcast happens in the index map
+            # kv-head-shared slab (leading axis 1): every head block reads
+            # head 0's page, so the broadcast happens in the index map
             return (0 if shared else h_, _page(b_, j, lens_ref, pt_ref), 0, 0)
 
         in_specs += [
-            pl.BlockSpec((1, 1, g, r), lambda b_, h_, j, *_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, ps, r), phi_map),
+            pl.BlockSpec((sq, hb, g, r), head_map),
+            pl.BlockSpec((sq if shared else hb, sq, ps, r), phi_map),
         ]
         args += [phi_q, phi_pages]
     else:
         in_specs += [None, None]
         args += [None, None]
     if bias_mode == "alibi":
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(slopes.reshape(kvh * g).astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((hb, g, 1), lambda b_, h_, j, *_:
+                                     (h_, 0, 0)))
+        args.append(slopes.astype(jnp.float32))
     else:
         in_specs.append(None)
         args.append(None)
@@ -301,13 +362,8 @@ def flash_decode_paged_fwd(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda b_, h_, j, *_: (b_, h_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((sq, hb, g, dv), head_map),
+        scratch_shapes=_scratch(hb, g, dv),
     )
     out = pl.pallas_call(
         kernel,

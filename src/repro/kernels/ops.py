@@ -658,17 +658,17 @@ def _pallas_decode_hm(q, k_cache, v_cache, lengths, phi_q, phi_k, slopes,
         dict(q=qt, k_cache=k_cache, v_cache=v_cache, lengths=lengths,
              phi_q=pqt, phi_k=pkt, slopes=_grouped_slopes(slopes, kvh, g)),
         dict(q=bh, k_cache=bh, v_cache=bh, lengths=("batch",), phi_q=bh,
-             phi_k=bh, slopes=("kv_heads",)),
+             phi_k=bh, slopes=("kv_heads", None, None)),
         bh, batch=b, kv_heads=kvh)
     return out[:, :, :g, :dv].reshape(b, 1, h, dv)
 
 
 def _grouped_slopes(slopes, kvh: int, g: int):
-    """(H,) slopes -> the decode kernels' flat ``(KVH * G_pad,)`` form:
+    """(H,) slopes -> the decode kernels' ``(KVH, G_pad, 1)`` column:
     kv-head-major, each group zero-padded to the 8-row sublane tile."""
     if slopes is None:
         return None
-    return _pad_axis(slopes.reshape(kvh, g), 1, _ceil_to(g, 8)).reshape(-1)
+    return _pad_axis(slopes.reshape(kvh, g), 1, _ceil_to(g, 8))[..., None]
 
 
 def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
@@ -825,7 +825,7 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
              # the layer/kv-head-shared slab (leading 1) is whole everywhere
              phi_pages=(pool if pkt is not None and pkt.shape[0] == kvh
                         else (None,) * 4),
-             slopes=("kv_heads",)),
+             slopes=("kv_heads", None, None)),
         bh, batch=b, kv_heads=kvh)
     return out[:, :, :g, :dv].reshape(b, 1, h, dv)
 

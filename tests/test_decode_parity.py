@@ -308,3 +308,101 @@ class TestCarriedPoolDecodeStep:
             assert sorted(zip(*np.nonzero(changed))) == [(2, 0), (11, 2)]
             for page, row in ((2, 0), (11, 2)):  # every layer and kv head
                 assert np.any(rows[page, row], axis=-1).all(), leaf
+
+
+class TestFoldedHeadPagedDecode:
+    """The paged kernel moves a block of ``Hb`` kv heads a grid step. It
+    must match the XLA paged path in every bias mode with an inactive slot
+    (length 0), a length of 1, lengths at a page boundary ±1, stale table
+    entries past each slot's length (in the pool and out of it), a traced
+    layer of a stack of 3, and a VMEM budget small enough that ``Hb`` is a
+    proper divisor of ``KVH`` (the reduced-block branch)."""
+
+    PS, N_PAGES, P_SLOT, N_LAYERS, LAYER, D = 8, 24, 4, 3, 1, 128
+    LENGTHS = np.array([0, 1, 7, 8, 9, 25], np.int32)
+    # (H, KVH): phi is MHA (G = 1, padded to 8), none GQA with G = 4
+    HEADS = {"phi": (4, 4), "none": (16, 4), "alibi": (8, 4)}
+
+    def _inputs(self, mode):
+        h, kvh = self.HEADS[mode]
+        b, ps = len(self.LENGTHS), self.PS
+        ks = jax.random.split(jax.random.PRNGKey(7), 4)
+        pool = (self.N_LAYERS, kvh, self.N_PAGES, ps, self.D)
+        kp = jax.random.normal(ks[0], pool)
+        vp = jax.random.normal(ks[1], pool)
+        q = jax.random.normal(ks[2], (b, 1, h, self.D))
+        rng = np.random.RandomState(3)
+        # stale entries: in-pool pages of other slots and ids off the pool
+        table = rng.randint(-3, self.N_PAGES + 5, (b, self.P_SLOT))
+        live = rng.permutation(self.N_PAGES)
+        for i, n in enumerate(self.LENGTHS):
+            used = -(-int(n) // ps)
+            table[i, :used], live = live[:used], live[used:]
+        kw = {}
+        if mode == "phi":
+            # the layer- and head-shared slab of ALiBi key factors (1, pos)
+            slab = np.zeros((self.N_PAGES, ps, 128), np.float32)
+            slab[..., 0] = 1.0
+            slab[..., 1] = rng.randint(0, 700, (self.N_PAGES, ps))
+            kw = {"phi_q": jax.random.normal(ks[3], (b, 1, h, 2)) * 0.05,
+                  "phi_k": jnp.asarray(slab)}
+        elif mode == "alibi":
+            kw = {"slopes": jnp.asarray(0.5 ** np.arange(1, h + 1),
+                                        jnp.float32)}
+        return q, kp, vp, jnp.asarray(table, jnp.int32), kw
+
+    @pytest.mark.parametrize("budget", ["whole", "reduced"])
+    @pytest.mark.parametrize("mode", ["phi", "none", "alibi"])
+    def test_matches_xla_paged(self, mode, budget, monkeypatch):
+        from repro.kernels import flash_decode as fd
+        seen, real = [], fd._head_block
+        monkeypatch.setattr(fd, "_head_block",
+                            lambda *a, **k: seen.append(real(*a, **k))
+                            or seen[-1])
+        if budget == "reduced":
+            # two heads' blocks fit, four do not (~45-55 KB a head here)
+            monkeypatch.setattr(fd, "_VMEM_BUDGET", 120_000)
+        q, kp, vp, table, kw = self._inputs(mode)
+        lengths = jnp.asarray(self.LENGTHS)
+        layer = jnp.asarray(self.LAYER, jnp.int32)
+        call = functools.partial(ops.flash_decode, q, kp, vp, lengths,
+                                 page_table=table, kv_layout="bhsd",
+                                 layer=layer, **kw)
+        got = np.asarray(call(impl="pallas_interpret"))
+        want = np.asarray(call(impl="xla"))
+        kvh = self.HEADS[mode][1]
+        assert seen == ([kvh] if budget == "whole" else [2]), seen
+        live = self.LENGTHS > 0
+        np.testing.assert_allclose(got[live], want[live], atol=3e-5,
+                                   err_msg=f"{mode}/{budget}")
+        # an inactive slot reads nothing and returns zeros, not NaN
+        np.testing.assert_array_equal(got[~live], 0.0)
+        # the layer index matters: another layer's pools give other values
+        other = np.asarray(call(impl="xla", layer=jnp.asarray(0, jnp.int32)))
+        assert np.abs(other[live] - want[live]).max() > 1e-2
+
+    @pytest.mark.parametrize("cell", ["decode-short", "doc-qa"])
+    def test_grid_has_one_step_per_slot_and_page(self, cell):
+        """At the cells' widths every kv head fits one step: the grid is
+        ``b x p_max`` steps, not ``b x kvh x p_max``."""
+        import math
+        from repro.kernels.flash_decode import flash_decode_paged_fwd
+        if cell == "decode-short":    # gpt2_alibi_15b: 32 padded heads, phi
+            b, kvh, g, p_max, n_pages, n_layers, phi = 8, 32, 8, 2, 32, 48, 1
+        else:                         # granite: 8 kv heads, G 4 padded to 8
+            b, kvh, g, p_max, n_pages, n_layers, phi = 32, 8, 8, 24, 768, 4, 0
+        ps = d = 128
+        sds = jax.ShapeDtypeStruct
+        pool = sds((n_layers, kvh, n_pages, ps, d), jnp.bfloat16)
+        args = [sds((b, kvh, g, d), jnp.bfloat16), pool, pool,
+                sds((b,), jnp.int32), sds((b, p_max), jnp.int32)]
+        if phi:
+            args += [sds((b, kvh, g, 128), jnp.float32),
+                     sds((1, n_pages, ps, 128), jnp.float32)]
+        jaxpr = jax.make_jaxpr(lambda *a: flash_decode_paged_fwd(
+            *a, layer=jnp.int32(1), scale=0.125))(*args)
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls] == ["flash_decode_paged"]
+        grid = calls[0].params["grid_mapping"].grid
+        assert math.prod(grid) == b * p_max, grid
