@@ -120,7 +120,8 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False):
     vc = jax.random.normal(ks[2], (2, s, kvh, d))
     q1 = jax.random.normal(ks[0], (2, 1, h, d))
     lengths = jnp.array([s - 195, s], jnp.int32)
-    o_k = ops.flash_decode(q1, kc, vc, lengths, slopes=slopes,
+    o_k = ops.flash_decode(q1, kc.transpose(0, 2, 1, 3),
+                           vc.transpose(0, 2, 1, 3), lengths, slopes=slopes,
                            impl="pallas_interpret", block_k=128)
     o_r = ref.decode_reference(q1, kc, vc, lengths, slopes=slopes)
     rows.append(Row("decode_kernel_parity", 0.0,

@@ -165,60 +165,6 @@ def compare_lazy_whole(arch: str = "stablelm_12b", n_slots: int = 4,
             "ratio": tps["lazy"] / tps["whole"]}
 
 
-def compare_layout_legacy(arch: str = "stablelm_12b", n_slots: int = 4,
-                          prompt_len: int = 16, steps: int = 16,
-                          occupancy: int = 4, page_size: int = 16) -> dict:
-    """Interleaved kernel-layout vs legacy-layout decode A/B (ISSUE 5).
-
-    Two PAGED engines serve the identical workload from identical params:
-    one with the default kernel-native cache layout (kv-head-major pools,
-    zero-copy into the kernels, capped XLA gather), one with
-    ``cache_layout="legacy"`` (canonical pools, per-step re-layout in
-    ops). The CI gate (scripts/check_bench.py) holds ``ratio`` to a
-    tolerance band around 1.0 — the kernel layout must never be slower
-    than the transpose-per-step path it deleted. Timing methodology:
-    ``_interleaved_decode_ab``.
-
-    Output parity across layouts is recorded as ``outputs_identical``
-    (and warned about), not asserted: the layouts' decode paths sum
-    logits in different orders (concat-fold/chunked vs head-major
-    einsums), so a vocab tie at ULP distance could legitimately flip one
-    greedy argmax — a timing job shouldn't die on that. The HARD parity
-    contract lives in tests/test_cache_layout.py, where seeds are pinned.
-    """
-    from repro.configs import smoke_config
-    from repro.models import get_model
-    from repro.models.common import init_params
-    from repro.serve import ServeEngine
-
-    base = smoke_config(arch)
-    max_len = -(-(prompt_len + steps + 12) // page_size) * page_size
-    engines = {}
-    params = None
-    for mode in ("legacy", "kernel"):
-        cfg = base.replace(cache_layout=mode)
-        model = get_model(cfg)
-        if params is None:
-            params = init_params(model.template(), jax.random.PRNGKey(0))
-        engines[mode] = ServeEngine(
-            model, params, max_len=max_len, n_slots=n_slots,
-            prefill_len=prompt_len, page_size=page_size,
-            pages_per_slot=max_len // page_size)
-    tps, outs = _interleaved_decode_ab(engines, base.vocab, prompt_len,
-                                       steps, occupancy)
-    identical = all(np.array_equal(a, b)
-                    for a, b in zip(outs["kernel"], outs["legacy"]))
-    if not identical:
-        print("# WARNING: layout A/B greedy outputs diverged (likely a "
-              "ULP logit tie; hard parity is tested in "
-              "tests/test_cache_layout.py)")
-    return {"occupancy": occupancy, "page_size": page_size,
-            "legacy_decode_tokens_per_s": tps["legacy"],
-            "kernel_decode_tokens_per_s": tps["kernel"],
-            "outputs_identical": identical,
-            "ratio": tps["kernel"] / tps["legacy"]}
-
-
 def compare_chunked_prefill(arch: str = "stablelm_12b", n_slots: int = 4,
                             prompt_len: int = 16, long_prompt: int = 192,
                             steps: int = 40, chunk: int = 16,
@@ -472,15 +418,6 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False):
     data["lazy_vs_whole"] = compare_lazy_whole(
         **{k: v for k, v in kw.items() if k != "occupancies"},
         occupancy=max(kw.get("occupancies", (4,))))
-    # ISSUE 5: kernel-native vs legacy cache layout, measured not asserted.
-    # Pinned to a page-dense shape (long decode, small pages) regardless of
-    # smoke: the layouts differ in per-step pool/view handling, so the A/B
-    # needs enough pages in flight for that term to rise above host noise
-    # (at steps=16/ps=16 every slot holds ~2 pages and the ratio is noise).
-    data["layout_vs_legacy"] = compare_layout_legacy(
-        **{k: v for k, v in kw.items() if k not in ("occupancies", "steps")},
-        steps=64, page_size=8,
-        occupancy=max(kw.get("occupancies", (4,))))
     # ISSUE 7: decode-step tail latency under a concurrent long-prompt
     # arrival — whole-prompt admission stalls the batch for one full
     # prefill, chunked admission amortizes it one chunk per step. The
@@ -491,7 +428,7 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False):
         long_prompt=128 if smoke else 192)
     # ISSUE 10: decode throughput with non-finite emission guards on vs
     # off — containment must stay within 5% of the unguarded engine.
-    # steps pinned to 64 regardless of smoke (like the layout A/B): the
+    # steps pinned to 64 regardless of smoke: the
     # true per-step delta is small, and min-over-16 samples on a shared
     # CPU runner leaves ~5% jitter in the ratio — the gate's whole budget.
     data["guard_overhead"] = compare_guard_overhead(

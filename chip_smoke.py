@@ -31,8 +31,8 @@ one request's logits are checked against ``attn_impl="xla"`` on the same
 mesh. The decode program must hold the kernel (inside ``shard_map``) and no
 all-gather as large as a layer's pool shard.
 
-The system under test always runs ``attn_impl="pallas"`` with
-``cache_layout="kernel"`` in the config's bf16. The reference is
+The system under test always runs ``attn_impl="pallas"`` over the
+kv-head-major KV caches in the config's bf16. The reference is
 ``attn_impl="xla"`` computing in f32 at matmul precision ``"highest"`` on
 the same weights, and the bf16 ``attn_impl="xla"`` path, as served, measures
 how far bf16 alone moves the output (the floor). No phase uses ``"auto"`` or
@@ -155,8 +155,7 @@ def init_weights(model, seed: int, dtype=jnp.bfloat16, mesh=None):
 
 
 def lm_config(arch: str, impl: str = SYSTEM, dtype=None):
-    return configured(get_config(arch).replace(cache_layout="kernel"), impl,
-                      dtype)
+    return configured(get_config(arch), impl, dtype)
 
 
 def seeded_prompts(rng, vocab: int, n: int, lo: int, hi: int):

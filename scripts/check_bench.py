@@ -18,30 +18,26 @@ than ``--tolerance`` (default 30%) below its committed baseline:
    whole-request-reservation decode throughput at occupancy 4. The two
    engines are timed interleaved (same load profile), so this ratio is
    noise-robust and needs no baseline.
-4. serve: ``layout_vs_legacy.ratio`` — the kernel-native cache layout
-   (ISSUE 5) must be at least as fast as the legacy canonical layout it
-   replaced (>= 1.0 within tolerance). Interleaved like the lazy A/B, so
-   no baseline is needed.
-5. serve: ``chunked_prefill.ratio`` — p99 decode-step latency under a
+4. serve: ``chunked_prefill.ratio`` — p99 decode-step latency under a
    concurrent long-prompt arrival, whole-prompt admission over chunked
    (ISSUE 7). Gated at a FIXED structural floor of 1.2 (not
    tolerance-scaled): chunked admission amortizing the arrival sits > 2,
    a degeneration into a monolithic prefill stall sits ~1.0.
-6. serve: ``prefix_sharing.ratio`` — shared-prefix admission throughput,
+5. serve: ``prefix_sharing.ratio`` — shared-prefix admission throughput,
    prefix cache on over off, at the 64-requests x 512-token-prefix point
    (ISSUE 9). Gated at a FIXED structural floor of 2.0: page sharing
    deletes ~8/9 of the prefill compute there (> 3 observed), while an
    admission path that silently stops matching sits ~1.0.
-7. serve: ``guard_overhead.ratio`` — decode throughput with the ISSUE 10
+6. serve: ``guard_overhead.ratio`` — decode throughput with the ISSUE 10
    non-finite emission guards on over off. Gated at a FIXED floor of
    0.95: default-on fault containment may cost at most 5% of decode
    throughput. Interleaved, so no baseline is needed.
-8. neural (``--neural``, opt-in): the Table 6 Pairformer inference A/B
+7. neural (``--neural``, opt-in): the Table 6 Pairformer inference A/B
    from BENCH_neural.json — dense-path time / FlashBias-neural-path time,
    a same-machine ratio gated against a committed conservative baseline
    (the neural path ran ungated since the bench landed, so a factor-MLP
    regression would have merged silently).
-9. pairformer (``--pairformer``, opt-in): the ISSUE 6 batched-serve A/B
+8. pairformer (``--pairformer``, opt-in): the ISSUE 6 batched-serve A/B
    from BENCH_pairformer.json. Two gates: the headline
    ``factored_vs_dense.ratio`` (factored factor-cache step vs the official
    recompute-from-z dataflow, interleaved, >= 1.0 within tolerance — the
@@ -111,7 +107,6 @@ SCHEMAS: dict[str, tuple[str, ...]] = {
         "points[].occupancy",
         "points[].decode_tokens_per_s",
         "lazy_vs_whole.ratio",
-        "layout_vs_legacy.ratio",
         "chunked_prefill.ratio",
         "prefix_sharing.ratio",
         "prefix_sharing.hit_rate",
@@ -184,11 +179,6 @@ def serve_decode_point(bench: dict) -> tuple[int, float]:
 def lazy_vs_whole_ratio(bench: dict) -> float:
     """Interleaved lazy/whole decode throughput ratio (ISSUE 4)."""
     return float(bench["lazy_vs_whole"]["ratio"])
-
-
-def layout_vs_legacy_ratio(bench: dict) -> float:
-    """Interleaved kernel-layout/legacy decode throughput ratio (ISSUE 5)."""
-    return float(bench["layout_vs_legacy"]["ratio"])
 
 
 def chunked_prefill_ratio(bench: dict) -> float:
@@ -373,13 +363,6 @@ def main(argv=None) -> int:
     check(
         "serve lazy-vs-whole decode ratio",
         lazy_vs_whole_ratio(serve),
-        band,
-        f"interleaved A/B, no baseline, tol {args.tolerance:.0%}",
-        failures,
-    )
-    check(
-        "serve kernel-layout-vs-legacy decode ratio",
-        layout_vs_legacy_ratio(serve),
         band,
         f"interleaved A/B, no baseline, tol {args.tolerance:.0%}",
         failures,

@@ -9,8 +9,8 @@ finding, printing each as ``[rule] program: message [offending eqn]``:
 2. Trace-time jaxpr contracts per cache family: the ISSUE-5 pool-relayout
    tripwire on the decode step, no host callbacks inside jit, the Eq. 3
    fold on the precision-free factored-bias path, the pow2 recompile-key
-   bound — plus a built-in NEGATIVE test proving ``cache_layout="legacy"``
-   still trips the transpose rule (skip with ``--skip-negative``).
+   bound — plus a built-in NEGATIVE test proving a per-layer pool
+   transpose still trips the relayout rule (skip with ``--skip-negative``).
 3. Mesh/HLO checks (``--mesh``, needs >= 4 devices — forces 4 host
    devices when real ones are absent): real collectives in the sharded
    decode HLO, state axes in the Rules vocabulary, no silent pool
@@ -20,14 +20,12 @@ Examples::
 
     PYTHONPATH=src python scripts/run_statcheck.py
     PYTHONPATH=src python scripts/run_statcheck.py --families dense,ring
-    PYTHONPATH=src python scripts/run_statcheck.py --layout legacy  # fails
     python scripts/run_statcheck.py --lint-only     # no jax needed
     PYTHONPATH=src python scripts/run_statcheck.py --mesh
 
-The default ``--impl pallas_interpret`` is load-bearing: the legacy
-layout's Θ(pool) transpose lives in the Pallas layout adapters, so
-interpret mode is what lets CPU CI see the exact jaxpr the TPU path
-would run (see statcheck/README.md).
+The default ``--impl pallas_interpret`` traces the Pallas dispatch, so
+CPU CI sees the exact decode jaxpr the TPU path would run (see
+statcheck/README.md).
 """
 from __future__ import annotations
 
@@ -46,10 +44,9 @@ def run_lint() -> list:
     return lint_tree(REPO)
 
 
-def run_contract_checks(families, layout, impl, self_test) -> list:
+def run_contract_checks(families, impl, self_test) -> list:
     from repro.statcheck.contracts import run_contracts
-    return run_contracts(families, cache_layout=layout, impl=impl,
-                         self_test=self_test)
+    return run_contracts(families, impl=impl, self_test=self_test)
 
 
 def run_mesh_checks(impl: str) -> list:
@@ -78,19 +75,14 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--families", default=DEFAULT_FAMILIES,
                     help=f"comma-separated (default {DEFAULT_FAMILIES})")
-    ap.add_argument("--layout", default="kernel",
-                    choices=("kernel", "legacy"),
-                    help="cache layout to check (legacy exists to watch "
-                    "the tripwire fire)")
     ap.add_argument("--impl", default="pallas_interpret",
                     help="attn_impl for the traced programs (default "
-                    "pallas_interpret: the layout adapters the tripwire "
-                    "watches live on the Pallas path)")
+                    "pallas_interpret: the Pallas dispatch the chip runs)")
     ap.add_argument("--lint-only", action="store_true",
                     help="AST host-path lint only (no jax import)")
     ap.add_argument("--no-lint", action="store_true")
     ap.add_argument("--skip-negative", action="store_true",
-                    help="skip the built-in legacy-tripwire self test")
+                    help="skip the built-in relayout-tripwire self test")
     ap.add_argument("--mesh", action="store_true",
                     help="also compile the sharded decode on a (2,2) "
                     "mesh and check collectives (needs >= 4 devices)")
@@ -101,7 +93,7 @@ def main(argv=None) -> int:
         findings += run_lint()
     if not args.lint_only:
         families = [f for f in args.families.split(",") if f]
-        findings += run_contract_checks(families, args.layout, args.impl,
+        findings += run_contract_checks(families, args.impl,
                                         self_test=not args.skip_negative)
         if args.mesh:
             findings += run_mesh_checks(args.impl)
@@ -112,7 +104,7 @@ def main(argv=None) -> int:
         print(f"statcheck: {len(findings)} finding(s)", file=sys.stderr)
         return 1
     scope = "lint" if args.lint_only else \
-        f"lint+contracts[{args.families};layout={args.layout}]" \
+        f"lint+contracts[{args.families}]" \
         if not args.no_lint else f"contracts[{args.families}]"
     print(f"statcheck passed ({scope})")
     return 0

@@ -89,15 +89,21 @@ class ArchConfig:
     remat: str = "dots"           # "none" | "dots" | "full"
     attn_chunk: int = 512         # kv chunk of the XLA flash path
     attn_impl: str = "auto"       # "auto" | "xla" | "pallas" | "pallas_interpret"
-    cache_layout: str = "kernel"  # "kernel" (kv-head-major, zero-copy decode)
-                                  # | "legacy" (canonical (B,S,KVH,hd); kept as
-                                  # the layout_vs_legacy A/B + parity reference)
+    # kept only because bench/families pass cache_layout="kernel"; no code
+    # reads it, and any other value is refused in __post_init__
+    cache_layout: str = "kernel"
     ssd_chunk: int = 256          # SSD intra-chunk quadratic block
     grad_accum: int = 1           # microbatches per train step (activation fit)
     grad_rs: bool = False         # pin grads to param shardings (forces the
                                   # DP reduction to reduce-scatter, ZeRO-2)
 
     notes: str = ""
+
+    def __post_init__(self):
+        if self.cache_layout != "kernel":
+            raise ValueError(
+                f"cache_layout={self.cache_layout!r}: the kv-head-major "
+                "\"kernel\" layout is the only KV-cache layout")
 
     # ---- derived (TP padding; zero-padded weights keep math exact) ----
     @property

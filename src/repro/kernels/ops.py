@@ -11,15 +11,16 @@ inference and SDPA autograd for training).
 Cache layout contract (the decode hot path)
 -------------------------------------------
 
-The kernels consume KV in **kv-head-major** layout, and since ISSUE 5 the
-caches are *stored* that way from allocation, so the jitted decode step
-hands them over zero-copy — there is no per-step transpose, lane-pad or
-factor broadcast of anything pool-sized:
+KV caches have one layout, the kernels' own **kv-head-major** one. The
+models allocate them that way and every writer (token scatter, prefill
+page scatter, ring rotation) writes it, so the jitted decode step hands
+them to the kernels zero-copy — there is no per-step transpose, lane-pad
+or factor broadcast of anything pool-sized:
 
-- contiguous / ring KV: ``(B, KVH, S, hd)`` per layer (``kv_layout="bhsd"``).
-  Store ``hd`` as a 128-lane multiple and ``S`` as a multiple of the decode
-  block (128 is always safe) for the zero-copy guarantee; other shapes fall
-  back to a documented pad (correctness, not speed).
+- contiguous / ring KV: ``(B, KVH, S, hd)`` per layer. Store ``hd`` as a
+  128-lane multiple and ``S`` as a multiple of the decode block (128 is
+  always safe) for the zero-copy guarantee; other shapes fall back to a
+  documented pad (correctness, not speed).
 - paged KV: pools ``(KVH, n_pages, ps, hd_pad)`` per layer, ``hd_pad`` lane-
   padded at ``init_paged_cache``; the model's decode step passes the whole
   ``(L, KVH, n_pages, ps, hd_pad)`` stack with ``layer=`` and the kernel
@@ -27,13 +28,6 @@ factor broadcast of anything pool-sized:
   layer- AND kv-head-shared at ``(n_pages, ps, r_pad)`` — the kv-head
   broadcast happens in the kernel's block index maps, never as a
   ``broadcast_to`` on the pool.
-
-Nobody owns a transpose anymore: allocation writes the kernel layout, every
-writer (token scatter, prefill page scatter, ring rotation) writes it, and
-the kernels read it. The canonical ``(B, S, KVH, hd)`` layout remains
-accepted (``kv_layout="bshd"``, the default for direct callers) and is the
-``layout_vs_legacy`` A/B + parity reference: it adapts per call, paying
-exactly the per-step cost the kernel layout deletes.
 
 The XLA fallbacks take cheap views of the kernel layout (head-major
 einsums; the paged gather is capped at ``ceil(max(lengths)/page_size)``
@@ -103,13 +97,13 @@ def resolve_impl(impl: str) -> str:
 def _pick_block(s_len: int, want: int) -> int:
     """Largest multiple-of-8 divisor of ``s_len`` that is <= ``want``
     (8 = TPU sublane: Mosaic rejects blocks whose second-minor dim isn't a
-    multiple of it). Returns ``s_len`` itself below 8 (single tiny block,
-    same as the canonical path's ``min(block_k, S)``) and 0 when no
+    multiple of it). Returns ``s_len`` itself below 8 (a single tiny
+    block) and 0 when no
     aligned divisor exists — the caller then pads the seq axis once.
 
     Under the cache layout contract (S a multiple of 128, or S <= want)
-    this finds >= min(want, 128), so the kernel-layout decode path never
-    pads the cache sequence axis. Trace-time Python: <= want/8 steps."""
+    this finds >= min(want, 128), so the decode path never pads the cache
+    sequence axis. Trace-time Python: <= want/8 steps."""
     if s_len < 8:
         return s_len
     b = (min(want, s_len) // 8) * 8
@@ -417,15 +411,13 @@ def _static_page_cap(lengths, ps: int, p_slot: int,
     return max(1, min(-(-longest // ps), p_slot))
 
 
-def _xla_decode_head_major(q, k_cache, v_cache, lengths, phi_q, phi_k,
-                           slopes, scale):
-    """XLA decode over kernel-layout caches — head-major einsums, no
+def _xla_decode(q, k_cache, v_cache, lengths, phi_q, phi_k, slopes, scale):
+    """XLA decode over kv-head-major caches — head-major einsums, no
     transpose or per-head factor materialization of anything pool-sized.
 
-    q (B,1,H,D); k/v (B,KVH,S,E); phi_k (B,KVH,S,R) or kv-head-shared
-    (B,S,R); slopes (H,). Factor ranks align by slicing the wider operand
-    (stored factor slabs are zero-padded to the lane boundary, so slicing
-    them back is exact)."""
+    q (B,1,H,D); k/v (B,KVH,S,E); phi_k (B,KVH,S,R); slopes (H,). Factor
+    ranks align by slicing the wider operand (stored factor slabs are
+    zero-padded to the lane boundary, so slicing them back is exact)."""
     b, _, h, d = q.shape
     kvh, s_len = k_cache.shape[1], k_cache.shape[2]
     dv = v_cache.shape[-1]
@@ -438,11 +430,8 @@ def _xla_decode_head_major(q, k_cache, v_cache, lengths, phi_q, phi_k,
     if phi_q is not None:
         r = min(phi_q.shape[-1], phi_k.shape[-1])
         pq = phi_q[:, 0].reshape(b, kvh, g, -1)[..., :r].astype(jnp.float32)
-        pk = phi_k[..., :r].astype(jnp.float32)
-        if pk.ndim == 3:                      # (B, S, R) kv-head-shared
-            s = s + jnp.einsum("bkgr,bsr->bkgs", pq, pk)
-        else:                                 # (B, KVH, S, R)
-            s = s + jnp.einsum("bkgr,bksr->bkgs", pq, pk)
+        s = s + jnp.einsum("bkgr,bksr->bkgs", pq,
+                           phi_k[..., :r].astype(jnp.float32))
     k_pos = jnp.arange(s_len)
     if slopes is not None:
         rel = (k_pos[None] - (lengths - 1)[:, None]).astype(jnp.float32)
@@ -456,7 +445,7 @@ def _xla_decode_head_major(q, k_cache, v_cache, lengths, phi_q, phi_k,
 
 def flash_decode(
     q: jax.Array,                        # (B, 1, H, D)
-    k_cache: jax.Array,                  # see kv_layout below
+    k_cache: jax.Array,                  # (B, KVH, S, D) or a page pool
     v_cache: jax.Array,
     lengths: jax.Array,                  # (B,) int32
     phi_q: Optional[jax.Array] = None,   # (B, 1, H, R)
@@ -467,53 +456,36 @@ def flash_decode(
     impl: str = "auto",
     block_k: int = 512,
     page_table: Optional[jax.Array] = None,  # (B, P_slot) int32 -> paged mode
-    kv_layout: str = "bshd",
     max_pages: Optional[int] = None,
     layer: Optional[jax.Array] = None,   # int32 scalar: paged layer stack
 ) -> jax.Array:
-    """Single-token decode against a KV cache. Returns (B, 1, H, Dv).
+    """Single-token decode against a kv-head-major KV cache (module
+    docstring has the layout contract). Returns (B, 1, H, Dv).
 
-    ``kv_layout`` selects the cache layout (module docstring has the full
-    contract):
-
-    - ``"bshd"`` (canonical, the parity/legacy reference): ``k_cache`` /
-      ``v_cache`` are ``(B, S, KVH, *)``; paged pools ``(n_pages, ps, KVH,
-      *)``; ``phi_k`` ``(B, S, KVH|H|1, R)`` or the paged slab
-      ``(n_pages, ps, R)`` / ``(n_pages, ps, KVH, R)``. Adapted to the
-      kernels per call (the cost the kernel layout deletes).
-    - ``"bhsd"`` (kernel-native, what the models store): ``(B, KVH, S, *)``;
-      paged pools ``(KVH, n_pages, ps, *)`` handed to the Pallas kernel
-      zero-copy; ``phi_k`` ``(B, KVH, S, R)`` or the layer/kv-head-shared
-      paged slab ``(n_pages, ps, r_pad)`` (kv-head broadcast happens in the
-      kernel block index maps).
-
-    With ``page_table`` the caches are a shared PAGE POOL; ``page_table[b,
-    j]`` maps request b's j-th logical block to its physical page; entries
-    beyond the mapped prefix are ignored (clamped + length-masked). The
-    XLA fallback gathers each request's logical view first, capped at
-    ``ceil(max(lengths)/page_size)`` pages (see ``max_pages``) instead of
-    the full table width.
+    Contiguous caches are ``(B, KVH, S, *)`` with ``phi_k`` ``(B, KVH, S,
+    R)``. With ``page_table`` the caches are a shared PAGE POOL ``(KVH,
+    n_pages, ps, *)`` handed to the Pallas kernel zero-copy, and ``phi_k``
+    is the layer/kv-head-shared slab ``(n_pages, ps, r_pad)`` (kv-head
+    broadcast in the kernel's block index maps) or ``(KVH, n_pages, ps,
+    R)``. ``page_table[b, j]`` maps request b's j-th logical block to its
+    physical page; entries beyond the mapped prefix are ignored (clamped +
+    length-masked). The XLA fallback gathers each request's logical view
+    first, capped at ``ceil(max(lengths)/page_size)`` pages (see
+    ``max_pages``) instead of the full table width.
 
     ``layer`` (paged only): the pools are the model's whole layer stack,
-    ``(L, *pool)``, and the call attends over layer ``layer``. The kernel-
-    layout Pallas path reads that layer in place (a scalar-prefetch operand
-    of the kernel), the XLA paths index it inside their page gather, and
-    the canonical layout slices it out (the legacy cost). ``None``: the
-    pools are one layer's.
+    ``(L, *pool)``, and the call attends over layer ``layer``. The Pallas
+    path reads that layer in place (a scalar-prefetch operand of the
+    kernel) and the XLA paths index it inside their page gather. ``None``:
+    the pools are one layer's.
     """
-    assert kv_layout in ("bshd", "bhsd"), kv_layout
     if page_table is not None:
         return _flash_decode_paged(q, k_cache, v_cache, lengths, page_table,
                                    phi_q, phi_k, slopes, scale=scale,
-                                   impl=impl, block_k=block_k,
-                                   kv_layout=kv_layout, max_pages=max_pages,
+                                   impl=impl, max_pages=max_pages,
                                    layer=layer)
     assert layer is None, "layer= indexes a paged pool stack"
     b, _, h, d = q.shape
-    if kv_layout == "bhsd":
-        kvh, s_len = k_cache.shape[1], k_cache.shape[2]
-    else:
-        s_len, kvh = k_cache.shape[1], k_cache.shape[2]
     dv = v_cache.shape[-1]
     scale = (1.0 / float(np.sqrt(d))) if scale is None else scale
     impl = resolve_impl(impl)
@@ -527,97 +499,21 @@ def flash_decode(
         eps = jnp.asarray(1e-30, jnp.float32)
         o = q[..., :1].astype(jnp.float32) * eps + dep * eps
         return jnp.broadcast_to(o, (b, 1, h, dv)).astype(q.dtype)
-
-    if kv_layout == "bhsd":
-        if impl == "xla":
-            return _xla_decode_head_major(q, k_cache, v_cache, lengths,
-                                          phi_q, phi_k, slopes, scale)
-        return _pallas_decode_hm(q, k_cache, v_cache, lengths, phi_q, phi_k,
-                                 slopes, scale, block_k,
-                                 interpret=(impl == "pallas_interpret"))
-
     if impl == "xla":
-        phi_k_x = phi_k
-        if phi_k_x is not None and phi_k_x.shape[2] not in (1, h):
-            # per-kv-head factors: expand over each kv head's query group
-            assert h % phi_k_x.shape[2] == 0, (phi_k_x.shape, h)
-            phi_k_x = jnp.repeat(phi_k_x, h // phi_k_x.shape[2], axis=2)
-        if phi_k_x is not None and phi_k_x.shape[2] == 1:
-            phi_k_x = jnp.broadcast_to(phi_k_x, (b, s_len, h, phi_k_x.shape[-1]))
-        if slopes is not None:
-            # ALiBi factors for the decode row: q at position lengths-1.
-            qpos = (lengths.astype(jnp.float32) - 1.0)[:, None, None, None]
-            pq = jnp.concatenate([-jnp.broadcast_to(qpos, (b, 1, h, 1)),
-                                  jnp.ones((b, 1, h, 1), jnp.float32)], -1)
-            pq = pq * slopes.reshape(1, 1, h, 1)
-            kj = jnp.arange(s_len, dtype=jnp.float32)
-            pk = jnp.stack([jnp.ones_like(kj), kj], -1)[None, :, None, :]
-            phi_k_x = jnp.broadcast_to(pk, (b, s_len, h, 2))
-            phi_q = pq
-        return attn_mod.attention(
-            q, k_cache, v_cache, mask=MaskSpec("none"), scale=scale,
-            phi_q=phi_q, phi_k=phi_k_x, kv_length=lengths,
-            impl="chunked", chunk_size=min(block_k, s_len))
-
-    # Pallas path, canonical layout: adapt to head-major grouped layout
-    # with padded tiles — this per-call transpose is what kernel-layout
-    # caches (kv_layout="bhsd") avoid.
-    g = h // kvh
-    block_k = min(block_k, s_len)
-    s_p = _ceil_to(s_len, block_k)
-    d_p, dv_p = _ceil_to(d, _LANE), _ceil_to(dv, _LANE)
-    g_p = _ceil_to(g, 8)
-
-    def to_grouped_q(x, last_p):
-        # (B, 1, H, E) -> (B, KVH, G, E) padded
-        x = x[:, 0].reshape(b, kvh, g, x.shape[-1])
-        x = _pad_axis(_pad_axis(x, 2, g_p), 3, last_p)
-        return x
-
-    def to_cache(x, last_p):
-        # (B, S, KVH, E) -> (B, KVH, S_p, E)
-        x = _pad_axis(_pad_axis(x.transpose(0, 2, 1, 3), 2, s_p), 3, last_p)
-        return x
-
-    qt = to_grouped_q(q, d_p)
-    kt = to_cache(k_cache, d_p)
-    vt = to_cache(v_cache, dv_p)
-    pqt = pkt = None
-    if phi_q is not None:
-        r = phi_q.shape[-1]
-        r_p = _ceil_to(r, _LANE)
-        # The grouped-key layout carries ONE key factor per kv head:
-        # per-kv-head (B, S, KVH, R) rides as-is, head-shared broadcasts.
-        # PER-Q-HEAD factors (B, S, H, R) can differ within a GQA group,
-        # which the grouped layout cannot express — route to the XLA path
-        # (the old code silently took each group's first head: ISSUE 3).
-        kvh_pk = phi_k.shape[2]
-        if kvh_pk not in (kvh, 1):
-            assert kvh_pk == h, (phi_k.shape, h, kvh)
-            return flash_decode(q, k_cache, v_cache, lengths, phi_q, phi_k,
-                                slopes, scale=scale, impl="xla",
-                                block_k=block_k)
-        pqt = to_grouped_q(phi_q, r_p)
-        if kvh_pk == kvh:
-            pk_kv = phi_k
-        else:
-            pk_kv = jnp.broadcast_to(phi_k, (b, s_len, kvh, r))
-        pkt = to_cache(pk_kv, r_p)
-
-    out = _fd.flash_decode_fwd(
-        qt, kt, vt, lengths, pqt, pkt, _grouped_slopes(slopes, kvh, g),
-        scale=scale, block_k=block_k, interpret=(impl == "pallas_interpret"))
-    out = out[:, :, :g, :dv].reshape(b, 1, h, dv)
-    return out
+        return _xla_decode(q, k_cache, v_cache, lengths, phi_q, phi_k,
+                           slopes, scale)
+    return _pallas_decode(q, k_cache, v_cache, lengths, phi_q, phi_k, slopes,
+                          scale, block_k,
+                          interpret=(impl == "pallas_interpret"))
 
 
-def _pallas_decode_hm(q, k_cache, v_cache, lengths, phi_q, phi_k, slopes,
-                      scale, block_k, interpret):
-    """Kernel-layout contiguous Pallas decode: the cache IS the kernel
-    layout — q-side reshapes/pads are token-sized, and under the layout
-    contract (lane-aligned hd, block-divisible S) the cache tensors pass
-    through untouched. Off-contract shapes fall back to a correctness pad
-    (tiny test caches; never the serve engine)."""
+def _pallas_decode(q, k_cache, v_cache, lengths, phi_q, phi_k, slopes,
+                   scale, block_k, interpret):
+    """Contiguous Pallas decode: the cache IS the kernel layout — q-side
+    reshapes/pads are token-sized, and under the layout contract (lane-
+    aligned hd, block-divisible S) the cache tensors pass through
+    untouched. Off-contract shapes fall back to a correctness pad (tiny
+    test caches; never the serve engine)."""
     b, _, h, d = q.shape
     kvh, s_len = k_cache.shape[1], k_cache.shape[2]
     dv = v_cache.shape[-1]
@@ -672,17 +568,11 @@ def _grouped_slopes(slopes, kvh: int, g: int):
 
 
 def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
-                        phi_q, phi_k, slopes, *, scale, impl, block_k,
-                        kv_layout="bshd", max_pages=None, layer=None):
-    """Paged dispatch for ``flash_decode`` (see its docstring for layouts)."""
+                        phi_q, phi_k, slopes, *, scale, impl, max_pages=None,
+                        layer=None):
+    """Paged dispatch for ``flash_decode`` (see its docstring for shapes)."""
     b, _, h, d = q.shape
-    if layer is not None and kv_layout == "bshd":
-        # canonical pools adapt per call anyway: take the layer's pool
-        k_pages, v_pages, layer = k_pages[layer], v_pages[layer], None
-    if kv_layout == "bhsd":
-        kvh, n_pages, ps = k_pages.shape[-4:-1]
-    else:
-        n_pages, ps, kvh = k_pages.shape[:3]
+    kvh, n_pages, ps = k_pages.shape[-4:-1]
     dv = v_pages.shape[-1]
     p_slot = page_table.shape[1]
     scale = (1.0 / float(np.sqrt(d))) if scale is None else scale
@@ -690,7 +580,7 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
     p_cap = _static_page_cap(lengths, ps, p_slot, max_pages)
     pt = jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1)[:, :p_cap]
 
-    if kv_layout == "bhsd" and impl in ("xla", "io_stub"):
+    if impl in ("xla", "io_stub"):
         # logical views of the pool, gathered page-granular and capped at
         # p_cap pages — Θ(longest request), not Θ(table width). Everything
         # pool-sized stays in the gather's native (KVH, B, S, E) axis
@@ -746,25 +636,9 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
         return (o.transpose(1, 0, 2, 3).reshape(b, 1, h, dv)
                 .astype(q.dtype))
 
-    if impl in ("xla", "io_stub"):               # canonical pools
-        # gather each request's pages into its logical contiguous view and
-        # reuse the contiguous path (masking past ``lengths`` is identical)
-        def view(pool):
-            gth = pool[pt]                        # (B, P_cap, ps, KVH, E)
-            return gth.reshape(b, p_cap * ps, *pool.shape[2:])
-        phi_view = None
-        if phi_k is not None:
-            slab = phi_k if phi_k.ndim == 4 else phi_k[:, :, None, :]
-            phi_view = view(slab)                 # (B, S_view, KVH|1, R)
-        return flash_decode(q, view(k_pages), view(v_pages), lengths,
-                            phi_q, phi_view, slopes, scale=scale, impl=impl,
-                            block_k=block_k)
-
-    # Pallas path: kv-head-major pools, pages resolved in the kernel's
-    # scalar-prefetch block index maps (no gather, no view materialization).
-    # Kernel layout hands the pools (and the shared phi slab) over as-is;
-    # canonical pools adapt per call (transpose + lane pad + kv-head
-    # broadcast — the legacy cost).
+    # Pallas path: the pools (and the shared phi slab) go to the kernel
+    # as they are; pages resolve in its scalar-prefetch block index maps
+    # (no gather, no view materialization).
     g = h // kvh
     d_p, dv_p = _ceil_to(d, _LANE), _ceil_to(dv, _LANE)
     g_p = _ceil_to(g, 8)
@@ -773,15 +647,8 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
         x = x[:, 0].reshape(b, kvh, g, x.shape[-1])
         return _pad_axis(_pad_axis(x, 2, g_p), 3, last_p)
 
-    if kv_layout == "bhsd":
-        kt = _pad_axis(k_pages, -1, d_p)         # no-op: pools lane-padded
-        vt = _pad_axis(v_pages, -1, dv_p)
-    else:
-        def to_pool(x, last_p):
-            # (n_pages, ps, KVH, E) -> (KVH, n_pages, ps, E_pad)
-            return _pad_axis(x.transpose(2, 0, 1, 3), 3, last_p)
-        kt = to_pool(k_pages, d_p)
-        vt = to_pool(v_pages, dv_p)
+    kt = _pad_axis(k_pages, -1, d_p)             # no-op: pools lane-padded
+    vt = _pad_axis(v_pages, -1, dv_p)
 
     qt = to_grouped_q(q, d_p)
     pqt = pkt = None
@@ -790,21 +657,12 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
         assert phi_q.shape[2] in (h, kvh), (phi_q.shape, h, kvh)
         if phi_q.shape[2] == kvh and kvh != h:    # shared within each group
             phi_q = jnp.repeat(phi_q, g, axis=2)
-        if kv_layout == "bhsd":
-            if phi_k.ndim == 3:                   # layer/kv-head-shared slab
-                pkt = phi_k[None]                 # (1, n_pages, ps, r_pad)
-            else:
-                pkt = phi_k                       # (KVH, n_pages, ps, r_pad)
-            r_p = _ceil_to(max(r, pkt.shape[-1]), _LANE)
-            pkt = _pad_axis(pkt, 3, r_p)          # no-op on padded slabs
+        if phi_k.ndim == 3:                       # layer/kv-head-shared slab
+            pkt = phi_k[None]                     # (1, n_pages, ps, r_pad)
         else:
-            slab = phi_k if phi_k.ndim == 4 else phi_k[:, :, None, :]
-            assert slab.shape[2] in (kvh, 1), (phi_k.shape, kvh)
-            r_p = _ceil_to(max(r, slab.shape[-1]), _LANE)
-            # canonical slab: (n_pages, ps, KVH|1, R) -> kv-head-major; the
-            # kv-head-shared case stays a single copy (broadcast happens in
-            # the kernel's block index maps, not here)
-            pkt = _pad_axis(slab.transpose(2, 0, 1, 3), 3, r_p)
+            pkt = phi_k                           # (KVH, n_pages, ps, r_pad)
+        r_p = _ceil_to(max(r, pkt.shape[-1]), _LANE)
+        pkt = _pad_axis(pkt, 3, r_p)              # no-op on padded slabs
         pqt = to_grouped_q(_pad_axis(phi_q, 3, r_p), r_p)
 
     kernel = functools.partial(_fd.flash_decode_paged_fwd, scale=scale,
@@ -836,7 +694,7 @@ def _flash_decode_paged(q, k_pages, v_pages, lengths, page_table,
 
 def flash_chunk_attention(
     q: jax.Array,                        # (B, C, H, D) chunk queries
-    k_cache: jax.Array,                  # cache/pool, see kv_layout
+    k_cache: jax.Array,                  # (B, KVH, S, E) or a page pool
     v_cache: jax.Array,
     offsets: jax.Array,                  # (B,) int32: abs position of q[:, 0]
     chunk_lens: jax.Array,               # (B,) int32: valid queries (0=frozen)
@@ -844,7 +702,6 @@ def flash_chunk_attention(
     *,
     scale: Optional[float] = None,
     impl: str = "auto",
-    kv_layout: str = "bshd",
     page_table: Optional[jax.Array] = None,
     max_pages: Optional[int] = None,
 ) -> jax.Array:
@@ -864,18 +721,16 @@ def flash_chunk_attention(
     the rank-2 factored form specialized in-place, matching
     ``core.bias.alibi_factors(q_offset=...)`` exactly.
 
-    Layouts mirror ``flash_decode``: contiguous ``kv_layout="bhsd"`` caches
-    ``(B, KVH, S, E)`` / canonical ``(B, S, KVH, E)``; with ``page_table``
-    the caches are page pools (``(KVH, n_pages, ps, E)`` head-major or
-    ``(n_pages, ps, KVH, E)`` canonical) gathered into capped logical views
-    (``max_pages`` from the host-side length mirror, like decode).
+    Caches are kv-head-major like ``flash_decode``'s: contiguous ``(B, KVH,
+    S, E)``; with ``page_table`` page pools ``(KVH, n_pages, ps, E)``
+    gathered into capped logical views (``max_pages`` from the host-side
+    length mirror, like decode).
 
     Chunk attention is an ADMISSION-path program (runs once per chunk, not
     per token), so every impl routes to the head-major XLA path today — the
     decode hot path keeps its Pallas kernels. Returns (B, C, H, Dv_cache);
     lane-padded caches yield a lane-padded Dv for the caller to slice.
     """
-    assert kv_layout in ("bshd", "bhsd"), kv_layout
     b, c, h, d = q.shape
     scale = (1.0 / float(np.sqrt(d))) if scale is None else scale
     resolve_impl(impl)                   # validate; all impls -> XLA here
@@ -883,27 +738,16 @@ def flash_chunk_attention(
     chunk_lens = jnp.asarray(chunk_lens, jnp.int32)
 
     if page_table is not None:
-        if kv_layout == "bhsd":
-            kvh, n_pages, ps = k_cache.shape[:3]
-        else:
-            n_pages, ps, kvh = k_cache.shape[:3]
+        kvh, n_pages, ps = k_cache.shape[:3]
         p_slot = page_table.shape[1]
         p_cap = _static_page_cap(offsets + chunk_lens, ps, p_slot, max_pages)
         pt = jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1)[:, :p_cap]
-        if kv_layout == "bhsd":
-            def view(pool):              # (KVH, B, S_view, E) -> (B, KVH, ...)
-                gth = pool[:, pt].reshape(kvh, b, p_cap * ps, pool.shape[-1])
-                return gth.transpose(1, 0, 2, 3)
-        else:
-            def view(pool):
-                gth = pool[pt].reshape(b, p_cap * ps, kvh, pool.shape[-1])
-                return gth.transpose(0, 2, 1, 3)
+        def view(pool):                  # (KVH, B, S_view, E) -> (B, KVH, ...)
+            gth = pool[:, pt].reshape(kvh, b, p_cap * ps, pool.shape[-1])
+            return gth.transpose(1, 0, 2, 3)
         kv, vv = view(k_cache), view(v_cache)
-    elif kv_layout == "bhsd":
-        kv, vv = k_cache, v_cache        # (B, KVH, S, E) native
     else:
-        kv = k_cache.transpose(0, 2, 1, 3)
-        vv = v_cache.transpose(0, 2, 1, 3)
+        kv, vv = k_cache, v_cache        # (B, KVH, S, E) native
 
     kvh, s_len = kv.shape[1], kv.shape[2]
     dv = vv.shape[-1]

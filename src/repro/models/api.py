@@ -16,8 +16,8 @@
   (logits, cache); ``max_pages`` (static) caps the pages a paged decode
   step can reference (the serve engine derives it from host-side lengths),
 - ``init_cache(batch, max_len)``     — zeroed cache pytree (stored in the
-  kernel-native kv-head-major layout unless ``cfg.cache_layout="legacy"``
-  — see ops.py's cache layout contract),
+  kernel-native kv-head-major layout — see ops.py's cache layout
+  contract),
 - ``insert_cache(dst, src, slots)``  — scatter prefilled wave rows into the
   serve engine's slot cache (out-of-range slot ids are dropped),
 - ``init_paged_cache(batch, n_pages, page_size, pages_per_slot)`` — zeroed
@@ -119,17 +119,13 @@ def _lm_model(cfg: ArchConfig) -> Model:
             (lambda b, n_pages, page_size, pages_per_slot=None:
              lm.init_paged_cache(cfg, b, n_pages, page_size, pages_per_slot))
             if cfg.family in ("dense", "moe", "hybrid") else None),
-        insert_paged=(
-            (lambda dst, src, slots, tables: lm.insert_paged_cache_at_slots(
-                dst, src, slots, tables, layout=cfg.cache_layout))
-            if cfg.family in ("dense", "moe", "hybrid") else None),
+        insert_paged=(lm.insert_paged_cache_at_slots
+                      if cfg.family in ("dense", "moe", "hybrid") else None),
         grow_page_table=(lm.grow_page_tables_at_slots
                          if cfg.family in ("dense", "moe", "hybrid")
                          else None),
-        copy_pages=(
-            (lambda dst, src_ids, dst_ids: lm.copy_paged_pages(
-                dst, src_ids, dst_ids, layout=cfg.cache_layout))
-            if cfg.family in ("dense", "moe", "hybrid") else None),
+        copy_pages=(lm.copy_paged_pages
+                    if cfg.family in ("dense", "moe", "hybrid") else None),
         input_specs=input_specs,
     )
 
@@ -156,12 +152,9 @@ def _blocks_model(cfg: ArchConfig) -> Model:
         init_paged_cache=lambda b, n_pages, page_size, pages_per_slot=None:
             blocks.init_paged_cache(cfg, b, n_pages, page_size,
                                     pages_per_slot),
-        insert_paged=lambda dst, src, slots, tables:
-            lm.insert_paged_cache_at_slots(dst, src, slots, tables,
-                                           layout=cfg.cache_layout),
+        insert_paged=lm.insert_paged_cache_at_slots,
         grow_page_table=lm.grow_page_tables_at_slots,
-        copy_pages=lambda dst, src_ids, dst_ids: lm.copy_paged_pages(
-            dst, src_ids, dst_ids, layout=cfg.cache_layout))
+        copy_pages=lm.copy_paged_pages)
 
 
 def _swin_model(cfg: ArchConfig) -> Model:

@@ -146,14 +146,12 @@ def _attention(lp: dict, x: jax.Array, cfg: ArchConfig, *,
                mask_kind: str, q_offset=0) -> jax.Array:
     """Full-sequence attention (train / prefill). Returns (y, k, v).
 
-    k, v come back in the cfg's CACHE layout: kv-head-major
-    ``(B, KVH, S, hd)`` under ``cache_layout="kernel"`` (when the Pallas
-    kernel runs, the projection einsums write head-major directly and the
-    kernel consumes it zero-copy — prefill emits kernel-layout caches with
-    no post-hoc fixup), canonical ``(B, S, KVH, hd)`` under ``"legacy"``.
+    k, v come back in the cache layout, kv-head-major ``(B, KVH, S, hd)``
+    (when the Pallas kernel runs, the projection einsums write head-major
+    directly and the kernel consumes it zero-copy — prefill emits
+    kernel-layout caches with no post-hoc fixup).
     """
     dt = x.dtype
-    head_major = cfg.cache_layout == "kernel"
 
     slopes = None
     phi_q = phi_k = None
@@ -173,7 +171,7 @@ def _attention(lp: dict, x: jax.Array, cfg: ArchConfig, *,
     # dense-bias baseline) speak canonical, so there the projections stay
     # canonical and only the cache emission transposes (once per prefill —
     # the "cheap view" the layout contract allows off the hot path).
-    hm_compute = (head_major and dense_bias is None
+    hm_compute = (dense_bias is None
                   and kops.resolve_impl(cfg.attn_impl) != "xla")
     if hm_compute:
         q = jnp.einsum("bsd,dhe->bhse", x, lp["wq"].astype(dt))
@@ -207,8 +205,8 @@ def _attention(lp: dict, x: jax.Array, cfg: ArchConfig, *,
             window=cfg.window, scale=cfg.attn_scale, impl=cfg.attn_impl,
             block_q=128, block_k=128)
     y = jnp.einsum("bshe,hed->bsd", o, lp["wo"].astype(dt))
-    if head_major:                       # cache emission only, off hot path
-        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    # cache emission only, off the hot path
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     return constrain(y, "batch", "seq", None), k, v
 
 
@@ -223,14 +221,10 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
     layout a stale page table would otherwise corrupt pages that have been
     reallocated to ANOTHER request.
 
-    Cache layout (ISSUE 5): under ``cfg.cache_layout == "kernel"`` every
-    cache arrives in the kernels' native kv-head-major layout — contiguous
-    / ring ``(B, KVH, S, hd)``, page pools ``(KVH, n_pages, ps, hd_pad)``
-    — and is passed to ``ops.flash_decode`` ZERO-COPY (``kv_layout=
-    "bhsd"``); only the one new token's row is touched per step. The
-    ``"legacy"`` canonical layout (``(B, S, KVH, hd)`` / ``(n_pages, ps,
-    KVH, hd)``) is kept as the layout_vs_legacy A/B + parity reference and
-    pays ops' per-call adaptation.
+    Every cache arrives in the kernels' native kv-head-major layout —
+    contiguous / ring ``(B, KVH, S, hd)``, page pools ``(KVH, n_pages, ps,
+    hd_pad)`` — and is passed to ``ops.flash_decode`` ZERO-COPY; only the
+    one new token's row is touched per step.
 
     Paged mode (``page_table`` given): the pools are the whole ``(L, ...)``
     stack and ``layer`` names this layer. The new token's rows land in
@@ -238,15 +232,13 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
     reads the layer straight out of the stack, so no per-layer pool is
     sliced out or stacked back. ``phi_pages`` is the per-page ALiBi key
     factor slab (layer- and kv-head-shared; lane-padded ``(n_pages, ps,
-    r_pad)`` under the kernel layout); when present the bias is computed
+    r_pad)`` when a Pallas impl runs); when present the bias is computed
     from the CACHED factors (phi mode — factors ride with k, FlashBias
     Sec. 4.3) instead of re-materializing positions. ``max_pages`` statically caps the pages
     any request can reference (the serve engine derives it from host-side
     lengths).
     """
     dt = x.dtype
-    kernel_layout = cfg.cache_layout == "kernel"
-    kv_layout = "bhsd" if kernel_layout else "bshd"
     q = jnp.einsum("bsd,dhe->bshe", x, lp["wq"].astype(dt))
     k_new = jnp.einsum("bsd,dhe->bshe", x, lp["wk"].astype(dt))
     v_new = jnp.einsum("bsd,dhe->bshe", x, lp["wv"].astype(dt))
@@ -269,30 +261,21 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
     # IN PLACE on hardware (one row written); the functional `.at[].set`
     # would count a full cache read+write per layer in cost_analysis.
     skip_scatter = cfg.attn_impl == "io_stub"
-    if page_table is not None:                     # paged full cache
-        if kernel_layout:                    # (L, KVH, n_pages, ps, hd_pad)
-            n_pages, ps = k_cache.shape[2], k_cache.shape[3]
-        else:                                # (L, n_pages, ps, KVH, hd)
-            n_pages, ps = k_cache.shape[1], k_cache.shape[2]
+    if page_table is not None:     # paged (L, KVH, n_pages, ps, hd_pad)
+        n_pages, ps = k_cache.shape[2], k_cache.shape[3]
         pos = lengths - 1
         page = drop_if_frozen(page_table[bidx, pos // ps], n_pages)
         if not skip_scatter:
-            if kernel_layout:
-                # one scatter of the B x KVH token rows, every axis but the
-                # lanes indexed: a window of lanes alone keeps the pool in
-                # the layout the kernel reads (a window over kv heads too
-                # makes XLA relay the whole stack out around the scatter)
-                kv = jnp.arange(k_cache.shape[1])[None, :]
-                at = (layer, kv, page[:, None], (pos % ps)[:, None])
-                k_cache = k_cache.at[at].set(new_row(k_new, k_cache),
-                                             mode="drop")
-                v_cache = v_cache.at[at].set(new_row(v_new, v_cache),
-                                             mode="drop")
-            else:
-                k_cache = k_cache.at[layer, page, pos % ps].set(
-                    k_new[:, 0], mode="drop")
-                v_cache = v_cache.at[layer, page, pos % ps].set(
-                    v_new[:, 0], mode="drop")
+            # one scatter of the B x KVH token rows, every axis but the
+            # lanes indexed: a window of lanes alone keeps the pool in the
+            # layout the kernel reads (a window over kv heads too makes XLA
+            # relay the whole stack out around the scatter)
+            kv = jnp.arange(k_cache.shape[1])[None, :]
+            at = (layer, kv, page[:, None], (pos % ps)[:, None])
+            k_cache = k_cache.at[at].set(new_row(k_new, k_cache),
+                                         mode="drop")
+            v_cache = v_cache.at[at].set(new_row(v_new, v_cache),
+                                         mode="drop")
         phi_q = phi_k = None
         if slopes is not None and phi_pages is not None:
             # same rank-2 q factor the ops ALiBi path materializes; the key
@@ -307,95 +290,60 @@ def _attention_decode(lp: dict, x: jax.Array, k_cache, v_cache, lengths,
                               phi_k=phi_k, slopes=slopes,
                               scale=cfg.attn_scale, impl=cfg.attn_impl,
                               block_k=cfg.attn_chunk, page_table=page_table,
-                              kv_layout=kv_layout, max_pages=max_pages,
-                              layer=layer)
+                              max_pages=max_pages, layer=layer)
         # lane-padded pools return lane-padded values; the pad rows are
         # zero so slicing them off is exact (token-sized, not pool-sized)
         o = o[..., :v_new.shape[-1]]
-    elif cfg.window and cfg.window == k_cache.shape[2 if kernel_layout
-                                                    else 1]:  # ring (SWA)
+    elif cfg.window and cfg.window == k_cache.shape[2]:       # ring (SWA)
         sc = cfg.window
         slot = drop_if_frozen((lengths - 1) % sc, sc)
         if not skip_scatter:
-            if kernel_layout:
-                k_cache = k_cache.at[bidx, :, slot].set(k_new[:, 0],
-                                                        mode="drop")
-                v_cache = v_cache.at[bidx, :, slot].set(v_new[:, 0],
-                                                        mode="drop")
-            else:
-                k_cache = k_cache.at[bidx, slot].set(k_new[:, 0], mode="drop")
-                v_cache = v_cache.at[bidx, slot].set(v_new[:, 0], mode="drop")
-        o = _ring_window_attention(q, k_cache, v_cache, lengths, slopes, cfg,
-                                   head_major=kernel_layout)
+            k_cache = k_cache.at[bidx, :, slot].set(k_new[:, 0], mode="drop")
+            v_cache = v_cache.at[bidx, :, slot].set(v_new[:, 0], mode="drop")
+        o = _ring_window_attention(q, k_cache, v_cache, lengths, slopes, cfg)
     else:                                          # contiguous full cache
-        sc = k_cache.shape[2 if kernel_layout else 1]
+        sc = k_cache.shape[2]
         pos = drop_if_frozen(lengths - 1, sc)
         if not skip_scatter:
-            if kernel_layout:
-                k_cache = k_cache.at[bidx, :, pos].set(
-                    new_row(k_new, k_cache), mode="drop")
-                v_cache = v_cache.at[bidx, :, pos].set(
-                    new_row(v_new, v_cache), mode="drop")
-            else:
-                k_cache = k_cache.at[bidx, pos].set(k_new[:, 0], mode="drop")
-                v_cache = v_cache.at[bidx, pos].set(v_new[:, 0], mode="drop")
+            k_cache = k_cache.at[bidx, :, pos].set(
+                new_row(k_new, k_cache), mode="drop")
+            v_cache = v_cache.at[bidx, :, pos].set(
+                new_row(v_new, v_cache), mode="drop")
         o = kops.flash_decode(q, k_cache, v_cache, lengths, slopes=slopes,
                               scale=cfg.attn_scale, impl=cfg.attn_impl,
-                              block_k=cfg.attn_chunk, kv_layout=kv_layout)
+                              block_k=cfg.attn_chunk)
         o = o[..., :v_new.shape[-1]]     # lane-padded caches return padded
     y = jnp.einsum("bshe,hed->bsd", o, lp["wo"].astype(dt))
     return y, k_cache, v_cache
 
 
-def _ring_window_attention(q, k_cache, v_cache, lengths, slopes, cfg, *,
-                           head_major=False):
-    """Dense decode over a ring cache of size window (small: <= few K).
+def _ring_window_attention(q, k_cache, v_cache, lengths, slopes, cfg):
+    """Dense decode over a ring cache ``(B, KVH, W, hd)`` of size window
+    (small: <= few K) — grouped einsums consume it directly (no G-fold
+    ``jnp.repeat`` of the window, no transpose).
 
     Slot s holds absolute position p = len-1 - ((len-1 - s) mod W), valid
     iff p >= 0. ALiBi bias from absolute positions; softmax over the window.
-
-    ``head_major``: the ring cache is kernel-layout ``(B, KVH, W, hd)`` —
-    grouped einsums consume it directly (no G-fold ``jnp.repeat`` of the
-    window, no transpose).
     """
     b, _, h, e = q.shape
     scale = 1.0 / np.sqrt(e)
-    if head_major:
-        kvh, w = k_cache.shape[1], k_cache.shape[2]
-        g = h // kvh
-        slot = jnp.arange(w)
-        last = (lengths - 1)[:, None]
-        pos = last - ((last - slot) % w)                 # (B, W)
-        valid = pos >= 0
-        qg = q[:, 0].reshape(b, kvh, g, e).astype(jnp.float32)
-        s = jnp.einsum("bkge,bkwe->bkgw", qg,
-                       k_cache.astype(jnp.float32)) * scale
-        if slopes is not None:
-            rel = (pos - last).astype(jnp.float32)       # <= 0
-            s = s + slopes.reshape(kvh, g)[None, :, :, None] \
-                * rel[:, None, None, :]
-        s = jnp.where(valid[:, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkgw,bkwe->bkge", p, v_cache.astype(jnp.float32))
-        return o.reshape(b, 1, h, e).astype(q.dtype)
-    w = k_cache.shape[1]
-    kvh = k_cache.shape[2]
+    kvh, w = k_cache.shape[1], k_cache.shape[2]
     g = h // kvh
     slot = jnp.arange(w)
     last = (lengths - 1)[:, None]
     pos = last - ((last - slot) % w)                     # (B, W)
     valid = pos >= 0
-    kf = jnp.repeat(k_cache, g, axis=2)
-    vf = jnp.repeat(v_cache, g, axis=2)
-    s = jnp.einsum("bhe,bwhe->bhw", q[:, 0].astype(jnp.float32),
-                   kf.astype(jnp.float32)) * scale
+    qg = q[:, 0].reshape(b, kvh, g, e).astype(jnp.float32)
+    s = jnp.einsum("bkge,bkwe->bkgw", qg,
+                   k_cache.astype(jnp.float32)) * scale
     if slopes is not None:
         rel = (pos - last).astype(jnp.float32)           # <= 0
-        s = s + slopes[None, :, None] * rel[:, None, :]
-    s = jnp.where(valid[:, None, :], s, -1e30)
+        s = s + slopes.reshape(kvh, g)[None, :, :, None] \
+            * rel[:, None, None, :]
+    s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhw,bwhe->bhe", p, vf.astype(jnp.float32))
-    return o[:, None].astype(q.dtype)
+    o = jnp.einsum("bkgw,bkwe->bkge", p, v_cache.astype(jnp.float32))
+    return o.reshape(b, 1, h, e).astype(q.dtype)
 
 
 def _attention_chunk(lp: dict, x: jax.Array, k_cache, v_cache,
@@ -420,8 +368,6 @@ def _attention_chunk(lp: dict, x: jax.Array, k_cache, v_cache,
     chunk size at ``window`` (positions must land on distinct slots).
     """
     dt = x.dtype
-    kernel_layout = cfg.cache_layout == "kernel"
-    kv_layout = "bhsd" if kernel_layout else "bshd"
     b, c, _ = x.shape
     q = jnp.einsum("bsd,dhe->bshe", x, lp["wq"].astype(dt))
     k_new = jnp.einsum("bsd,dhe->bshe", x, lp["wk"].astype(dt))
@@ -441,34 +387,25 @@ def _attention_chunk(lp: dict, x: jax.Array, k_cache, v_cache,
             x_new = jnp.pad(x_new, ((0, 0),) * 3 + ((0, pad),))
         return x_new
 
-    if page_table is not None:                            # paged full cache
-        if kernel_layout:                # (KVH, n_pages, ps, hd_pad)
-            n_pages, ps = k_cache.shape[1], k_cache.shape[2]
-        else:                            # (n_pages, ps, KVH, hd)
-            n_pages, ps = k_cache.shape[0], k_cache.shape[1]
+    if page_table is not None:     # paged (KVH, n_pages, ps, hd_pad)
+        n_pages, ps = k_cache.shape[1], k_cache.shape[2]
         page = jnp.where(valid, page_table[bidx[:, None], pos // ps], n_pages)
         flat_pg, flat_ix = page.reshape(-1), (pos % ps).reshape(-1)
-        if kernel_layout:
-            kvh = k_new.shape[2]
-            def rows(x_new, pool):       # -> (KVH, B*C, hd_pad)
-                r = pad_rows(x_new, pool)
-                return r.transpose(2, 0, 1, 3).reshape(kvh, b * c, -1)
-            k_cache = k_cache.at[:, flat_pg, flat_ix].set(
-                rows(k_new, k_cache), mode="drop")
-            v_cache = v_cache.at[:, flat_pg, flat_ix].set(
-                rows(v_new, v_cache), mode="drop")
-        else:
-            k_cache = k_cache.at[flat_pg, flat_ix].set(
-                k_new.reshape(b * c, *k_new.shape[2:]), mode="drop")
-            v_cache = v_cache.at[flat_pg, flat_ix].set(
-                v_new.reshape(b * c, *v_new.shape[2:]), mode="drop")
+        kvh = k_new.shape[2]
+
+        def rows(x_new, pool):           # -> (KVH, B*C, hd_pad)
+            r = pad_rows(x_new, pool)
+            return r.transpose(2, 0, 1, 3).reshape(kvh, b * c, -1)
+        k_cache = k_cache.at[:, flat_pg, flat_ix].set(
+            rows(k_new, k_cache), mode="drop")
+        v_cache = v_cache.at[:, flat_pg, flat_ix].set(
+            rows(v_new, v_cache), mode="drop")
         o = kops.flash_chunk_attention(
             q, k_cache, v_cache, offsets, chunk_lens, slopes,
-            scale=cfg.attn_scale, impl=cfg.attn_impl, kv_layout=kv_layout,
+            scale=cfg.attn_scale, impl=cfg.attn_impl,
             page_table=page_table, max_pages=max_pages)
         o = o[..., :v_new.shape[-1]]
-    elif cfg.window and cfg.window == k_cache.shape[2 if kernel_layout
-                                                    else 1]:  # ring (SWA)
+    elif cfg.window and cfg.window == k_cache.shape[2]:       # ring (SWA)
         w = cfg.window
         assert c <= w, (c, w)            # distinct ring slots per chunk
         h = q.shape[2]
@@ -484,10 +421,8 @@ def _attention_chunk(lp: dict, x: jax.Array, k_cache, v_cache,
         slot_written = (p_old >= 0) & (offsets[:, None] > 0)
         qg = (q.reshape(b, c, kvh, g, e).transpose(0, 2, 3, 1, 4)
               .astype(jnp.float32))                       # (B,KVH,G,C,E)
-        kf = k_cache if kernel_layout else k_cache.transpose(0, 2, 1, 3)
-        vf = v_cache if kernel_layout else v_cache.transpose(0, 2, 1, 3)
         s_old = jnp.einsum("bkgce,bkwe->bkgcw", qg,
-                           kf.astype(jnp.float32)) * scale
+                           k_cache.astype(jnp.float32)) * scale
         kn = k_new.transpose(0, 2, 1, 3).astype(jnp.float32)  # (B,KVH,C,E)
         vn = v_new.transpose(0, 2, 1, 3).astype(jnp.float32)
         s_in = jnp.einsum("bkgce,bkje->bkgcj", qg, kn) * scale
@@ -508,36 +443,26 @@ def _attention_chunk(lp: dict, x: jax.Array, k_cache, v_cache,
             jnp.where(m_in[:, None, None], s_in, -1e30)], axis=-1)
         p_all = jax.nn.softmax(s_all, axis=-1)
         o = jnp.einsum("bkgcw,bkwe->bkgce", p_all[..., :w],
-                       vf.astype(jnp.float32)) \
+                       v_cache.astype(jnp.float32)) \
             + jnp.einsum("bkgcj,bkje->bkgce", p_all[..., w:], vn)
         o = (o.transpose(0, 3, 1, 2, 4).reshape(b, c, h, e)
              .astype(q.dtype))
         # rotate the chunk into the ring AFTER attending (frozen rows drop)
         ring_slot = jnp.where(valid, pos % w, w)
-        if kernel_layout:
-            k_cache = k_cache.at[bidx[:, None], :, ring_slot].set(
-                k_new, mode="drop")
-            v_cache = v_cache.at[bidx[:, None], :, ring_slot].set(
-                v_new, mode="drop")
-        else:
-            k_cache = k_cache.at[bidx[:, None], ring_slot].set(
-                k_new, mode="drop")
-            v_cache = v_cache.at[bidx[:, None], ring_slot].set(
-                v_new, mode="drop")
+        k_cache = k_cache.at[bidx[:, None], :, ring_slot].set(
+            k_new, mode="drop")
+        v_cache = v_cache.at[bidx[:, None], :, ring_slot].set(
+            v_new, mode="drop")
     else:                                                 # contiguous full
-        sc = k_cache.shape[2 if kernel_layout else 1]
+        sc = k_cache.shape[2]
         pos_w = jnp.where(valid, pos, sc)
-        if kernel_layout:
-            k_cache = k_cache.at[bidx[:, None], :, pos_w].set(
-                pad_rows(k_new, k_cache), mode="drop")
-            v_cache = v_cache.at[bidx[:, None], :, pos_w].set(
-                pad_rows(v_new, v_cache), mode="drop")
-        else:
-            k_cache = k_cache.at[bidx[:, None], pos_w].set(k_new, mode="drop")
-            v_cache = v_cache.at[bidx[:, None], pos_w].set(v_new, mode="drop")
+        k_cache = k_cache.at[bidx[:, None], :, pos_w].set(
+            pad_rows(k_new, k_cache), mode="drop")
+        v_cache = v_cache.at[bidx[:, None], :, pos_w].set(
+            pad_rows(v_new, v_cache), mode="drop")
         o = kops.flash_chunk_attention(
             q, k_cache, v_cache, offsets, chunk_lens, slopes,
-            scale=cfg.attn_scale, impl=cfg.attn_impl, kv_layout=kv_layout)
+            scale=cfg.attn_scale, impl=cfg.attn_impl)
         o = o[..., :v_new.shape[-1]]
     y = jnp.einsum("bshe,hed->bsd", o, lp["wo"].astype(dt))
     return y, k_cache, v_cache
@@ -1017,24 +942,23 @@ def prefill(params, batch, cfg: ArchConfig, *, max_len: Optional[int] = None,
 def _prefill_cache(caches: dict, cfg: ArchConfig, lens, total: int,
                    max_len: int) -> dict:
     """The cache a prefill hands on, from its per-layer entries: K/V padded
-    to ``max_len`` (or rotated into a ring of ``window``) in the cfg's cache
+    to ``max_len`` (or rotated into a ring of ``window``) in the cache
     layout, SSM state and conv tails as they are."""
     b = lens.shape[0]
     cache = {"length": lens}
     if "k" in caches:
         sc = cfg.window if (cfg.window and cfg.window < max_len) else max_len
-        # k/v ride in the cfg's cache layout straight out of _attention:
-        # kernel (L,B,KVH,S,hd) — seq axis 3; legacy (L,B,S,KVH,hd) — axis 2
-        kernel = cfg.cache_layout == "kernel"
+        # k/v ride in the cache layout straight out of _attention:
+        # (L, B, KVH, S, hd), seq axis 3
         ring = bool(cfg.window) and cfg.window < max_len
-        seq_ax = 3 if kernel else 2
+        seq_ax = 3
         k, v = caches["k"], caches["v"]
         if sc >= total:
-            # full caches AND ring caches whose prompt fits the window
+            # full caches AND ring caches whose prompt fits the window; the
+            # lane pad matches init_cache's once-at-allocation pad
             pad = [(0, 0)] * 5
             pad[seq_ax] = (0, sc - total)
-            if kernel:   # match init_cache's once-at-allocation lane pad
-                pad[4] = (0, _contig_hd_alloc(cfg, ring) - k.shape[-1])
+            pad[4] = (0, _contig_hd_alloc(cfg, ring) - k.shape[-1])
             cache["k"] = jnp.pad(k, pad)
             cache["v"] = jnp.pad(v, pad)
         else:
@@ -1215,7 +1139,7 @@ def prefill_chunk(params, cache, tokens, cfg: ArchConfig, *, offsets,
 
 
 def _contig_hd_alloc(cfg: ArchConfig, ring: bool) -> int:
-    """Stored head dim of a kernel-layout contiguous cache: 128-lane-padded
+    """Stored head dim of a contiguous cache: 128-lane-padded
     when the Pallas kernel will consume it (pad once at allocation, never
     per step), raw ``hd`` for ring caches (dense XLA window path) and XLA
     backends (head-major einsums read unpadded pools directly)."""
@@ -1229,15 +1153,13 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, *,
                length: int = 0) -> dict:
     """Empty cache pytree (zeros) for decode-only dry-runs and serving.
 
-    Layout follows ``cfg.cache_layout``: kernel-native kv-head-major
-    ``(L, B, KVH, S, hd[_pad])`` (the flash-decode kernel reads it
-    zero-copy — see ops.py's layout contract; like the paged pools, the
-    head dim is 128-lane-padded HERE, ONCE, when a Pallas impl will run
-    and the cache is full-KV — a non-aligned hd like stablelm's 160 would
-    otherwise be re-padded every decode step, the exact Θ(pool) cost this
-    layout deletes; ring caches feed the dense XLA window path and stay
-    unpadded) or legacy canonical ``(L, B, S, KVH, hd)`` (the A/B +
-    parity reference).
+    K/V are kernel-native kv-head-major ``(L, B, KVH, S, hd[_pad])``: the
+    flash-decode kernel reads them zero-copy (see ops.py's layout
+    contract). Like the paged pools, the head dim is 128-lane-padded HERE,
+    ONCE, when a Pallas impl will run and the cache is full-KV — a
+    non-aligned hd like stablelm's 160 would otherwise be re-padded every
+    decode step; ring caches feed the dense XLA window path and stay
+    unpadded.
     """
     dt = jnp.dtype(cfg.dtype)
     l = cfg.n_layers
@@ -1245,14 +1167,9 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, *,
     if cfg.family in ("dense", "moe", "hybrid"):
         ring = bool(cfg.window) and cfg.window < max_len
         sc = cfg.window if ring else max_len
-        kvp, hd = cfg.kv_heads_padded, cfg.resolved_head_dim
-        if cfg.cache_layout == "kernel":
-            hd_alloc = _contig_hd_alloc(cfg, ring)
-            cache["k"] = jnp.zeros((l, batch, kvp, sc, hd_alloc), dt)
-            cache["v"] = jnp.zeros((l, batch, kvp, sc, hd_alloc), dt)
-        else:
-            cache["k"] = jnp.zeros((l, batch, sc, kvp, hd), dt)
-            cache["v"] = jnp.zeros((l, batch, sc, kvp, hd), dt)
+        shape = (l, batch, cfg.kv_heads_padded, sc, _contig_hd_alloc(cfg, ring))
+        cache["k"] = jnp.zeros(shape, dt)
+        cache["v"] = jnp.zeros(shape, dt)
     if cfg.family in ("ssm", "hybrid"):
         hs, p, n = cfg.ssm_heads_padded, cfg.ssm_head_dim, cfg.ssm_state
         w = cfg.conv_width
@@ -1277,31 +1194,24 @@ def init_paged_cache(cfg: ArchConfig, batch: int, n_pages: int,
     stay on the slot-contiguous discipline; SSM leaves of a hybrid arch
     ride along unchanged.
 
-    Under ``cfg.cache_layout == "kernel"`` the pools are born in the
-    flash-decode kernel's native layout — kv-head-major ``(L, KVH,
-    n_pages, ps, hd[_pad])`` — and the slab stays layer- and kv-head-
-    shared (the kv-head broadcast lives in the kernel's block index maps).
-    The decode step then hands every pool to the kernel zero-copy. The
-    128-lane pad on the trailing dim exists purely for the Pallas TPU
-    tiles, so it is applied HERE, ONCE, and only when a Pallas impl will
-    actually run (``resolve_impl``); the XLA fallback keeps unpadded pools
-    and would otherwise gather real padding bytes every step. ``"legacy"``
-    keeps the canonical ``(L, n_pages, ps, KVH, hd)`` pools + ``(n_pages,
-    ps, 2)`` slab that ops re-lays-out per step (the layout_vs_legacy A/B
-    baseline).
+    The pools are born in the flash-decode kernel's native layout —
+    kv-head-major ``(L, KVH, n_pages, ps, hd[_pad])`` — and the slab stays
+    layer- and kv-head-shared (the kv-head broadcast lives in the kernel's
+    block index maps). The decode step then hands every pool to the kernel
+    zero-copy. The 128-lane pad on the trailing dim (and the slab's, from
+    2 to 128) exists purely for the Pallas TPU tiles, so it is applied
+    HERE, ONCE, and only when a Pallas impl will actually run
+    (``resolve_impl``); the XLA fallback keeps unpadded pools and would
+    otherwise gather real padding bytes every step.
     """
     assert cfg.family in ("dense", "moe", "hybrid"), cfg.family
     dt = jnp.dtype(cfg.dtype)
     l = cfg.n_layers
     kvp, hd = cfg.kv_heads_padded, cfg.resolved_head_dim
     pps = pages_per_slot or n_pages
-    kernel = cfg.cache_layout == "kernel"
     pallas = kops.resolve_impl(cfg.attn_impl).startswith("pallas")
-    if kernel:
-        hd_pad = (-(-hd // 128) * 128) if pallas else hd
-        pool_shape = (l, kvp, n_pages, page_size, hd_pad)
-    else:
-        pool_shape = (l, n_pages, page_size, kvp, hd)
+    hd_pad = (-(-hd // 128) * 128) if pallas else hd
+    pool_shape = (l, kvp, n_pages, page_size, hd_pad)
     cache = {
         "length": jnp.zeros((batch,), jnp.int32),
         "pages_k": jnp.zeros(pool_shape, dt),
@@ -1309,7 +1219,7 @@ def init_paged_cache(cfg: ArchConfig, batch: int, n_pages: int,
         "page_table": jnp.zeros((batch, pps), jnp.int32),
     }
     if cfg.bias_kind == "alibi":
-        r_slab = 128 if (kernel and pallas) else 2
+        r_slab = 128 if pallas else 2
         cache["pages_phi"] = jnp.zeros((n_pages, page_size, r_slab),
                                        jnp.float32)
     if cfg.family == "hybrid":
@@ -1321,14 +1231,13 @@ def init_paged_cache(cfg: ArchConfig, batch: int, n_pages: int,
     return cache
 
 
-def insert_paged_cache_at_slots(dst: dict, src: dict, slots, tables, *,
-                                layout: str = "kernel") -> dict:
+def insert_paged_cache_at_slots(dst: dict, src: dict, slots, tables) -> dict:
     """Scatter a prefilled wave into the paged cache, whole pages at a time.
 
     ``src`` is a contiguous wave cache from ``prefill`` whose sequence
-    length S is a page multiple, in the same ``layout`` the pool uses
-    (prefill emits it that way — kernel-layout pages scatter into the
-    kernel-layout pool DIRECTLY, there is no post-hoc fixup pass).
+    length S is a page multiple, kv-head-major like the pool (prefill emits
+    it that way — its pages scatter into the pool DIRECTLY, there is no
+    post-hoc fixup pass).
     ``tables`` (W, pages_per_slot) int32 holds each wave row's full
     page-table row — the pages covering its prompt first, then any pages
     reserved for decode growth; unused entries carry an out-of-range id
@@ -1338,16 +1247,11 @@ def insert_paged_cache_at_slots(dst: dict, src: dict, slots, tables, *,
     per-slot ``length`` scatter at ``slots``; SSM leaves (hybrid) ride the
     slot path of ``insert_cache_at_slots``.
     """
-    assert layout in ("kernel", "legacy"), layout
     slots = jnp.asarray(slots, jnp.int32)
     tables = jnp.asarray(tables, jnp.int32)
-    kernel = layout == "kernel"
-    if kernel:          # pool (L, KVH, n_pages, ps, hd_pad); src (L,W,KVH,S,hd)
-        n_pages, ps = dst["pages_k"].shape[2], dst["pages_k"].shape[3]
-        s = src["k"].shape[3]
-    else:               # pool (L, n_pages, ps, KVH, hd); src (L, W, S, KVH, hd)
-        n_pages, ps = dst["pages_k"].shape[1], dst["pages_k"].shape[2]
-        s = src["k"].shape[2]
+    # pool (L, KVH, n_pages, ps, hd_pad); src (L, W, KVH, S, hd)
+    n_pages, ps = dst["pages_k"].shape[2], dst["pages_k"].shape[3]
+    s = src["k"].shape[3]
     w = tables.shape[0]
     assert s % ps == 0, (s, ps)
     p_w = s // ps
@@ -1361,26 +1265,20 @@ def insert_paged_cache_at_slots(dst: dict, src: dict, slots, tables, *,
     out = dict(dst)
     for key, pool_key in (("k", "pages_k"), ("v", "pages_v")):
         kv = src[key]
-        l = kv.shape[0]
-        if kernel:
-            kvh, hd = kv.shape[2], kv.shape[4]
-            pages = kv.reshape(l, w, kvh, p_w, ps, hd)
-            pages = pages.transpose(0, 2, 1, 3, 4, 5)
-            pages = pages.reshape(l, kvh, w * p_w, ps, hd)
-            hd_pad = dst[pool_key].shape[-1]
-            if hd_pad != hd:              # pool is lane-padded at init
-                pages = jnp.pad(pages, ((0, 0),) * 4 + ((0, hd_pad - hd),))
-            out[pool_key] = dst[pool_key].at[:, :, flat_ids].set(
-                pages, mode="drop")
-        else:
-            pages = kv.reshape(l, w * p_w, ps, *kv.shape[3:])
-            out[pool_key] = dst[pool_key].at[:, flat_ids].set(pages,
-                                                              mode="drop")
+        l, kvh, hd = kv.shape[0], kv.shape[2], kv.shape[4]
+        pages = kv.reshape(l, w, kvh, p_w, ps, hd)
+        pages = pages.transpose(0, 2, 1, 3, 4, 5)
+        pages = pages.reshape(l, kvh, w * p_w, ps, hd)
+        hd_pad = dst[pool_key].shape[-1]
+        if hd_pad != hd:                  # pool is lane-padded at init
+            pages = jnp.pad(pages, ((0, 0),) * 4 + ((0, hd_pad - hd),))
+        out[pool_key] = dst[pool_key].at[:, :, flat_ids].set(
+            pages, mode="drop")
     if "pages_phi" in dst:
         r_slab = dst["pages_phi"].shape[-1]
         pos = jnp.arange(s, dtype=jnp.float32)
         rows = jnp.stack([jnp.ones_like(pos), pos], -1)   # (S, 2): [1, pos]
-        if r_slab > 2:                    # lane-padded slab (kernel layout)
+        if r_slab > 2:                    # lane-padded slab (Pallas impls)
             rows = jnp.pad(rows, ((0, 0), (0, r_slab - 2)))
         rows = jnp.broadcast_to(rows.reshape(1, p_w, ps, r_slab),
                                 (w, p_w, ps, r_slab))
@@ -1413,8 +1311,7 @@ def grow_page_tables_at_slots(dst: dict, slots, tables) -> dict:
     return out
 
 
-def copy_paged_pages(dst: dict, src_ids, dst_ids, *,
-                     layout: str = "kernel") -> dict:
+def copy_paged_pages(dst: dict, src_ids, dst_ids) -> dict:
     """Copy whole pages ``src_ids[i] -> dst_ids[i]`` across every paged
     pool leaf: K, V, and the ``pages_phi`` factor slab.
 
@@ -1429,20 +1326,14 @@ def copy_paged_pages(dst: dict, src_ids, dst_ids, *,
     just-evicted src page as another entry's dst. Theta(W * page) — never
     pool-sized, and no relayout of the pool itself (statcheck
     ``no-pool-relayout`` holds for this program)."""
-    assert layout in ("kernel", "legacy"), layout
     src_ids = jnp.asarray(src_ids, jnp.int32)
     dst_ids = jnp.asarray(dst_ids, jnp.int32)
-    page_axis = 2 if layout == "kernel" else 1
     out = dict(dst)
     for pool_key in ("pages_k", "pages_v"):
-        pool = dst[pool_key]
-        take = jnp.clip(src_ids, 0, pool.shape[page_axis] - 1)
-        if page_axis == 2:      # kernel: (L, KVH, n_pages, ps, hd_pad)
-            out[pool_key] = pool.at[:, :, dst_ids].set(pool[:, :, take],
-                                                       mode="drop")
-        else:                   # legacy: (L, n_pages, ps, KVH, hd)
-            out[pool_key] = pool.at[:, dst_ids].set(pool[:, take],
-                                                    mode="drop")
+        pool = dst[pool_key]                    # (L, KVH, n_pages, ps, hd_pad)
+        take = jnp.clip(src_ids, 0, pool.shape[2] - 1)
+        out[pool_key] = pool.at[:, :, dst_ids].set(pool[:, :, take],
+                                                   mode="drop")
     if "pages_phi" in dst:
         phi = dst["pages_phi"]                  # (n_pages, ps, r_slab)
         take = jnp.clip(src_ids, 0, phi.shape[0] - 1)

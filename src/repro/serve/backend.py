@@ -407,7 +407,6 @@ class TokenDecodeBackend(Backend):
         the allocator never needs a collective to rewrite an int32 row.
         SSM state shards on batch only (its head dim is padded for the
         kernel, not for the mesh)."""
-        kernel = self.model.cfg.cache_layout == "kernel"
         axes: Dict[str, tuple] = {
             "length": ("batch",),
             "ssm_h": ("layers", "batch", None, None, None),
@@ -415,14 +414,12 @@ class TokenDecodeBackend(Backend):
             "conv_bc": ("layers", "batch", None, None),
         }
         if self.paged:
-            pool = (("layers", "kv_heads", None, None, None) if kernel
-                    else ("layers", None, None, "kv_heads", None))
+            pool = ("layers", "kv_heads", None, None, None)
             axes.update(pages_k=pool, pages_v=pool,
                         page_table=(None, None),
                         pages_phi=(None, None, None))
         else:
-            kv = (("layers", "batch", "kv_heads", None, None) if kernel
-                  else ("layers", "batch", None, "kv_heads", None))
+            kv = ("layers", "batch", "kv_heads", None, None)
             axes.update(k=kv, v=kv)
         return axes
 

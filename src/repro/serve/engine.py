@@ -250,29 +250,6 @@ class ServeEngine:
         self._free: List[int] = list(range(n_slots))
         self._advanced = 0              # committed budget units (stall sig)
 
-    # -- legacy aliases: device state lives in the backend now, but the
-    # -- pre-ISSUE-6 attribute names remain the observable surface used by
-    # -- tests and benches
-    @property
-    def _cache(self):
-        return self.backend._cache
-
-    @property
-    def _pool(self):
-        return self.backend._pool
-
-    @property
-    def _slot_pages(self):
-        return self.backend._slot_pages
-
-    @property
-    def _paged(self) -> bool:
-        return self.backend.paged
-
-    @property
-    def _lazy(self) -> bool:
-        return self.backend.lazy
-
     def _page_cap(self) -> Optional[int]:
         return self.backend.page_cap(self._live)
 

@@ -20,12 +20,12 @@ over each closed jaxpr:
 
 Tracing is abstract (``jax.jit(...).trace`` over ``ShapeDtypeStruct``
 params), so the whole sweep runs in seconds on CPU with no kernels
-executed. The default ``attn_impl="pallas_interpret"`` matters: the
-legacy layout's pool transpose lives in the Pallas layout adapters
-(``kernels/ops.py``), so interpret mode is what makes the tripwire able
-to *see* it on CPU CI — and ``verify_tripwire`` proves per run that the
-discrimination still works by checking that ``cache_layout="legacy"``
-fails (a tripwire that cannot fire is not a tripwire).
+executed. The default ``attn_impl="pallas_interpret"`` traces the Pallas
+dispatch in ``kernels/ops.py`` — the exact decode jaxpr the accelerator
+path runs — on CPU CI. ``verify_tripwire`` proves per run that the
+relayout rule can still fire, on a synthetic decode-shaped program that
+transposes a pool per layer (a tripwire that cannot fire is not a
+tripwire).
 """
 from __future__ import annotations
 
@@ -246,45 +246,54 @@ def _check_pair_family(family: str, cfg) -> List[Finding]:
     return findings
 
 
-def check_family(family: str, *, cache_layout: str = "kernel",
+def check_family(family: str, *,
                  impl: str = "pallas_interpret") -> List[Finding]:
     """Trace every jitted serve program of ``family`` and return all rule
     violations (empty list = contracts hold)."""
-    cfg = FAMILIES[family]().replace(cache_layout=cache_layout,
-                                     attn_impl=impl)
+    cfg = FAMILIES[family]().replace(attn_impl=impl)
     if cfg.family == "pairformer":
         return _check_pair_family(family, cfg)
     return _check_token_family(family, cfg)
 
 
 def verify_tripwire(impl: str = "pallas_interpret") -> List[Finding]:
-    """Built-in negative test: ``cache_layout="legacy"`` MUST trip the
-    decode-step pool-relayout rule (the per-layer ``to_pool`` transpose).
-    Returns a finding when it does not — a tripwire that cannot fire
-    would pass every future regression too."""
-    legacy = check_family("dense", cache_layout="legacy", impl=impl)
-    hits = [f for f in legacy
-            if f.rule == "no-pool-relayout" and "decode" in f.program
-            and "transpose" in f.eqn]
+    """Built-in negative test: a decode-shaped program — a ``lax.scan``
+    over the layers of the dense family's real page pool that transposes
+    each layer's pool — MUST trip ``no-pool-relayout`` at the threshold
+    the dense check uses. Returns a finding when it does not: a tripwire
+    that cannot fire would pass every future regression too."""
+    cfg = FAMILIES["dense"]().replace(attn_impl=impl)
+    cache = _token_backend(cfg)._cache
+    thresh = pool_threshold_for(cache, cfg.n_layers)
+    pool = cache["pages_k"]                 # (L, KVH, n_pages, ps, hd)
+
+    def relayout_step(pools, x):
+        def body(x, layer_pool):            # (KVH, n_pages, ps, hd)
+            return x + layer_pool.transpose(1, 2, 0, 3).sum(), None
+        return jax.lax.scan(body, x, pools)[0]
+
+    traced = jax.jit(relayout_step).trace(
+        jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        jax.ShapeDtypeStruct((), pool.dtype))
+    hits = [f for f in no_pool_relayout(traced.jaxpr, thresh,
+                                        program="dense/decode[relayout]")
+            if "transpose" in f.eqn]
     if hits:
         return []
     return [Finding(
-        rule="tripwire-self-test", program="dense/decode[legacy]",
-        message=("cache_layout='legacy' no longer trips the decode-step "
-                 "transpose rule — the tripwire lost its teeth (did the "
-                 "pool threshold calibration or the legacy adapter "
-                 "change?)"))]
+        rule="tripwire-self-test", program="dense/decode[relayout]",
+        message=("a per-layer pool transpose no longer trips the "
+                 "decode-step relayout rule — the tripwire lost its teeth "
+                 "(did the pool threshold calibration change?)"))]
 
 
-def run_contracts(families, *, cache_layout: str = "kernel",
-                  impl: str = "pallas_interpret",
+def run_contracts(families, *, impl: str = "pallas_interpret",
                   self_test: bool = True) -> List[Finding]:
-    """Check ``families`` under one layout/impl; with ``self_test`` also
-    prove the legacy tripwire still fires."""
+    """Check ``families`` under one impl; with ``self_test`` also prove
+    the relayout tripwire still fires."""
     findings: List[Finding] = []
     for family in families:
-        findings += check_family(family, cache_layout=cache_layout,
-                                 impl=impl)
+        findings += check_family(family, impl=impl)
     if self_test:
         findings += verify_tripwire(impl=impl)
     return findings

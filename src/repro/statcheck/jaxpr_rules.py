@@ -11,7 +11,7 @@ Each rule takes a ``ClosedJaxpr`` (plus calibration arguments) and returns
 a list of :class:`Finding`. ``walk_eqns`` descends into every sub-jaxpr
 (scan/while/cond bodies, pjit calls, custom-vjp wrappers, pallas_call
 bodies), so a violation cannot hide inside the layer scan — which is where
-the legacy layout's per-layer pool transpose actually lives.
+a per-layer pool relayout would live.
 
 Calibration notes (empirically pinned by ``tests/test_statcheck.py``):
 
@@ -20,12 +20,11 @@ Calibration notes (empirically pinned by ``tests/test_statcheck.py``):
   whole, which only makes a relayout of them larger), so the threshold is
   ``min(leaf.size // n_layers)`` over the K/V leaves, not the whole-leaf
   size. Token-batch operands (Θ(B·H·D)) sit orders of magnitude below it.
-- The GOOD kernel-native layout emits zero banned pool-sized eqns under
-  both the XLA and interpret-mode Pallas decode paths for every family;
-  ``cache_layout="legacy"`` emits the per-layer ``to_pool`` transpose
-  (paged families, Pallas path) and the GQA ``jnp.repeat`` broadcast
-  (ring families, both paths). ``contracts.verify_tripwire`` keeps this
-  discrimination honest as a built-in negative test.
+- The kv-head-major cache layout emits zero banned pool-sized eqns under
+  both the XLA and interpret-mode Pallas decode paths for every family.
+  ``contracts.verify_tripwire`` keeps the rule honest as a built-in
+  negative test: a synthetic layer scan that transposes each layer's
+  pool must trip it at the dense family's real threshold.
 """
 from __future__ import annotations
 
@@ -118,10 +117,11 @@ def no_pool_relayout(jaxpr, pool_threshold: int, *,
 
     ``pool_threshold`` is the size (element count) of the smallest
     per-layer KV slab of the live cache — anything at or above it is pool
-    traffic, not token traffic. The kernel-native layout feeds the kernels
-    zero-copy, so the GOOD decode jaxpr has no such eqn; the legacy layout
-    pays a per-layer ``transpose`` (paged ``to_pool`` adapter) or a GQA
-    ``broadcast_in_dim`` (ring ``jnp.repeat``) every decoded token.
+    traffic, not token traffic. The kv-head-major caches feed the kernels
+    zero-copy, so a good decode jaxpr has no such eqn; a per-layer
+    ``transpose`` of a pool or a GQA ``broadcast_in_dim`` of a cache
+    (``jnp.repeat`` over the query groups) would cost Θ(pool) every
+    decoded token.
     """
     assert pool_threshold > 0, pool_threshold
     findings = []

@@ -182,7 +182,7 @@ def _priority_run(model, params, cfg, preempt_mid_chunk):
         assert resumed.rid == r_lo and resumed.max_new_tokens == 6
         np.testing.assert_array_equal(resumed.tokens, p_lo)
     eng.run()
-    assert eng._pool.n_free == eng.n_pages               # pages drained
+    assert eng.backend._pool.n_free == eng.n_pages       # pages drained
     return eng.result(r_hi), eng.result(r_lo), streams["lo"]
 
 

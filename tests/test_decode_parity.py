@@ -8,6 +8,10 @@ interpreted Pallas path — must match the LAST ROW of full causal
 Plus regression tests for two GQA phi_k bugs: the full-attention XLA path
 used to collapse per-kv-head factors to kv head 0, and the Pallas decode
 path used to raise on them.
+
+The caches ``flash_decode`` reads are kv-head-major (``(B, KVH, S, *)``,
+pools ``(KVH, n_pages, ps, *)``); the references read the token-major
+tensors they were made from.
 """
 import functools
 
@@ -36,6 +40,11 @@ def _setup(key=0):
     return q, k, v, pq, pk, slopes
 
 
+def _hm(x):
+    """(B, S, KVH, E) -> the caches' kv-head-major (B, KVH, S, E)."""
+    return x.transpose(0, 2, 1, 3)
+
+
 def _bias_kwargs(mode, pq, pk, slopes):
     if mode == "phi":
         return {"phi_q": pq, "phi_k": pk}
@@ -52,11 +61,11 @@ class TestDecodePrefillParity:
         lengths = jnp.asarray(LENGTHS)
         bidx = jnp.arange(B)
         q_dec = q[bidx, LENGTHS - 1][:, None]               # (B,1,H,D)
-        kw = _bias_kwargs(mode, pq, pk, slopes)
+        kw = _bias_kwargs(mode, pq, _hm(pk), slopes)
         if mode == "phi":
             kw["phi_q"] = pq[bidx, LENGTHS - 1][:, None]    # (B,1,H,R)
-        out = ops.flash_decode(q_dec, k, v, lengths, impl=impl, block_k=16,
-                               **kw)
+        out = ops.flash_decode(q_dec, _hm(k), _hm(v), lengths, impl=impl,
+                               block_k=16, **kw)
         for b in range(B):
             n = int(LENGTHS[b])
             kw_b = _bias_kwargs(mode, pq[b:b + 1, :n], pk[b:b + 1, :n],
@@ -75,13 +84,13 @@ class TestDecodePrefillParity:
         lengths = jnp.asarray(LENGTHS)
         bidx = jnp.arange(B)
         q_dec = q[bidx, LENGTHS - 1][:, None]
-        kw = _bias_kwargs(mode, pq, pk, slopes)
+        kw = _bias_kwargs(mode, pq, _hm(pk), slopes)
         if mode == "phi":
             kw["phi_q"] = pq[bidx, LENGTHS - 1][:, None]
-        a = ops.flash_decode(q_dec, k, v, lengths, impl="xla", block_k=16,
-                             **kw)
-        b_ = ops.flash_decode(q_dec, k, v, lengths, impl="pallas_interpret",
-                              block_k=16, **kw)
+        a = ops.flash_decode(q_dec, _hm(k), _hm(v), lengths, impl="xla",
+                             block_k=16, **kw)
+        b_ = ops.flash_decode(q_dec, _hm(k), _hm(v), lengths,
+                              impl="pallas_interpret", block_k=16, **kw)
         np.testing.assert_allclose(a, b_, atol=3e-5)
 
 
@@ -123,39 +132,11 @@ class TestGQAPhiKRegressions:
         bidx = jnp.arange(B)
         q_dec = q[bidx, LENGTHS - 1][:, None]
         pq_dec = pq[bidx, LENGTHS - 1][:, None]
-        out = ops.flash_decode(q_dec, k, v, lengths, phi_q=pq_dec, phi_k=pk,
-                               impl=impl, block_k=16)
+        out = ops.flash_decode(q_dec, _hm(k), _hm(v), lengths, phi_q=pq_dec,
+                               phi_k=_hm(pk), impl=impl, block_k=16)
         want = ref.decode_reference(q_dec, k, v, lengths, phi_q=pq_dec,
                                     phi_k=jnp.repeat(pk, G, axis=2))
         np.testing.assert_allclose(out, want, atol=3e-5)
-
-    @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
-    def test_decode_per_q_head_phi_k(self, impl):
-        """Regression (ISSUE 3): per-Q-HEAD key factors (B, S, H, R) with
-        DISTINCT rows inside each GQA group. The Pallas path's grouped-key
-        layout carries one key factor per kv head and used to silently take
-        each group's first head — it must route this shape to the XLA path
-        instead (and the XLA path must expand nothing: the factors are
-        already per head)."""
-        q, k, v, pq, _, _ = _setup(key=5)
-        # per-q-head factors, guaranteed distinct within every group
-        pk_h = jax.random.normal(jax.random.PRNGKey(55), (B, S, H, R))
-        lengths = jnp.asarray(LENGTHS)
-        bidx = jnp.arange(B)
-        q_dec = q[bidx, LENGTHS - 1][:, None]
-        pq_dec = pq[bidx, LENGTHS - 1][:, None]
-        out = ops.flash_decode(q_dec, k, v, lengths, phi_q=pq_dec,
-                               phi_k=pk_h, impl=impl, block_k=16)
-        want = ref.decode_reference(q_dec, k, v, lengths, phi_q=pq_dec,
-                                    phi_k=pk_h)
-        np.testing.assert_allclose(out, want, atol=3e-5)
-        # the group-first-head collapse must produce DIFFERENT values here,
-        # otherwise this regression would pass vacuously
-        pk_head0 = jnp.repeat(pk_h.reshape(B, S, KVH, G, R)[:, :, :, 0],
-                              G, axis=2)
-        wrong = ref.decode_reference(q_dec, k, v, lengths, phi_q=pq_dec,
-                                     phi_k=pk_head0)
-        assert float(jnp.abs(want - wrong).max()) > 1e-2
 
 
 class TestPagedDecodeParity:
@@ -171,16 +152,17 @@ class TestPagedDecodeParity:
         rng = np.random.RandomState(seed)
         perm = rng.permutation(n_pages)[:B * p].reshape(B, p)
         kp = np.array(jax.random.normal(jax.random.PRNGKey(90 + seed),
-                                        (n_pages, self.PS, KVH, D)))
+                                        (KVH, n_pages, self.PS, D)))
         vp = np.array(jax.random.normal(jax.random.PRNGKey(91 + seed),
-                                        (n_pages, self.PS, KVH, D)))
+                                        (KVH, n_pages, self.PS, D)))
         slab = np.zeros((n_pages, self.PS, 2), np.float32)
         pos = np.arange(S, dtype=np.float32)
         slab_log = np.stack([np.ones(S, np.float32), pos], -1)
         for b in range(B):
             for j in range(p):
-                kp[perm[b, j]] = np.asarray(k[b, j * self.PS:(j + 1) * self.PS])
-                vp[perm[b, j]] = np.asarray(v[b, j * self.PS:(j + 1) * self.PS])
+                rows = slice(j * self.PS, (j + 1) * self.PS)
+                kp[:, perm[b, j]] = np.asarray(_hm(k)[b, :, rows])
+                vp[:, perm[b, j]] = np.asarray(_hm(v)[b, :, rows])
                 slab[perm[b, j]] = slab_log[j * self.PS:(j + 1) * self.PS]
         return (jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(slab),
                 jnp.asarray(perm, jnp.int32))
@@ -193,7 +175,7 @@ class TestPagedDecodeParity:
         bidx = jnp.arange(B)
         q_dec = q[bidx, LENGTHS - 1][:, None]
         kp, vp, slab, pt = self._paginate(k, v)
-        kw_c = _bias_kwargs(mode, pq, pk, slopes)
+        kw_c = _bias_kwargs(mode, pq, _hm(pk), slopes)
         kw_p = dict(kw_c)
         if mode == "phi":
             # paged mode reads key factors from the per-page slab (here the
@@ -203,10 +185,10 @@ class TestPagedDecodeParity:
             kw_c = {"phi_q": pq2,
                     "phi_k": jnp.broadcast_to(
                         jnp.stack([jnp.ones(S), jnp.arange(S, dtype=jnp.float32)],
-                                  -1)[None, :, None, :], (B, S, 1, 2))}
+                                  -1), (B, KVH, S, 2))}
             kw_p = {"phi_q": pq2, "phi_k": slab}
-        want = ops.flash_decode(q_dec, k, v, lengths, impl="xla", block_k=16,
-                                **kw_c)
+        want = ops.flash_decode(q_dec, _hm(k), _hm(v), lengths, impl="xla",
+                                block_k=16, **kw_c)
         got = ops.flash_decode(q_dec, kp, vp, lengths, page_table=pt,
                                impl=impl, block_k=16, **kw_p)
         np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -263,16 +245,16 @@ class TestCarriedPoolDecodeStep:
                         "length": lengths}
 
     @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
-    @pytest.mark.parametrize("arch,layout", [
-        ("gpt2_alibi_15b", "kernel"),      # MHA
-        ("stablelm_12b", "kernel"),        # GQA 4 / 2
-        ("stablelm_12b", "legacy"),        # canonical pools, sliced per layer
-    ], ids=["mha", "gqa", "gqa-legacy"])
-    def test_matches_per_layer_loop(self, arch, layout, impl):
+    @pytest.mark.parametrize("arch", [
+        "gpt2_alibi_15b",                  # MHA
+        "stablelm_12b",                    # GQA 4 / 2
+        "command_r_plus_104b",             # GQA 8 / 2
+    ], ids=["mha", "gqa", "gqa-8-2"])
+    def test_matches_per_layer_loop(self, arch, impl):
         from repro.configs import smoke_config
         from repro.models import get_model, lm
         from repro.models.common import init_params
-        cfg = smoke_config(arch).replace(attn_impl=impl, cache_layout=layout)
+        cfg = smoke_config(arch).replace(attn_impl=impl)
         model = get_model(cfg)
         params = init_params(model.template(), jax.random.PRNGKey(3))
         cache = lm.init_paged_cache(cfg, 3, self.N_PAGES, self.PS,
@@ -300,10 +282,9 @@ class TestCarriedPoolDecodeStep:
         # the token rows landed at (page 2, row 0) and (page 11, row 2), in
         # every layer and kv head; nothing else moved, so the frozen slot's
         # pages are bit-identical to the input
-        page_axis = 2 if layout == "kernel" else 1
-        for leaf in ("pages_k", "pages_v"):
+        for leaf in ("pages_k", "pages_v"):      # (L, KVH, n_pages, ps, hd)
             diff = np.asarray(got[leaf]) != np.asarray(cache[leaf])
-            rows = np.moveaxis(diff, (page_axis, page_axis + 1), (0, 1))
+            rows = np.moveaxis(diff, (2, 3), (0, 1))
             changed = np.any(rows, axis=tuple(range(2, rows.ndim)))
             assert sorted(zip(*np.nonzero(changed))) == [(2, 0), (11, 2)]
             for page, row in ((2, 0), (11, 2)):  # every layer and kv head
@@ -366,8 +347,7 @@ class TestFoldedHeadPagedDecode:
         lengths = jnp.asarray(self.LENGTHS)
         layer = jnp.asarray(self.LAYER, jnp.int32)
         call = functools.partial(ops.flash_decode, q, kp, vp, lengths,
-                                 page_table=table, kv_layout="bhsd",
-                                 layer=layer, **kw)
+                                 page_table=table, layer=layer, **kw)
         got = np.asarray(call(impl="pallas_interpret"))
         want = np.asarray(call(impl="xla"))
         kvh = self.HEADS[mode][1]

@@ -93,8 +93,7 @@ def test_alibi_phi_decode_past_256_matches_float64(length):
         jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
         jnp.asarray([length], jnp.int32), phi_q=jnp.asarray(phi_q),
         phi_k=jnp.asarray(slab), impl="pallas_interpret",
-        page_table=jnp.arange(n_pages, dtype=jnp.int32)[None],
-        kv_layout="bhsd")
+        page_table=jnp.arange(n_pages, dtype=jnp.int32)[None])
     s = np.einsum("hd,thd->ht", q[0, 0].astype(np.float64),
                   k.astype(np.float64)) / np.sqrt(d)
     s += slopes[:, None] * (np.arange(length) - (length - 1))[None, :]

@@ -133,7 +133,7 @@ class TestLifecycle:
         assert eng.cancel(a) is True         # live cancel: frees the slot
         assert eng.status(a) == CANCELLED and eng.occupancy == 0
         assert eng.result(a).size >= 1       # partial output retained
-        assert eng._pool.n_free == eng.n_pages   # pages drained
+        assert eng.backend._pool.n_free == eng.n_pages   # pages drained
         assert eng.cancel(a) is False        # terminal: too late
         eng.run()                            # drains trivially
         assert eng.status_counts() == {CANCELLED: 2}
@@ -236,7 +236,7 @@ class TestQuarantine:
         for g, w in zip(got, ref):
             assert g.status == OK
             np.testing.assert_array_equal(g, w)
-        assert eng._pool.n_free == eng.n_pages      # nothing leaked
+        assert eng.backend._pool.n_free == eng.n_pages      # nothing leaked
 
     def test_retries_exhaust_to_failed_with_structured_error(self):
         cfg, model, params = _model("stablelm_12b")
@@ -254,7 +254,7 @@ class TestQuarantine:
         assert "non-finite" in ra.error["detail"]
         assert eng.result(b).status == OK           # neighbor unharmed
         assert eng.n_quarantines == 2               # initial trip + retry
-        assert eng._pool.n_free == eng.n_pages
+        assert eng.backend._pool.n_free == eng.n_pages
 
     def test_zero_retries_fails_on_first_trip(self):
         cfg, model, params = _model("stablelm_12b")
@@ -293,7 +293,7 @@ class TestQuarantine:
         stats = eng.page_stats()
         assert stats["prefix"]["invalidated"] >= 4   # the prompt chain
         assert stats["prefix"]["entries"] == 0
-        assert eng._pool.n_free == eng.n_pages       # index refs released
+        assert eng.backend._pool.n_free == eng.n_pages   # index refs freed
         # the engine still serves: the same prompt re-lands cleanly
         rid2 = eng.submit(prompt, 6)
         eng.run()
@@ -342,7 +342,7 @@ class TestContainment:
         for g, w in zip(got, ref):
             assert g.status == OK
             np.testing.assert_array_equal(g, w)
-        assert eng._pool.n_free == eng.n_pages
+        assert eng.backend._pool.n_free == eng.n_pages
 
 
 # ---------------------------------------------------------------------------
@@ -537,8 +537,8 @@ def test_randomized_chaos_conserves_pool_and_terminates():
         if rec.status == OK:
             assert rec.size >= 1
     # page conservation: the only remaining holders are index entries
-    pool, prefix = eng._pool, eng.backend._prefix
-    assert not eng._slot_pages
+    pool, prefix = eng.backend._pool, eng.backend._prefix
+    assert not eng.backend._slot_pages
     index_pages = {e.page for e in prefix._entries.values()}
     assert pool.n_used == len(index_pages)
     for page in index_pages:

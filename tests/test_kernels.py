@@ -103,6 +103,11 @@ class TestFlashBiasAttnKernel:
         np.testing.assert_allclose(a, b, atol=2e-5)
 
 
+def _head_major(x):
+    """(B, S, KVH, E) -> the caches' kv-head-major (B, KVH, S, E)."""
+    return x.transpose(0, 2, 1, 3)
+
+
 class TestFlashDecodeKernel:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("b,s,h,kvh,d,blk", [
@@ -118,8 +123,9 @@ class TestFlashDecodeKernel:
         lengths = jnp.asarray(
             np.random.RandomState(0).randint(1, s + 1, (b,)), jnp.int32)
         slopes = bias_mod.alibi_slopes(h)
-        out = ops.flash_decode(q, kc, vc, lengths, slopes=slopes,
-                               impl="pallas_interpret", block_k=blk)
+        out = ops.flash_decode(q, _head_major(kc), _head_major(vc), lengths,
+                               slopes=slopes, impl="pallas_interpret",
+                               block_k=blk)
         want = ref.decode_reference(q, kc, vc, lengths, slopes=slopes)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(want, np.float32),
@@ -133,8 +139,10 @@ class TestFlashDecodeKernel:
         pq = jax.random.normal(jax.random.PRNGKey(3), (b, 1, h, r))
         pk = jax.random.normal(jax.random.PRNGKey(4), (b, s, 1, r))
         lengths = jnp.array([31, 48], jnp.int32)
-        out = ops.flash_decode(q, kc, vc, lengths, pq, pk,
-                               impl="pallas_interpret", block_k=16)
+        pk_hm = jnp.broadcast_to(_head_major(pk), (b, kvh, s, r))
+        out = ops.flash_decode(q, _head_major(kc), _head_major(vc), lengths,
+                               pq, pk_hm, impl="pallas_interpret",
+                               block_k=16)
         want = ref.decode_reference(q, kc, vc, lengths, phi_q=pq, phi_k=pk)
         np.testing.assert_allclose(out, want, atol=2e-5)
 
@@ -145,8 +153,8 @@ class TestFlashDecodeKernel:
         vc = jax.random.normal(jax.random.PRNGKey(2), (b, s, kvh, d))
         lengths = jnp.array([10, 64], jnp.int32)
         slopes = bias_mod.alibi_slopes(h)
-        out = ops.flash_decode(q, kc, vc, lengths, slopes=slopes, impl="xla",
-                               block_k=16)
+        out = ops.flash_decode(q, _head_major(kc), _head_major(vc), lengths,
+                               slopes=slopes, impl="xla", block_k=16)
         want = ref.decode_reference(q, kc, vc, lengths, slopes=slopes)
         np.testing.assert_allclose(out, want, atol=2e-5)
 
@@ -175,12 +183,10 @@ MESH_PARITY = textwrap.dedent("""
             q, kk, vv, slopes=slopes, mask_kind="causal",
             impl="pallas_interpret", layout="bhsd"),
         "decode": lambda: ops.flash_decode(
-            qd, kk, vv, lengths, slopes=slopes, impl="pallas_interpret",
-            kv_layout="bhsd"),
+            qd, kk, vv, lengths, slopes=slopes, impl="pallas_interpret"),
         "paged": lambda: ops.flash_decode(
             qd, pool_k, pool_v, lengths, slopes=slopes,
-            impl="pallas_interpret", page_table=table, kv_layout="bhsd",
-            max_pages=2),
+            impl="pallas_interpret", page_table=table, max_pages=2),
     }
     mesh = make_mesh((2, 2), ("data", "model"))
     for name, call in calls.items():

@@ -118,17 +118,17 @@ def test_grow_on_boundary_allocates_one_page_per_crossing():
     rid = eng.submit(_prompts(cfg, (5,), seed=1)[0], 20)
     eng.step()                             # admit: prompt pages only
     (slot,) = eng._live.keys()
-    seen = {len(eng._slot_pages[slot])}
+    seen = {len(eng.backend._slot_pages[slot])}
     assert seen == {1}                     # ceil(5 / 8)
     while not eng.is_done(rid):
         eng.step()
-        if slot in eng._slot_pages:
-            seen.add(len(eng._slot_pages[slot]))
+        if slot in eng.backend._slot_pages:
+            seen.add(len(eng.backend._slot_pages[slot]))
     # final length 5 + 20 - 1 = 24 -> three pages, grown one at a time
     assert seen == {1, 2, 3}
     assert eng.page_stats()["grown"] == 2
     assert eng.page_stats()["watermark"] == 3
-    assert eng._pool.n_free == eng.n_pages
+    assert eng.backend._pool.n_free == eng.n_pages
 
 
 def test_preemption_frees_pages_for_lowest_index_reuse():
@@ -142,17 +142,17 @@ def test_preemption_frees_pages_for_lowest_index_reuse():
     r0 = eng.submit(p0, 6)
     r1 = eng.submit(p1, 6)
     eng.step()                             # admit both: 1 + 2 pages, dry
-    assert eng._slot_pages == {0: [0], 1: [1, 2]}
+    assert eng.backend._slot_pages == {0: [0], 1: [1, 2]}
     eng.step()                             # r0 crosses 8: grow -> preempt r1
     assert eng.n_preemptions == 1 and not eng.is_done(r1)
-    assert eng._slot_pages == {0: [0, 1]}  # lowest freed page reused
+    assert eng.backend._slot_pages == {0: [0, 1]}  # lowest freed page reused
     eng.run()
     for rid, p in ((r0, p0), (r1, p1)):
         np.testing.assert_array_equal(
             eng.result(rid),
             _alone(model, params, p, 6, max_len=32, n_slots=2,
                    prefill_len=10, page_size=8, n_pages=3))
-    assert eng._pool.n_free == eng.n_pages
+    assert eng.backend._pool.n_free == eng.n_pages
 
 
 def test_whole_reservation_mode_never_grows_or_preempts():
@@ -218,7 +218,7 @@ def test_preempted_equals_alone_full_kv_auto():
     for rid, p, b, sp in zip(rids, prompts, budgets, samplings):
         alone = _alone(model, params, p, b, sampling=sp, **kw)
         np.testing.assert_array_equal(eng.result(rid), alone)
-    assert eng._pool.n_free == eng.n_pages
+    assert eng.backend._pool.n_free == eng.n_pages
 
 
 @pytest.mark.parametrize("arch", ["stablelm_12b", "hymba_15b"])

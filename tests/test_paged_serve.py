@@ -110,11 +110,11 @@ def test_paged_matches_contiguous_staggered(arch):
 
     eng_c, out_c = run({})
     eng_p, out_p = run({"page_size": 16})
-    assert eng_p._paged == (arch == "stablelm_12b")
+    assert eng_p.backend.paged == (arch == "stablelm_12b")
     for i, (c, p) in enumerate(zip(out_c, out_p)):
         np.testing.assert_array_equal(c, p, err_msg=f"{arch} request {i}")
-    if eng_p._paged:        # drained engine must have returned every page
-        assert eng_p._pool.n_free == eng_p.n_pages
+    if eng_p.backend.paged:     # a drained engine returned every page
+        assert eng_p.backend._pool.n_free == eng_p.n_pages
 
 
 def test_hybrid_full_kv_pages_with_ssm_slot_leaves():
@@ -127,7 +127,8 @@ def test_hybrid_full_kv_pages_with_ssm_slot_leaves():
     kw = {"max_len": 32, "n_slots": 2, "prefill_len": 10}
     out_c = ServeEngine(model, params, **kw).generate(prompts, 5)
     eng_p = ServeEngine(model, params, page_size=8, **kw)
-    assert eng_p._paged and "ssm_h" in eng_p.model.init_paged_cache(2, 8, 8)
+    assert eng_p.backend.paged
+    assert "ssm_h" in eng_p.model.init_paged_cache(2, 8, 8)
     np.testing.assert_array_equal(out_c, eng_p.generate(prompts, 5))
 
 
@@ -137,7 +138,7 @@ def test_moe_paged_matches_contiguous():
     prompts = _prompts(cfg, (5, 8), seed=3)
     eng_c = ServeEngine(model, params, **kw)
     eng_p = ServeEngine(model, params, page_size=8, **kw)
-    assert eng_p._paged
+    assert eng_p.backend.paged
     np.testing.assert_array_equal(eng_c.generate(prompts, 4),
                                   eng_p.generate(prompts, 4))
 
@@ -155,7 +156,7 @@ def test_paged_accepts_request_beyond_max_len():
     rid = eng_p.submit(prompt, 40)                # needs 5 of 8 pages
     eng_p.run()
     assert eng_p.result(rid).size == 40
-    assert eng_p._pool.n_free == eng_p.n_pages
+    assert eng_p.backend._pool.n_free == eng_p.n_pages
 
     # a request that can NEVER fit its page-table row is rejected up front
     with pytest.raises(AdmissionRejected):
@@ -184,7 +185,7 @@ def test_oom_admission_backpressure():
     for rid, p in zip(rids, prompts):
         alone = _alone(model, params, p, budget, **kw)
         np.testing.assert_array_equal(eng.result(rid), alone)
-    assert eng._pool.n_free == eng.n_pages
+    assert eng.backend._pool.n_free == eng.n_pages
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +212,7 @@ def test_retired_slot_is_frozen_and_reusable():
     free_slot = eng._free[0]
     for _ in range(20):                 # idle slot rides 20 full-batch steps
         eng.step()
-        assert int(np.asarray(eng._cache["length"])[free_slot]) == 0
+        assert int(np.asarray(eng.backend._cache["length"])[free_slot]) == 0
     r2 = eng.submit(prompts[2], 6)      # reuses the slot (and r0's pages)
     eng.run()
 
@@ -232,11 +233,11 @@ def test_retired_slot_frozen_contiguous_too():
     while not eng.is_done(r0):
         eng.step()
     slot = eng._free[0]
-    k_before = np.asarray(eng._cache["k"][:, slot])
+    k_before = np.asarray(eng.backend._cache["k"][:, slot])
     for _ in range(10):
         eng.step()
-        assert int(np.asarray(eng._cache["length"])[slot]) == 0
-    np.testing.assert_array_equal(np.asarray(eng._cache["k"][:, slot]),
+        assert int(np.asarray(eng.backend._cache["length"])[slot]) == 0
+    np.testing.assert_array_equal(np.asarray(eng.backend._cache["k"][:, slot]),
                                   k_before)
 
 

@@ -2,9 +2,10 @@
 complex, admission caches its pair-bias factors per slot, every step is one
 refinement iteration over the padded slot batch. The contract under test:
 
-- batched == solo, bitwise: per-slot computation is batch-row independent
-  and padding is pinned at max_len, so a complex's result is identical
-  whether it shares the batch with strangers or runs alone;
+- batched == solo, to f32 rounding: per-slot computation is batch-row
+  independent and padding is pinned at max_len, so a complex's result is
+  the same whether it shares the batch with strangers or runs alone (the
+  two programs' reduction orders differ, so not bitwise);
 - the factor cache is admission-frozen: steps reuse phi_q/phi_k untouched
   (the Pairformer analogue of the LM KV cache);
 - the cached-dense and official-recompute dense dataflows are the same
@@ -41,6 +42,12 @@ def _complexes(lens, f=64, seed=1):
     return [rng.standard_normal((n, f)).astype(np.float32) for n in lens]
 
 
+# Batched and solo runs are different programs (other batch shapes, other
+# reduction orders): on CPU their outputs (|x| ~0.7) differ by <= 1.5e-7,
+# so results are held to 10x that, not bitwise.
+BATCH_ATOL = 1.5e-6
+
+
 def _alone(model, params, feats, budget, **kw):
     eng = ServeEngine(model, params, max_len=MAX_LEN, n_slots=1, **kw)
     rid = eng.submit(feats, budget)
@@ -50,8 +57,8 @@ def _alone(model, params, feats, budget, **kw):
 
 def test_batched_matches_single_complex_runs():
     """5 variable-length complexes through 2 slots, arriving mid-flight and
-    finishing at different steps (budgets differ). Every result must be
-    bit-equal to the same complex served alone."""
+    finishing at different steps (budgets differ). Every result must
+    match the same complex served alone, to BATCH_ATOL."""
     cfg, model, params = _model()
     complexes = _complexes((12, 7, 16, 9, 5))
     budgets = [3, 5, 2, 4, 3]
@@ -72,7 +79,7 @@ def test_batched_matches_single_complex_runs():
         got = eng.result(rid)
         assert got.shape == (complexes[i].shape[0], cfg.d_model)
         ref = _alone(model, params, complexes[i], budgets[i])
-        np.testing.assert_array_equal(got, ref)
+        np.testing.assert_allclose(got, ref, rtol=0, atol=BATCH_ATOL)
 
 
 def test_factor_cache_frozen_across_steps():
@@ -128,7 +135,7 @@ def test_factor_mlp_cache_serves_batched():
     """Eq. 5 factor-MLP mode: fitted (here randomly initialised — the
     contract is structural) factor params ride ``factors=`` into the
     engine; the cache holds MLP factors at the full configured rank and
-    batched results still match solo bitwise."""
+    batched results still match solo, to BATCH_ATOL."""
     cfg, model, params = _model()
     fp = init_params(stack_layers(pf_mod.factor_mlp_template(cfg, hidden=16),
                                   cfg.n_layers), jax.random.PRNGKey(5))
@@ -139,7 +146,8 @@ def test_factor_mlp_cache_serves_batched():
     assert eng.backend._cache["phi_q"].shape[-1] == cfg.bias_rank
     for c, rid in zip(complexes, rids):
         ref = _alone(model, params, c, 3, factors=fp)
-        np.testing.assert_array_equal(eng.result(rid), ref)
+        np.testing.assert_allclose(eng.result(rid), ref, rtol=0,
+                                   atol=BATCH_ATOL)
 
 
 def test_pair_request_validation():

@@ -170,11 +170,11 @@ def test_retire_retains_prompt_pages_in_index():
     outs, eng = _run(model, params, prompts, prefix_cache=True, budget=6)
     be = eng.backend
     assert len(be._prefix) == 2                 # full prompt pages indexed
-    assert be._prefix.n_cached(eng._pool) == 2  # retained past retirement
-    assert eng._pool.n_free == eng.n_pages - 2  # decode/partial pages drain
+    assert be._prefix.n_cached(be._pool) == 2   # retained past retirement
+    assert be._pool.n_free == eng.n_pages - 2   # decode/partial pages drain
     # the retained pages are evictable on demand — nothing leaks
-    assert be._prefix.evict(eng._pool, 2) == 2
-    assert eng._pool.n_free == eng.n_pages
+    assert be._prefix.evict(be._pool, 2) == 2
+    assert be._pool.n_free == eng.n_pages
 
 
 def test_second_request_hits_and_emits_identically():
@@ -239,7 +239,8 @@ def test_parity_through_preemption_of_a_sharing_request():
     st = eng.page_stats()
     assert st["preemptions"] > 0            # sharing requests were evicted
     assert st["prefix"]["evictions"] > 0    # and the index gave pages back
-    assert eng._pool.n_free + st["prefix"]["cached_pages"] == eng.n_pages
+    assert (eng.backend._pool.n_free + st["prefix"]["cached_pages"]
+            == eng.n_pages)
 
 
 def test_submit_error_accounts_for_shared_hits():

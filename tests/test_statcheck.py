@@ -150,8 +150,9 @@ class TestContracts:
         assert check_family("dense") == []
 
     def test_legacy_tripwire_fires(self):
-        """The built-in negative test: cache_layout='legacy' must trip the
-        decode-step transpose rule (the per-layer to_pool adapter)."""
+        """The built-in negative test: a layer scan that transposes each
+        layer's pool must trip the decode-step relayout rule at the dense
+        family's real threshold."""
         from repro.statcheck.contracts import verify_tripwire
         assert verify_tripwire() == []   # empty = the tripwire DID fire
 

@@ -87,7 +87,7 @@ def test_alibi_contiguous_decode_gpt2(one_chip):
     lengths = _arg((4,), jnp.int32, one_chip)
     slopes = _arg((64,), jnp.float32, one_chip)
     _compile(lambda q, k, v, n, s: ops.flash_decode(
-        q, k, v, n, slopes=s, impl="pallas", kv_layout="bhsd"),
+        q, k, v, n, slopes=s, impl="pallas"),
         q, kv, kv, lengths, slopes, kernel="flash_decode")
 
 
@@ -106,7 +106,7 @@ def test_paged_decode_shared_phi_slab(one_chip, heads, kv_heads, head_dim):
     slab = _arg((n_pages, ps, 128), jnp.float32, one_chip)
     _compile(lambda q, k, v, n, t, pq, pk: ops.flash_decode(
         q, k, v, n, phi_q=pq, phi_k=pk, impl="pallas", page_table=t,
-        kv_layout="bhsd", max_pages=p_slot),
+        max_pages=p_slot),
         q, pool, pool, lengths, table, phi_q, slab,
         kernel="flash_decode_paged")
 
@@ -178,7 +178,7 @@ def _sharded_paged_decode(topo, stacked):
     with use_mesh_rules(mesh, Rules()):
         hlo = _compile(lambda q, k, v, n, t, pq, pk, layer: ops.flash_decode(
             q, k, v, n, phi_q=pq, phi_k=pk, impl="pallas", page_table=t,
-            kv_layout="bhsd", max_pages=p_slot, layer=layer),
+            max_pages=p_slot, layer=layer),
             q, pool, pool, lengths, table, phi_q, slab, layer,
             kernel="flash_decode_paged")
     pool_shard = (h // 2) * n_pages * ps * 128
