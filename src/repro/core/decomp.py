@@ -13,17 +13,19 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import lowrank
 
-__all__ = ["svd_factors", "NeuralDecompParams", "neural_decomp_init",
-           "neural_decomp_apply", "fit_neural_decomposition",
-           "reconstruction_error"]
+__all__ = ["svd_factors", "svd_solves", "NeuralDecompParams",
+           "neural_decomp_init", "neural_decomp_apply",
+           "fit_neural_decomposition", "reconstruction_error"]
 
 
 # ---------------------------------------------------------------------------
@@ -34,7 +36,7 @@ def svd_factors(table: jax.Array, rank: Optional[int] = None,
                 energy: float = 0.99) -> Tuple[jax.Array, jax.Array]:
     """Truncated-SVD factors of a (possibly per-head) dense bias table.
 
-    table: (N, M) or (H, N, M). Returns (phi_q, phi_k) with shapes
+    table: (N, M) or (..., N, M). Returns (phi_q, phi_k) with shapes
     (..., N, R) and (..., M, R) such that phi_q @ phi_k^T is the best
     rank-R approximation (Eckart–Young). If ``rank`` is None it is chosen
     per ``energy`` (Remark 3.8: R maintaining e.g. 99% of sigma^2 mass),
@@ -42,16 +44,77 @@ def svd_factors(table: jax.Array, rank: Optional[int] = None,
 
     Singular values are split evenly (sqrt) between the two factors to keep
     their magnitudes balanced — this matters for bf16 kernels downstream.
+
+    A batch of square tables takes ``_polar_eigh_svd`` on TPU (one
+    batched eigensolve per call, ``svd_solves``); everything else, and
+    every table off TPU, ``jnp.linalg.svd``, whose LAPACK kernel factors
+    each matrix alone, so a matrix rounds the same in any batch.
     """
     mat = table.astype(jnp.float32)
     if rank is None:
         rank = lowrank.rank_for_energy(mat, energy)
+    r = int(min(rank, *mat.shape[-2:]))
+    if _batched_square(mat.shape):
+        u, s, v = lax.platform_dependent(
+            mat, tpu=functools.partial(_polar_eigh_svd, r=r),
+            default=functools.partial(_svd, r=r))
+    else:
+        u, s, v = _svd(mat, r)
+    sq = jnp.sqrt(s)
+    return u * sq[..., None, :], v * sq[..., None, :]
+
+
+def _svd(mat: jax.Array, r: int):
+    """Top-``r`` singular triplets (u, s, v) by ``jnp.linalg.svd``."""
     u, s, vt = jnp.linalg.svd(mat, full_matrices=False)
-    r = int(min(rank, s.shape[-1]))
-    sq = jnp.sqrt(s[..., :r])
-    phi_q = u[..., :, :r] * sq[..., None, :]
-    phi_k = jnp.swapaxes(vt[..., :r, :], -1, -2) * sq[..., None, :]
-    return phi_q, phi_k
+    return u[..., :, :r], s[..., :r], jnp.swapaxes(vt[..., :r, :], -1, -2)
+
+
+def _batched_square(shape) -> bool:
+    return len(shape) > 2 and shape[-1] == shape[-2]
+
+
+def svd_solves(shape) -> int:
+    """Eigensolver calls ``svd_factors`` makes on TPU to factor a table
+    of ``shape``: one for a batch of square tables. ``jnp.linalg.svd``,
+    which takes the rest, solves a batch in one call up to 256 x 256 and
+    maps its eigensolve over the batch one matrix at a time above."""
+    if _batched_square(shape) or min(shape[-2:]) <= 256:
+        return 1
+    return math.prod(shape[:-2])
+
+
+def _polar_eigh_svd(mat: jax.Array, r: int):
+    """Top-``r`` singular triplets (u, s, v) of a batch of square matrices.
+
+    The composition of jax's TPU ``svd`` (polar decomposition A = U_p H by
+    QDWH, then H = V diag(s) V^T, so A = (U_p V) diag(s) V^T), with one
+    Jacobi ``eigh`` of H over the whole batch. jax's own TPU ``eigh`` maps
+    over the batch one matrix at a time and, above n = 256, divides each
+    spectrum by further QDWH iterations down to 256 x 256 Jacobi leaves;
+    on a TPU v5e that costs more than Jacobi on the whole matrix for the
+    Pairformer's zero-padded 384 x 384 pair biases. (Jacobi's time grows
+    with the batch: batching saves the serial loop, not the solves.)
+    Jacobi is TPU-only; elsewhere the default ``eigh``, so the composition
+    runs anywhere. Rows that are zero in ``mat`` stay exactly zero in u
+    and v. Matmuls run at f32 precision, as in jax's ``svd``: the TPU
+    default rounds through bf16.
+    """
+    with jax.default_matmul_precision("float32"):
+        polar = functools.partial(lax.linalg.qdwh, max_iterations=10)
+        for _ in range(mat.ndim - 2):
+            polar = jax.vmap(polar)
+        u_p, h, _, _ = polar(mat)
+        eigh = functools.partial(lax.linalg.eigh, sort_eigenvalues=False)
+        v, w = lax.platform_dependent(
+            h, default=eigh, tpu=functools.partial(
+                eigh, implementation=lax.linalg.EighImplementation.JACOBI))
+        # eigh may return tiny negative eigenvalues of the PSD factor H
+        s = jnp.maximum(w, 0.0)
+        top = jnp.argsort(s, axis=-1, descending=True)[..., :r]
+        s = jnp.take_along_axis(s, top, axis=-1)
+        v = jnp.take_along_axis(v, top[..., None, :], axis=-1)
+        return u_p @ v, s, v
 
 
 def reconstruction_error(table: jax.Array, phi_q: jax.Array,

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro import flags
 from repro.configs.base import ArchConfig
+from repro.core.decomp import svd_factors, svd_solves
 from repro.dist.sharding import constrain
 from repro.kernels import ops as kops
 from repro.models.common import PDef, gelu_mlp, rmsnorm, stack_layers
@@ -244,6 +245,18 @@ def _serve_rank(cfg: ArchConfig, n: int, mode: str) -> int:
     return cfg.bias_rank if mode == "mlp" else min(cfg.bias_rank, n)
 
 
+def admission_svds(cfg: ArchConfig, batch: int, max_len: int,
+                   factors=None) -> tuple:
+    """(matrices, eigensolver calls) of one admission wave's SVDs: each
+    layer factors the (batch, heads, max_len, max_len) pair bias; (0, 0)
+    unless the cache holds SVD factors."""
+    if _serve_mode(cfg, factors) != "svd":
+        return 0, 0
+    shape = (batch, cfg.n_heads, max_len, max_len)
+    return (cfg.n_layers * batch * cfg.n_heads,
+            cfg.n_layers * svd_solves(shape))
+
+
 def init_serve_cache(cfg: ArchConfig, batch: int, max_len: int,
                      factors=None) -> dict:
     """Zeroed pair slot cache. ``length`` doubles as the active mask
@@ -277,8 +290,6 @@ def serve_prefill(params, batch, cfg: ArchConfig, factors=None, *,
     Returns (None, wave_cache) — the wave cache rows scatter into the slot
     cache via ``insert_serve_cache_at_slots``.
     """
-    from repro.core.decomp import svd_factors
-
     feats = batch["feats"]
     b, n = feats.shape[0], feats.shape[1]
     dt = jnp.dtype(cfg.dtype)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist.sharding import Rules, shard_put, use_mesh_rules
+from repro.models import pairformer
 from repro.models.api import Model
 from repro.serve.lifecycle import AdmissionRejected, PoolError, PoolExhausted
 from repro.serve.pages import PagePool
@@ -80,6 +81,10 @@ class Backend:
     # tokens (residues) among them
     n_prefill_rows_x_len: int = 0
     n_prefill_real: int = 0
+    # bias-table SVDs at admission: the matrices factored and the
+    # eigensolver calls that factored them
+    n_svd_matrices: int = 0
+    n_svd_solves: int = 0
     # device state per kind, read by ``engine.counters()`` once allocated:
     # recurrent-state bytes one slot holds (SSM state and conv tails) and
     # the layers whose K/V live in the page pool
@@ -1031,6 +1036,8 @@ class PairBatchBackend(Backend):
                                 static_argnames=("max_len",))
         self._step = jax.jit(named("refine", model.decode))
         self._insert = jax.jit(named("insert_factors", model.insert_cache))
+        self._svds = pairformer.admission_svds(model.cfg, n_slots, max_len,
+                                               factors)
 
     def ensure_state(self) -> None:
         if self._cache is None:
@@ -1063,6 +1070,8 @@ class PairBatchBackend(Backend):
             lengths[i] = real[i]
         self.n_prefill_rows_x_len += ns * self.max_len
         self.n_prefill_real += sum(real)
+        self.n_svd_matrices += self._svds[0]
+        self.n_svd_solves += self._svds[1]
         with span("serve.prefill", requests=w, rows=ns, row_len=self.max_len,
                   real=sum(real), real_sq=sum(n * n for n in real)):
             _, wave_cache = self._prefill(self.params, jnp.asarray(feats),

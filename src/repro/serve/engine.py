@@ -395,7 +395,9 @@ class ServeEngine:
         continues its snapshot's): engine ``steps``, admission ``waves``,
         ``prefill_rows_x_len`` (rows x padded length the prefill programs
         ran) and ``prefill_real`` (the real prompt tokens, or residues,
-        among them), ``preemptions``, ``quarantines`` and
+        among them), ``svd_matrices`` and ``svd_solves`` (the bias
+        matrices admission factored by SVD and the eigensolver calls that
+        factored them), ``preemptions``, ``quarantines`` and
         ``faults_contained``. ``1 - prefill_real / prefill_rows_x_len``
         is the share of prefill rows that were padding. Two sizes of the
         device state ride along once it is allocated:
@@ -405,6 +407,8 @@ class ServeEngine:
         return {"steps": self.step_idx, "waves": self.n_waves,
                 "prefill_rows_x_len": self.backend.n_prefill_rows_x_len,
                 "prefill_real": self.backend.n_prefill_real,
+                "svd_matrices": self.backend.n_svd_matrices,
+                "svd_solves": self.backend.n_svd_solves,
                 "preemptions": self.n_preemptions,
                 "quarantines": self.n_quarantines,
                 "faults_contained": self.n_faults_contained,
@@ -862,6 +866,8 @@ class ServeEngine:
         self.backend.n_prefill_rows_x_len = counters.get(
             "prefill_rows_x_len", 0)
         self.backend.n_prefill_real = counters.get("prefill_real", 0)
+        self.backend.n_svd_matrices = counters.get("svd_matrices", 0)
+        self.backend.n_svd_solves = counters.get("svd_solves", 0)
         self._meta = {int(rid): _ReqMeta(status=m["status"],
                                          error=m["error"],
                                          retries=m["retries"],

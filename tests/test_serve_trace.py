@@ -150,6 +150,26 @@ def test_pair_counters_count_padded_residues():
     assert c["prefill_real"] == sum(sizes)
 
 
+@pytest.mark.parametrize("bias_mode", ["flashbias", "dense"])
+def test_pair_counters_count_svd_solves(bias_mode):
+    """Each admission wave factors every layer's (slots, heads) pair-bias
+    matrices by SVD in one batched eigensolve; a dense bias cache runs no
+    SVD."""
+    model, params = _model("pairformer_lite")
+    cfg = model.cfg.replace(bias_mode=bias_mode)
+    eng = ServeEngine(get_model(cfg), params, max_len=MAX_LEN,
+                      n_slots=N_SLOTS)
+    rng = np.random.RandomState(1)
+    for n in (9, 20, 32):
+        eng.submit(rng.standard_normal((n, 64)).astype(np.float32), 1)
+    eng.run()
+    c = eng.counters()
+    svd = bias_mode == "flashbias"
+    assert c["waves"] == 2
+    assert c["svd_matrices"] == svd * 2 * cfg.n_layers * N_SLOTS * cfg.n_heads
+    assert c["svd_solves"] == svd * 2 * cfg.n_layers
+
+
 def test_counters_survive_snapshot_and_restore():
     eng = _engine()
     for p in _prompts(6, seed=7):
